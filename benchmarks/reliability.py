@@ -228,17 +228,19 @@ _DISTRIBUTED_CHILD = textwrap.dedent("""
         "segments": int(res.iterations),
         "final_rel": float(res.final_residual),
         "events": [e.kind for e in res.fault_events],
-        "devices": jax.device_count()}}))
+        "devices": jax.device_count(), "backend": jax.default_backend()}}))
 """)
 
 
 def _ft_distributed_row(n: int) -> Dict:
     """Contract B in a subprocess: 8 virtual host devices, 2x4 mesh, a fault
-    injected into the sharded dense image mid-solve."""
+    injected into the sharded dense image mid-solve.  The child always runs
+    on the CPU backend (a parent that has touched JAX holds the chip), and
+    its row says so in ``backend``."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c",
                           _DISTRIBUTED_CHILD.format(n=n)],
                          capture_output=True, text=True, env=env, timeout=900)
@@ -250,7 +252,8 @@ def _ft_distributed_row(n: int) -> Dict:
             "segments": int(res["segments"]),
             "final_rel": f"{res['final_rel']:.3e}",
             "events": ";".join(res["events"]),
-            "devices": int(res["devices"])}
+            "devices": int(res["devices"]),
+            "backend": res["backend"]}
 
 
 def _serving_rows(n_requests: int) -> List[Dict]:

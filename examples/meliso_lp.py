@@ -38,9 +38,11 @@ from repro import solvers
 from repro.core import CrossbarConfig, MCAGeometry, get_device, rel_l2
 from repro.engine import AnalogEngine
 from repro.launch.mesh import make_mesh
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--m", type=int, default=256, help="LP constraints (rows)")
     ap.add_argument("--n", type=int, default=512, help="LP variables (cols)")

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from repro import solvers
 from repro.core import CrossbarConfig, MCAGeometry, get_device, rel_l2
 from repro.engine import AnalogEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def blur_matrix(n: int, sigma: float) -> jnp.ndarray:
@@ -54,6 +55,7 @@ def piecewise_signal(n: int, key) -> jnp.ndarray:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=128, help="signal length")
     ap.add_argument("--sigma", type=float, default=2.0,

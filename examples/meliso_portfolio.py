@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro import solvers
 from repro.core import CrossbarConfig, MCAGeometry, get_device, rel_l2
 from repro.engine import AnalogEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def objective(f, q, x) -> float:
@@ -37,6 +38,7 @@ def objective(f, q, x) -> float:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--assets", type=int, default=96, help="universe size n")
     ap.add_argument("--factors", type=int, default=32,

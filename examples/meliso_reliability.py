@@ -38,6 +38,7 @@ from repro.reliability import (RefreshPolicy, attach_age, ft_cg,
                                predicted_residual, probe_tile_scores,
                                refresh_tiles)
 from repro.solvers import cg
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _spd(n: int, key: jax.Array):
@@ -129,6 +130,7 @@ def fault_act(n: int, mesh_shape) -> None:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=256)
     # ag-si: the highest fault-rate device in the zoo -- damage shows up in

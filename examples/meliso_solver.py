@@ -41,9 +41,11 @@ from repro import solvers
 from repro.core import CrossbarConfig, MCAGeometry, get_device, rel_l2
 from repro.engine import AnalogEngine
 from repro.launch.mesh import make_mesh
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=4096)
     ap.add_argument("--tol", type=float, default=1e-3,

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro import solvers
 from repro.core import CrossbarConfig, MCAGeometry, get_device
 from repro.engine import AnalogEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def sbm_laplacian(n: int, p_in: float, p_out: float, key):
@@ -50,6 +51,7 @@ def accuracy(fiedler, labels) -> float:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=128, help="graph vertices")
     ap.add_argument("--p-in", type=float, default=0.30,

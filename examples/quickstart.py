@@ -14,9 +14,11 @@ import jax.numpy as jnp
 from repro.core import CrossbarConfig, MCAGeometry, get_device, rel_l2
 from repro.core.matrices import paper_matrix
 from repro.engine import AnalogEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     a = jnp.asarray(paper_matrix("bcsstk02"), jnp.float32)   # kappa = 4325
     key = jax.random.PRNGKey(0)
     xs = [jax.random.normal(jax.random.fold_in(key, i), (66,))

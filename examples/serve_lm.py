@@ -19,9 +19,11 @@ from repro.configs.base import RRAMBackendConfig
 from repro.models import params as PM
 from repro.models.common import Runtime
 from repro.train.serve import Server
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--batch", type=int, default=4)

@@ -24,6 +24,7 @@ from repro.data.pipeline import Prefetcher, batches
 from repro.distributed import CheckpointManager
 from repro.models import params as PM
 from repro.train import Trainer
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def preset_config(name: str, arch_name: str) -> ModelConfig:
@@ -41,6 +42,7 @@ def preset_config(name: str, arch_name: str) -> ModelConfig:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--preset", default="smoke",

@@ -4,7 +4,7 @@ The paper's scalability claim is that a >= 65,536^2 solve never allocates an
 A-sized array -- not on the host, not on any device.  That property is
 *structural*: it is visible in the jaxpr of the jitted computation before
 anything runs.  :func:`max_aval_elements` walks every equation (recursing
-into scan/while/cond/pjit/shard_map/custom_vjp sub-jaxprs) and returns the
+into scan/while/cond/jit/shard_map/custom_vjp sub-jaxprs) and returns the
 largest intermediate, input, constant or output aval in elements, so tests
 and benchmarks can assert ``max_aval_elements(mvm_fn, x, key) << m * n``
 without paying for (or being able to afford) a real A-sized buffer.

@@ -1,8 +1,10 @@
 """Three-term roofline from a compiled dry-run artifact (DESIGN.md section 8).
 
-TPU v5e per chip: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s per ICI link.
-cost_analysis()/memory_analysis() are per-device (the SPMD-partitioned
-program), so terms are per-chip directly:
+Peaks are keyed by ``device_kind`` (:data:`PEAKS`); a device not in the
+table is an error, never a default.  TPU v5e per chip (Google Cloud
+documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s HBM, 16 GB HBM;
+~50 GB/s per ICI link.  cost_analysis()/memory_analysis() are per-device
+(the SPMD-partitioned program), so terms are per-chip directly:
 
     compute    = HLO_FLOPs / peak
     memory     = HLO_bytes_accessed / HBM_bw
@@ -44,23 +46,36 @@ def _cpu_bf16_artifact_bytes(hlo: str) -> float:
     sizes.sort(reverse=True)
     return float(sum(sizes[:2]))
 
-__all__ = ["HW", "analyze_compiled", "roofline_terms", "format_row"]
+__all__ = ["PEAKS", "peaks", "analyze_compiled", "roofline_terms",
+           "format_row"]
 
-HW = {
-    "peak_flops": 197e12,     # bf16 / chip
-    "hbm_bw": 819e9,          # bytes/s
-    "ici_bw": 50e9,           # bytes/s/link
-    "hbm_bytes": 16 * 1024**3,
+#: Per-chip peaks by ``jax.Device.device_kind``.
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,     # bf16 / chip
+        "hbm_bw": 819e9,          # bytes/s
+        "ici_bw": 50e9,           # bytes/s/link
+        "hbm_bytes": 16 * 1024**3,
+    },
 }
 
 
-def analyze_compiled(compiled, n_devices: int,
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table of ``device_kind``; raises for a device not in it."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; known: "
+                       f"{sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
+
+def analyze_compiled(compiled, n_devices: int, device_kind: str,
                      model_flops: Optional[float] = None) -> Dict[str, Any]:
+    """Roofline record of ``compiled`` on ``n_devices`` chips of
+    ``device_kind``."""
+    hw = peaks(device_kind)
     # XLA's cost_analysis counts while bodies once; the loop-aware walker in
     # hlo_cost scales by trip count (and catches collectives inside scans).
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):   # jax < 0.5: one dict per device
-        xla_cost = xla_cost[0] if xla_cost else {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     hc = analyze_hlo_text(hlo)
@@ -88,26 +103,28 @@ def analyze_compiled(compiled, n_devices: int,
             "peak_bytes": peak_bytes,
             "cpu_bf16_artifact_bytes": artifact,
             "peak_bytes_tpu": peak_tpu,
-            "fits_hbm": bool(peak_tpu <= HW["hbm_bytes"]),
-            "fits_hbm_raw_cpu": bool(peak_bytes <= HW["hbm_bytes"]),
+            "fits_hbm": bool(peak_tpu <= hw["hbm_bytes"]),
+            "fits_hbm_raw_cpu": bool(peak_bytes <= hw["hbm_bytes"]),
         },
         "n_devices": n_devices,
     }
-    out.update(roofline_terms(flops, bytes_acc, wire))
+    out.update(roofline_terms(flops, bytes_acc, wire, device_kind))
     if model_flops:
         per_dev_useful = model_flops / n_devices
         out["model_flops"] = model_flops
         out["useful_ratio"] = (per_dev_useful / flops) if flops else 0.0
-        out["useful_time_s"] = per_dev_useful / HW["peak_flops"]
+        out["useful_time_s"] = per_dev_useful / hw["peak_flops"]
         dom = out["dominant_time_s"]
         out["roofline_fraction"] = (out["useful_time_s"] / dom) if dom else 0.0
     return out
 
 
-def roofline_terms(flops: float, bytes_acc: float, wire: float) -> Dict[str, Any]:
-    t_c = flops / HW["peak_flops"]
-    t_m = bytes_acc / HW["hbm_bw"]
-    t_x = wire / HW["ici_bw"]
+def roofline_terms(flops: float, bytes_acc: float, wire: float,
+                   device_kind: str) -> Dict[str, Any]:
+    hw = peaks(device_kind)
+    t_c = flops / hw["peak_flops"]
+    t_m = bytes_acc / hw["hbm_bw"]
+    t_x = wire / hw["ici_bw"]
     terms = {"compute_s": t_c, "memory_s": t_m, "collective_s": t_x}
     dom = max(terms, key=terms.get)
     return {**terms, "dominant": dom.replace("_s", ""),

@@ -16,7 +16,7 @@ program, visible in its jaxpr before anything runs:
   than a per-device block (**CollectiveAudit**).
 
 This module provides the one shared IR walker (:func:`walk_frames` /
-:func:`iter_equations`) -- recursing into scan/while/cond/pjit/shard_map
+:func:`iter_equations`) -- recursing into scan/while/cond/jit/shard_map
 and ``custom_vjp`` sub-jaxprs, including jaxprs reached through dict or
 nested-container params and the ``fwd_jaxpr_thunk`` callable -- plus the
 five passes.  Each violation carries a :class:`Site` naming the
@@ -42,10 +42,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tupl
 import jax
 import numpy as np
 
-try:  # jax >= 0.4.36 exposes the IR types under jax.extend.core
-    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr, Jaxpr, Literal  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 __all__ = [
     "Site",
@@ -68,16 +65,18 @@ __all__ = [
 # Primitives that open a new trace/dispatch scope when they appear at the
 # top level of an un-jitted trace.
 DISPATCH_PRIMITIVES = frozenset({
-    "pjit", "scan", "while", "cond", "shard_map", "remat2",
-    "custom_vjp_call_jaxpr", "custom_jvp_call", "custom_vjp_call",
+    "jit", "scan", "while", "cond", "shard_map", "remat2",
+    "custom_jvp_call", "custom_vjp_call",
 })
 
-# PRNG primitives.  ``random_bits`` is the consumption point jax 0.4.x
-# traces `jax.random.*` draws into; raw threefry shows up only in
-# lowered/legacy paths but is handled for completeness.
+# PRNG primitives.  ``random_bits`` is the consumption point jax traces
+# `jax.random.*` draws into; raw threefry shows up only in lowered paths but
+# is handled for completeness.
 RANDOM_CONSUMERS = frozenset({"random_bits", "threefry2x32"})
 
-COLLECTIVE_REDUCERS = frozenset({"psum", "psum2"})
+# ``psum_invariant`` is the psum a shard_map with replication (vma) checking
+# traces; ``psum`` the one without.
+COLLECTIVE_REDUCERS = frozenset({"psum", "psum_invariant"})
 COLLECTIVE_GATHERS = frozenset({"all_gather", "all_to_all"})
 
 _SUB_JAXPR_DEPTH = 6  # containers nested deeper than this are not scanned
@@ -113,11 +112,11 @@ def _eqn_site(eqn: Any, path: Tuple[str, ...]) -> Site:
     try:  # private, best-effort: violations still render without it
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             file = frame.file_name
             line = frame.start_line
-            function = frame.function_name
+            function = frame.function_name.rsplit(".", 1)[-1]  # qualname
     except Exception:  # pragma: no cover - jax internals moved
         pass
     return Site(name, path, file, line, function)
@@ -195,20 +194,16 @@ def _custom_vjp_fwd_jaxpr(eqn: Any) -> Optional[Jaxpr]:
     it.  The thunk takes one symbolic-zero flag per primal input.
     """
     thunk = eqn.params.get("fwd_jaxpr_thunk")
-    if not callable(thunk):
+    fun = eqn.params.get("call_jaxpr")
+    if thunk is None or not isinstance(fun, ClosedJaxpr):
         return None
-    fun = eqn.params.get("fun_jaxpr")
-    n = len(fun.jaxpr.invars) if isinstance(fun, ClosedJaxpr) else len(eqn.invars)
-    n -= int(eqn.params.get("num_consts", 0) or 0)
-    for count in (n, len(eqn.invars), 0):
-        try:
-            out = thunk(*([False] * max(count, 0)))
-        except Exception:
-            continue
-        if isinstance(out, tuple) and out and isinstance(out[0], (Jaxpr, ClosedJaxpr)):
-            return _as_jaxpr(out[0])
-        if isinstance(out, (Jaxpr, ClosedJaxpr)):
-            return _as_jaxpr(out)
+    n = len(fun.jaxpr.invars) - int(eqn.params.get("num_consts", 0) or 0)
+    try:
+        out = thunk.call_wrapped(*([False] * max(n, 0)))
+    except Exception:
+        return None
+    if isinstance(out, tuple) and out and isinstance(out[0], (Jaxpr, ClosedJaxpr)):
+        return _as_jaxpr(out[0])
     return None
 
 
@@ -220,7 +215,7 @@ def eqn_subjaxprs(eqn: Any) -> List[Tuple[str, Jaxpr]]:
         if id(sub) not in seen:
             seen.add(id(sub))
             out.append((eqn.primitive.name, sub))
-    if eqn.primitive.name in ("custom_vjp_call_jaxpr", "custom_vjp_call"):
+    if eqn.primitive.name == "custom_vjp_call":
         fwd = _custom_vjp_fwd_jaxpr(eqn)
         if fwd is not None and id(fwd) not in seen:
             out.append((f"{eqn.primitive.name}.fwd", fwd))
@@ -436,7 +431,7 @@ def dispatch_count(jaxpr: Any,
     """Count top-level dispatches and (optionally) producer invocations.
 
     A fused streamed pipeline is a *single* top-level equation (one
-    ``pjit``/``scan``); every extra top-level eqn is an extra device
+    ``jit``/``scan``); every extra top-level eqn is an extra device
     dispatch.  ``producer_calls`` comes from a :class:`CallCounter`
     wrapped around the block producer before tracing -- trace-time call
     count is the static number of producer inlinings.
@@ -500,7 +495,7 @@ class _KeyProvenance:
     Two key operands with identical signatures were produced by the same
     static computation from the same roots -- consuming randomness from
     both is a key-reuse bug.  Signatures follow dataflow across frame
-    boundaries (pjit/scan-const operands bind through; scan carries and
+    boundaries (jit/scan-const operands bind through; scan carries and
     xs are per-loop opaque roots, so a single in-loop consumption of a
     per-iteration key slice is *not* flagged, while two distinct
     consumption sites of the same carried key are).

@@ -35,6 +35,8 @@ from .virtualization import MCAGeometry, zero_padding
 from .write_verify import WriteStats
 
 __all__ = [
+    "PRECISION",
+    "matmul",
     "CrossbarConfig",
     "encode_tiled",
     "write_cost",
@@ -64,6 +66,18 @@ __all__ = [
     "corrected_mvm",
     "streamed_corrected_mvm",
 ]
+
+
+#: Precision of every float32 product in the simulated dataflow.  A TPU's
+#: default float32 matmul is a single bf16 pass (~3 significant digits),
+#: coarser than the tier-1 correction ``dA x_tilde`` it would carry; HIGHEST
+#: keeps the products at float32, as on the CPU backend.
+PRECISION = jax.lax.Precision.HIGHEST
+
+
+def matmul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a @ b`` at :data:`PRECISION`."""
+    return jnp.matmul(a, b, precision=PRECISION)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,8 +128,11 @@ def encode_tiled(
 
 
 def _encode_vec(x: jnp.ndarray, key: jax.Array, cfg: CrossbarConfig) -> jnp.ndarray:
+    """Input-DAC encode of (n,) or (n, batch) inputs.  Each column is its own
+    DAC write with its own range, so a batched MVM quantizes every column
+    exactly as it would be quantized alone."""
     dev = cfg.device
-    q = quantize(x, dev.levels, axis=None)
+    q = quantize(x, dev.levels, axis=0)
     sigma = effective_sigma(dev, cfg.k_iters).astype(x.dtype)
     eta = jax.random.normal(key, x.shape, dtype=x.dtype)
     return q * (1.0 + sigma * eta)
@@ -285,23 +302,27 @@ def program_blocks(
     Returns ``(at_blocks, da_blocks)``, both (mb, nb, cap_m, cap_n):
     the per-block conductance images ``A_tilde`` and the tier-1 correction
     operands ``dA = A - A_tilde`` (paper Eq. 7, with the first-order product
-    rewritten as  p = A_tilde x + dA x_tilde).
+    rewritten as  p = A_tilde x + dA x_tilde).  Block rows are encoded one
+    after another, so programming needs O(one block row) of memory beyond
+    ``a`` and the two outputs.
     """
     cap_m, cap_n = cfg.geom.capacity
     a_pad = zero_padding(a, cfg.geom)
     mp, np_ = a_pad.shape
     mb, nb = mp // cap_m, np_ // cap_n
-    blocks = a_pad.reshape(mb, cap_m, nb, cap_n).transpose(0, 2, 1, 3)
     keys = block_keys(key, mb, nb)
 
-    def enc_row(row_blocks, row_keys):
-        def enc_one(a_blk, k):
-            k_a, _ = jax.random.split(k)
-            return encode_tiled(a_blk, k_a, cfg)
-        return jax.vmap(enc_one)(row_blocks, row_keys)
+    def enc_one(a_blk, k):
+        k_a, _ = jax.random.split(k)
+        return encode_tiled(a_blk, k_a, cfg)
 
-    at_blocks = jax.vmap(enc_row)(blocks, keys)
-    return at_blocks, blocks - at_blocks
+    def enc_row(ops):
+        band, row_keys = ops
+        row_blocks = band.reshape(cap_m, nb, cap_n).transpose(1, 0, 2)
+        at_row = jax.vmap(enc_one)(row_blocks, row_keys)
+        return at_row, row_blocks - at_row
+
+    return jax.lax.map(enc_row, (a_pad.reshape(mb, cap_m, np_), keys))
 
 
 def programmed_block_mvm(
@@ -342,15 +363,15 @@ def programmed_block_mvm(
             _, k_x = jax.random.split(k)
             x_t = _encode_vec(x_blk, k_x, cfg) if cfg.encode_inputs else x_blk
             if not cfg.ec:
-                return at_blk @ x_t
+                return matmul(at_blk, x_t)
             if use_kernel:
                 from repro.kernels import ops as kops
                 return kops.rram_ec_tile_mvm(x_blk, x_t, at_blk, da_blk)
             if cfg.ec_mode == "faithful":
                 # The paper's three analog products, with A = A_tilde + dA.
-                return (at_blk @ x_blk + (at_blk + da_blk) @ x_t
-                        - at_blk @ x_t)
-            return at_blk @ x_blk + da_blk @ x_t             # fused, 2 matmuls
+                return (matmul(at_blk, x_blk) + matmul(at_blk + da_blk, x_t)
+                        - matmul(at_blk, x_t))
+            return matmul(at_blk, x_blk) + matmul(da_blk, x_t)  # fused, 2
         partials = jax.vmap(per_col)(at_row, da_row, x_chunks, row_keys)
         return jnp.sum(partials, axis=0)                     # sum over column blocks
 
@@ -401,15 +422,16 @@ def programmed_block_rmvm(
             _, k_x = jax.random.split(k)
             y_t = _encode_vec(y_blk, k_x, cfg) if cfg.encode_inputs else y_blk
             if not cfg.ec:
-                return at_blk.T @ y_t
+                return matmul(at_blk.T, y_t)
             if use_kernel:
                 from repro.kernels import ops as kops
                 return kops.rram_ec_tile_rmvm(y_blk, y_t, at_blk, da_blk)
             if cfg.ec_mode == "faithful":
                 # The paper's three analog products, transposed.
-                return (at_blk.T @ y_blk + (at_blk + da_blk).T @ y_t
-                        - at_blk.T @ y_t)
-            return at_blk.T @ y_blk + da_blk.T @ y_t         # fused, 2 matmuls
+                return (matmul(at_blk.T, y_blk)
+                        + matmul((at_blk + da_blk).T, y_t)
+                        - matmul(at_blk.T, y_t))
+            return matmul(at_blk.T, y_blk) + matmul(da_blk.T, y_t)  # fused
         partials = jax.vmap(per_row)(at_col, da_col, y_chunks, col_keys)
         return jnp.sum(partials, axis=0)                     # sum over row blocks
 
@@ -830,15 +852,16 @@ def streamed_block_mvm(
                 a_blk = block_fn(i, j) if cfg.ec else None
             x_t = _encode_vec(x_blk, k_x, cfg) if cfg.encode_inputs else x_blk
             if not cfg.ec:
-                return acc + at_blk @ x_t, None
+                return acc + matmul(at_blk, x_t), None
             if use_kernel:
                 from repro.kernels import ops as kops
                 return acc + kops.rram_ec_tile_mvm(
                     x_blk, x_t, at_blk, a_blk - at_blk), None
             if cfg.ec_mode == "faithful":
-                return acc + (at_blk @ x_blk + a_blk @ x_t
-                              - at_blk @ x_t), None
-            return acc + (at_blk @ x_blk + (a_blk - at_blk) @ x_t), None
+                return acc + (matmul(at_blk, x_blk) + matmul(a_blk, x_t)
+                              - matmul(at_blk, x_t)), None
+            return acc + (matmul(at_blk, x_blk)
+                          + matmul(a_blk - at_blk, x_t)), None
 
         acc0 = jnp.zeros((cap_m, batch), jnp.float32)
         col_xs = (row_keys, j0 + jnp.arange(nb), x_chunks) if oneshot else \
@@ -925,15 +948,16 @@ def streamed_block_rmvm(
                 a_blk = block_fn(i, j) if cfg.ec else None
             y_t = _encode_vec(y_blk, k_x, cfg) if cfg.encode_inputs else y_blk
             if not cfg.ec:
-                return acc + at_blk.T @ y_t, None
+                return acc + matmul(at_blk.T, y_t), None
             if use_kernel:
                 from repro.kernels import ops as kops
                 return acc + kops.rram_ec_tile_rmvm(
                     y_blk, y_t, at_blk, a_blk - at_blk), None
             if cfg.ec_mode == "faithful":
-                return acc + (at_blk.T @ y_blk + a_blk.T @ y_t
-                              - at_blk.T @ y_t), None
-            return acc + (at_blk.T @ y_blk + (a_blk - at_blk).T @ y_t), None
+                return acc + (matmul(at_blk.T, y_blk) + matmul(a_blk.T, y_t)
+                              - matmul(at_blk.T, y_t)), None
+            return acc + (matmul(at_blk.T, y_blk)
+                          + matmul((a_blk - at_blk).T, y_t)), None
 
         acc0 = jnp.zeros((cap_n, batch), jnp.float32)
         row_xs = (col_keys, i0 + jnp.arange(mb), y_chunks) if oneshot else \

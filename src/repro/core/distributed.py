@@ -44,14 +44,13 @@ reported as the mean across MCAs (mean across devices here).
 """
 from __future__ import annotations
 
-import warnings
 from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
 from .crossbar import (CrossbarConfig, input_write_cost, local_dense_mvm,
                        local_dense_rmvm, local_program_dense,
                        matrix_write_cost, streamed_block_mvm,
@@ -101,10 +100,9 @@ def mesh_grid_shape(mesh: Mesh, row_axes: Tuple[str, ...],
 
 def _row_index(row_axes: Tuple[str, ...]) -> jax.Array:
     """This device's row-shard index: row-major over ``row_axes`` (in-trace)."""
-    from .compat import axis_size
     idx = jnp.int32(0)
     for ax in row_axes:
-        idx = idx * axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 
@@ -584,45 +582,40 @@ def make_distributed_streamed_rmvm(
     )
 
 
-# Cached capability probes: (backend, mesh shape) -> bool.
-_PALLAS_PROBE_CACHE: dict = {}
+# Probes that passed, keyed (backend, mesh shape).
+_PALLAS_PROBE_CACHE: set = set()
 
 
 def pallas_shard_map_supported(mesh: Mesh) -> bool:
-    """Can the fused Pallas EC tile step lower inside ``shard_map`` here?
+    """Check that the fused Pallas EC tile step lowers inside ``shard_map``.
 
     Compiles (never runs) a one-tile :func:`repro.kernels.ops.rram_ec_tile_mvm`
-    wrapped in a trivial shard_map over ``mesh``.  On CPU the kernels run in
-    interpret mode and this always succeeds; on accelerator backends whose
-    Mosaic/Triton lowering rejects the manual-sharding context, the probe
-    fails once per (backend, mesh shape), emits a warning, and the engine
-    falls back to the reference tile step inside the same scan pipeline --
-    the documented behavior of ``backend="pallas"`` +
-    ``execution="distributed"`` (numerics are identical either way; only the
-    kernel fusion is lost).
+    wrapped in a trivial shard_map over ``mesh``, once per (backend, mesh
+    shape), and returns True.  On the CPU backend the kernels run in
+    interpret mode and this always passes; on a TPU the kernel lowers through
+    Mosaic.  If it cannot lower, this raises: ``backend="pallas"`` never
+    falls back to the reference tile step in silence, which would present a
+    reference run as a kernel run.
     """
     cache_key = (jax.default_backend(), tuple(mesh.devices.shape))
     if cache_key in _PALLAS_PROBE_CACHE:
-        return _PALLAS_PROBE_CACHE[cache_key]
+        return True
+    from repro.kernels import ops as kops
+
+    def local(x):
+        eye = jnp.eye(8, dtype=jnp.float32)
+        return kops.rram_ec_tile_mvm(x, x, eye, jnp.zeros_like(eye))
+
+    probe = shard_map(local, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                      check_vma=False)
     try:
-        from repro.kernels import ops as kops
-
-        def local(x):
-            eye = jnp.eye(8, dtype=jnp.float32)
-            return kops.rram_ec_tile_mvm(x, x, eye, jnp.zeros_like(eye))
-
-        probe = shard_map(local, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                          check_vma=False)
         jax.jit(probe).lower(jnp.zeros((8, 1), jnp.float32)).compile()
-        ok = True
-    except Exception as exc:  # pragma: no cover - backend-specific
-        warnings.warn(
-            "backend='pallas' cannot lower inside shard_map on this "
-            f"backend/mesh ({exc!r}); distributed execution falls back to "
-            "the reference tile step (same numerics, no kernel fusion)")
-        ok = False
-    _PALLAS_PROBE_CACHE[cache_key] = ok
-    return ok
+    except Exception as exc:
+        raise RuntimeError(
+            "backend='pallas' cannot lower the EC tile kernel inside "
+            f"shard_map on {cache_key}; use backend='reference'") from exc
+    _PALLAS_PROBE_CACHE.add(cache_key)
+    return True
 
 
 def distributed_corrected_mvm(

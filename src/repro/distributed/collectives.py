@@ -20,7 +20,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import axis_size, pvary
 
 __all__ = ["int8_quantize", "int8_dequantize", "compressed_psum",
            "ring_collective_matmul"]
@@ -72,7 +71,7 @@ def ring_collective_matmul(
     Must be called inside shard_map with ``axis_name`` bound; w is k-sharded
     over that axis.
     """
-    n_dev = axis_size(axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     k_local = w_local.shape[0]
     perm = [(j, (j + 1) % n_dev) for j in range(n_dev)]
@@ -90,6 +89,6 @@ def ring_collective_matmul(
                      jnp.promote_types(x.dtype, jnp.float32))
     # The accumulator is device-varying (it mixes ring-rotated shards):
     # mark it so the loop carry types match under shard_map's vma tracking.
-    acc0 = pvary(acc0, axis_name)
+    acc0 = jax.lax.pcast(acc0, axis_name, to="varying")
     acc, _ = jax.lax.fori_loop(0, n_dev, body, (acc0, w_local))
     return acc.astype(x.dtype)

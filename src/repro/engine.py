@@ -57,14 +57,14 @@ either direction).  The pallas tile step reads the same fused kernel in the
 ``y^T A`` direction (:func:`repro.kernels.ops.rram_ec_tile_rmvm`); see
 DESIGN.md section 5.
 
-``backend="pallas"`` under ``execution="distributed"`` is gated by
+``backend="pallas"`` under ``execution="distributed"`` is checked by
 :func:`repro.core.distributed.pallas_shard_map_supported`, a compile-only
 probe run once per (backend, mesh shape): where the kernel cannot lower
-inside shard_map the engine warns and falls back to the reference tile step
-in the same scan pipeline -- identical numerics, only the kernel fusion is
-lost.  Producer-driven distributed programming requires the block grid to
-divide evenly over the mesh (``mb % R == 0``, ``nb % C == 0``; row/column
-sizes must be capacity multiples on axes split more than one way).
+inside shard_map it raises -- a pallas engine never runs the reference tile
+step in its place.  Producer-driven distributed programming requires the
+block grid to divide evenly over the mesh (``mb % R == 0``, ``nb % C == 0``;
+row/column sizes must be capacity multiples on axes split more than one
+way).
 
 ``program(block_fn, ..., resident=False)`` (distributed only) keeps NO
 conductance image resident: every MVM re-encodes each block inside the scan
@@ -151,6 +151,7 @@ from typing import Callable, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import crossbar
 from repro.core.crossbar import CrossbarConfig
@@ -219,6 +220,12 @@ def _scan_cache(handle) -> _BoundedCache:
     return handle._scan_exec
 
 
+def _traced(x) -> bool:
+    """Whether ``x`` is a tracer, i.e. the caller is inside a jit trace (or
+    another transformation) where host-side state must not advance."""
+    return isinstance(x, jax.core.Tracer)
+
+
 def _scale_stats(stats: WriteStats, factor: float) -> WriteStats:
     """``factor`` members' worth of one member's :class:`WriteStats`."""
     return WriteStats(
@@ -265,8 +272,6 @@ class AnalogMatrix:
     # dispatch, and host-side executes advance the per-block MVM count.
     age: Optional["object"] = None
     calls: int = 0
-    # cached dense padded layout for the pallas backend (built on first use).
-    _padded: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
     # per-handle jitted scan pipelines: a _BoundedCache LRU keyed by
     # (backend, direction, batch bucket), built on first execute; dies with
     # the handle -- see the jit-scoping note below.
@@ -356,33 +361,22 @@ class AnalogMatrix:
     def image_nbytes(self) -> int:
         """Resident bytes of this handle's programmed operands.
 
-        Counts the stored image/correction layout (blocks or dense) plus any
-        derived caches built by executions (padded pallas layout); block_fn
-        producers are code, not residency, and count zero.  This is the unit
-        the serving :class:`~repro.serving.cache.ImageCache` budgets in."""
+        Counts the stored image/correction layout (blocks or dense); every
+        backend executes from that layout in place, and block_fn producers
+        are code, not residency, and count zero.  This is the unit the
+        serving :class:`~repro.serving.cache.ImageCache` budgets in."""
         total = 0
         for arr in (self.at_blocks, self.da_blocks, self.at_dense,
                     self.da_dense):
             if arr is not None and hasattr(arr, "nbytes"):
                 total += int(arr.nbytes)
-        if self._padded is not None:
-            total += sum(int(p.nbytes) for p in self._padded
-                         if hasattr(p, "nbytes"))
         return total
 
-    def release(self) -> int:
-        """Drop derived execution caches (padded layout, jitted scan
-        pipelines), returning the bytes freed.  The programmed image itself
-        survives -- eviction of the image is the cache owner dropping its
-        reference to the whole handle; ``release`` is the cheaper lever for
-        staying under budget without paying a reprogram."""
-        freed = 0
-        if self._padded is not None:
-            freed = sum(int(p.nbytes) for p in self._padded
-                        if hasattr(p, "nbytes"))
-            self._padded = None
+    def release(self) -> None:
+        """Drop the jitted execute pipelines cached on the handle.  The
+        programmed image itself survives -- eviction of the image is the
+        cache owner dropping its reference to the whole handle."""
         self._scan_exec = None
-        return freed
 
 
 @dataclasses.dataclass(frozen=True)
@@ -480,7 +474,6 @@ class AnalogMatrixGroup:
     # every member inside the same single dispatch.
     ages: Optional["object"] = None
     calls: int = 0
-    _padded: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
     _scan_exec: Optional["_BoundedCache"] = None
 
     @property
@@ -534,30 +527,22 @@ class AnalogMatrixGroup:
 
     @property
     def image_nbytes(self) -> int:
-        """Resident bytes of the stacked operands plus derived caches."""
+        """Resident bytes of the stacked operands."""
         total = 0
         for arr in (self.at_blocks, self.da_blocks, self.at_dense,
                     self.da_dense):
             if arr is not None and hasattr(arr, "nbytes"):
                 total += int(arr.nbytes)
-        if self._padded is not None:
-            total += sum(int(p.nbytes) for p in self._padded
-                         if hasattr(p, "nbytes"))
         return total
 
-    def release(self) -> int:
-        """Drop derived execution caches (padded stack, jitted grouped
-        pipelines), returning the bytes freed; the programmed stack stays."""
-        freed = 0
-        if self._padded is not None:
-            freed = sum(int(p.nbytes) for p in self._padded
-                        if hasattr(p, "nbytes"))
-            self._padded = None
+    def release(self) -> None:
+        """Drop the jitted grouped pipelines cached on the group; the
+        programmed stack stays."""
         self._scan_exec = None
-        return freed
 
 
 _assemble = crossbar.assemble_blocks
+_program_local = jax.jit(crossbar.program_blocks, static_argnames=("cfg",))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "m", "n"))
@@ -591,17 +576,20 @@ def _exec_reference_aged(at_blocks, da_blocks, xb, key, age, *, cfg, m, n,
 def _pallas_corrected(at, da, xb, key, cfg, m, n, transpose):
     """Shared Pallas execute body (unjitted; used solo-jitted and grouped).
 
-    ``at``/``da`` are the dense *padded* operands.  The kernel path encodes
-    the input with a single DAC pass (one noise draw for the whole padded
-    vector -- fold 1 of the call key forward, fold 2 transposed, keeping the
-    directions distinct when a caller reuses a key) instead of the reference
-    path's per-(block, chunk) draws -- statistically identical, one kernel
-    launch: ``y^T = x^T At^T + xt^T dA^T`` forward,
-    ``z^T = y^T At + yt^T dA`` backwards through the same operands.
+    ``at``/``da`` are the stored (mb, nb, cap_m, cap_n) capacity-block
+    stacks, which the kernel reads in place: no dense or transposed copy of
+    the image is ever made.  The kernel path encodes the input with a single
+    DAC pass (one noise draw for the whole padded vector -- fold 1 of the
+    call key forward, fold 2 transposed, keeping the directions distinct
+    when a caller reuses a key) instead of the reference path's
+    per-(block, chunk) draws -- statistically identical, one kernel launch:
+    ``At x + dA xt`` forward, ``z^T = y^T At + yt^T dA`` backwards through
+    the same operands.
     """
     from repro.kernels import ops as kops
 
-    pad_to = at.shape[0] if transpose else at.shape[1]
+    mp, np_ = kops.matrix_shape(at)
+    pad_to = mp if transpose else np_
     x_pad = jnp.pad(xb, ((0, pad_to - xb.shape[0]), (0, 0)))
     if cfg.encode_inputs:
         fold = 2 if transpose else 1
@@ -612,9 +600,11 @@ def _pallas_corrected(at, da, xb, key, cfg, m, n, transpose):
         if transpose:
             p = kops.rram_ec_matmul(x_pad.T, x_t.T, at, da).T[:n]
         else:
-            p = kops.rram_ec_matmul(x_pad.T, x_t.T, at.T, da.T).T[:m]
+            p = kops.rram_ec_matmul(at, da, x_pad, x_t)[:m]
     else:
-        p = (at.T @ x_t)[:n] if transpose else (at @ x_t)[:m]
+        dense = _assemble(at, mp, np_)
+        p = crossbar.matmul(dense.T, x_t)[:n] if transpose \
+            else crossbar.matmul(dense, x_t)[:m]
     if cfg.ec:
         if cfg.denoise_method == "neumann":
             p = kops.denoise_stencil(p, lam=cfg.lam, h=cfg.h)
@@ -629,15 +619,15 @@ def _pallas_corrected(at, da, xb, key, cfg, m, n, transpose):
 @functools.partial(jax.jit, static_argnames=("cfg", "m", "n"))
 def _exec_pallas(at, da, xb, key, *, cfg, m, n):
     """Tier-1 via the fused Pallas EC kernel + tier-2 via the solver kernels
-    (see :func:`_pallas_corrected`); ``at``/``da`` are the dense padded
-    operands assembled once at first use and cached on the handle."""
+    (see :func:`_pallas_corrected`); ``at``/``da`` are the handle's stored
+    capacity-block stacks."""
     return _pallas_corrected(at, da, xb, key, cfg, m, n, transpose=False)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "m", "n"))
 def _exec_pallas_t(at, da, yb, key, *, cfg, m, n):
     """Transposed tier-1 via the same fused Pallas EC kernel read backwards
-    (one padded-operand cache on the handle serves both directions)."""
+    over the same stored block stacks."""
     return _pallas_corrected(at, da, yb, key, cfg, m, n, transpose=True)
 
 
@@ -723,10 +713,9 @@ class AnalogEngine:
     backend:
         ``"reference"`` (pure jnp) | ``"pallas"`` (fused TPU kernels; interpret
         mode on CPU).  Under ``execution="distributed"`` the Pallas tile step
-        runs inside ``shard_map`` where the capability probe
-        (:func:`repro.core.distributed.pallas_shard_map_supported`) confirms
-        it lowers; otherwise the engine warns once and falls back to the
-        reference tile step (identical numerics).
+        runs inside ``shard_map``; the capability probe
+        (:func:`repro.core.distributed.pallas_shard_map_supported`) raises
+        where it cannot lower.
     mesh, row_axes, col_axis:
         Mesh placement for ``execution="distributed"``: rows shard over
         ``row_axes``, the contraction over ``col_axis``.
@@ -838,7 +827,7 @@ class AnalogEngine:
         m, n = a.shape
         if self.execution == "distributed":
             return self._program_distributed(a, key)
-        at_blocks, da_blocks = crossbar.program_blocks(a, key, self.cfg)
+        at_blocks, da_blocks = _program_local(a, key, cfg=self.cfg)
         return AnalogMatrix(
             engine=self, shape=(m, n), base_key=key,
             write_stats=crossbar.matrix_write_cost(m, n, self.cfg),
@@ -1037,12 +1026,11 @@ class AnalogEngine:
 
     def _program_group_distributed(self, stack, key, member_keys
                                    ) -> AnalogMatrixGroup:
-        from jax.sharding import NamedSharding, PartitionSpec
         from repro.core import distributed as D
         size, m, n = stack.shape
         row_spec = self.row_axes if len(self.row_axes) > 1 else self.row_axes[0]
         a_sh = jax.device_put(stack, NamedSharding(
-            self.mesh, PartitionSpec(None, row_spec, self.col_axis)))
+            self.mesh, P(None, row_spec, self.col_axis)))
         prog = self._dist_mvm_cache.get("group_program")
         if prog is None:
             prog = jax.jit(D.make_distributed_group_program(
@@ -1237,7 +1225,7 @@ class AnalogEngine:
             raise ValueError(
                 f"chain_mvm: input has {xb.shape[0]} rows but the members "
                 f"are {G.m} x {G.n}")
-        keys = self._group_keys(G, key)
+        keys = self._group_keys(G, key, xb)
         G.calls += 1
         use_kernel = self.backend == "pallas" and self.cfg.ec
         y = _exec_chain(G.at_blocks, G.da_blocks, xb, keys, cfg=self.cfg,
@@ -1245,7 +1233,7 @@ class AnalogEngine:
                         use_kernel=use_kernel)
         return y[:, 0] if squeeze else y
 
-    def _group_keys(self, G: AnalogMatrixGroup, key) -> jax.Array:
+    def _group_keys(self, G: AnalogMatrixGroup, key, x) -> jax.Array:
         """Per-member execute keys: explicit ``key`` fans out as
         ``fold_in(key, g)``; the default schedule folds each member's base
         key by the call counter, matching the solo per-handle schedule
@@ -1253,7 +1241,7 @@ class AnalogEngine:
         if key is not None:
             return jax.vmap(lambda g: jax.random.fold_in(key, g))(
                 jnp.arange(G.size))
-        if not getattr(jax.core, "trace_state_clean", lambda: True)():
+        if _traced(x):
             raise ValueError(
                 "engine.group_mvm inside jit needs an explicit key= (the "
                 "default call-counter key schedule is host-side state)")
@@ -1307,7 +1295,7 @@ class AnalogEngine:
                 "the group holds mesh-sharded operands but this engine "
                 f"executes {self.execution!r}; build it with this engine")
         xb, squeeze = self._group_input(G, x, transpose)
-        keys = self._group_keys(G, key)
+        keys = self._group_keys(G, key, x)
         G.calls += 1
         m, n = G.shape
         batch = xb.shape[2]
@@ -1323,7 +1311,7 @@ class AnalogEngine:
             p = _exec_group_reference_aged(
                 G.at_blocks, G.da_blocks, xb, keys, G.ages,
                 cfg=self.cfg, m=m, n=n, transpose=transpose)
-            if getattr(jax.core, "trace_state_clean", lambda: True)():
+            if not _traced(p):
                 G.ages = G.ages.advanced(1)
         elif G.da_blocks is None:
             # Streamed group: dA re-derived per block from each member's
@@ -1341,16 +1329,9 @@ class AnalogEngine:
                 cache.put(cache_key, fn)
             p = fn(G.at_blocks, xb, keys)
         elif self.backend == "pallas":
-            padded = G._padded
-            if padded is None:
-                _, mb, nb, cm, cn = G.at_blocks.shape
-                asm = jax.vmap(
-                    functools.partial(_assemble, m=mb * cm, n=nb * cn))
-                padded = (asm(G.at_blocks), asm(G.da_blocks))
-                if getattr(jax.core, "trace_state_clean", lambda: False)():
-                    G._padded = padded
-            p = _exec_group_pallas(*padded, xb, keys, cfg=self.cfg,
-                                   m=m, n=n, transpose=transpose)
+            p = _exec_group_pallas(G.at_blocks, G.da_blocks, xb, keys,
+                                   cfg=self.cfg, m=m, n=n,
+                                   transpose=transpose)
         else:
             p = _exec_group_reference(G.at_blocks, G.da_blocks, xb, keys,
                                       cfg=self.cfg, m=m, n=n,
@@ -1474,7 +1455,7 @@ class AnalogEngine:
             # jit trace it would freeze at its trace-time value and every
             # execution would reuse identical DAC noise -- require an explicit
             # key there instead of silently correlating the draws.
-            if not getattr(jax.core, "trace_state_clean", lambda: True)():
+            if _traced(xb):
                 raise ValueError(
                     "engine.mvm inside jit needs an explicit key= (the "
                     "default call-counter key schedule is host-side state)")
@@ -1507,7 +1488,7 @@ class AnalogEngine:
                 # Host-dispatched executes age the image by one read disturb
                 # per call; traced executes (inside a solver's jit) advance
                 # the ledger explicitly via A.age = A.age.advanced(mvms).
-                if getattr(jax.core, "trace_state_clean", lambda: True)():
+                if not _traced(p):
                     A.age = A.age.advanced(1)
             elif A.age is not None:
                 raise ValueError(
@@ -1518,22 +1499,9 @@ class AnalogEngine:
                 # Streamed handle: dA is not resident; re-derive per block.
                 p = self._exec_streamed(A, xb, key, transpose)
             elif self.backend == "pallas":
-                if A._padded is None:
-                    mb, nb, cm, cn = A.at_blocks.shape
-                    padded = (_assemble(A.at_blocks, mb * cm, nb * cn),
-                              _assemble(A.da_blocks, mb * cm, nb * cn))
-                    # Only cache outside a trace: caching mid-trace would pin
-                    # tracers on the handle and leak them into later calls
-                    # (e.g. a solver's while_loop executing many MVMs).  If
-                    # this jax has no trace_state_clean, skip caching -- the
-                    # safe direction is recompute, never cache a maybe-tracer.
-                    if getattr(jax.core, "trace_state_clean",
-                               lambda: False)():
-                        A._padded = padded
-                else:
-                    padded = A._padded
                 run = _exec_pallas_t if transpose else _exec_pallas
-                p = run(*padded, xb, key, cfg=self.cfg, m=m, n=n)
+                p = run(A.at_blocks, A.da_blocks, xb, key,
+                        cfg=self.cfg, m=m, n=n)
             else:
                 run = _exec_reference_t if transpose else _exec_reference
                 p = run(A.at_blocks, A.da_blocks, xb, key,
@@ -1600,6 +1568,13 @@ class AnalogEngine:
                 m=m, n=n, mb=mb, nb=nb, resident=A.at_blocks is not None,
                 use_kernel=use_kernel))
             cache.put(cache_key, fn)
+        # Place the input as the shard_map takes it.  Its type then carries
+        # the mesh on every call, so a solver's first input and its loop
+        # carries (shard_map outputs) hit one trace of the pipeline.
+        row_spec = self.row_axes if len(self.row_axes) > 1 \
+            else self.row_axes[0]
+        xb = jax.lax.with_sharding_constraint(xb, NamedSharding(
+            self.mesh, P(row_spec if transpose else self.col_axis, None)))
         if A.at_blocks is not None:
             return fn(A.at_blocks, xb, key)
         return fn(xb, key)

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode -- the
+On a TPU the kernels lower through Mosaic.  On the CPU backend (the test
+suite, run with ``JAX_PLATFORMS=cpu``) they execute in interpret mode -- the
 kernel body runs as traced JAX ops per grid point, validating the exact TPU
-dataflow.  On TPU backends the same calls lower through Mosaic.  The wrappers
+dataflow; :func:`on_cpu` is the only place that mode is chosen.  The wrappers
 handle padding to block multiples and un-padding, so callers pass natural
 shapes.
 """
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 from .rram_mvm import DEFAULT_BLOCK_K, DEFAULT_BLOCK_M, DEFAULT_BLOCK_N
 from .rram_mvm import ec_matmul as _ec_matmul
 from .rram_mvm import encode_matmul as _encode_matmul
+from .rram_mvm import matrix_shape
 from .solver_update import cg_update as _cg_update
 from .solver_update import richardson_update as _richardson_update
 from .tridiag import stencil_denoise as _stencil
@@ -36,6 +38,7 @@ __all__ = [
 
 
 def on_cpu() -> bool:
+    """Whether the kernels must run interpreted (the CPU backend)."""
     return jax.default_backend() == "cpu"
 
 
@@ -52,6 +55,11 @@ def _pick_blocks(m, k, n, bm, bk, bn):
     """Shrink default blocks for small problems (keeps interpret tests fast and
     avoids padding a 66x66 paper matrix to 512^2)."""
     return min(bm, max(8, m)), min(bk, max(8, k)), min(bn, max(8, n))
+
+
+def _fit(block: int, cap: int) -> int:
+    """A tile extent that divides the capacity-block extent ``cap``."""
+    return block if cap % block == 0 else cap
 
 
 def rram_encode_matmul(
@@ -81,26 +89,33 @@ def rram_encode_matmul(
 
 
 def rram_ec_matmul(
-    x: jnp.ndarray,
-    x_tilde: jnp.ndarray,
-    w_tilde: jnp.ndarray,
-    dw: jnp.ndarray,
+    l1: jnp.ndarray,
+    l2: jnp.ndarray,
+    r1: jnp.ndarray,
+    r2: jnp.ndarray,
     *,
     block_m: int = DEFAULT_BLOCK_M,
     block_k: int = DEFAULT_BLOCK_K,
     block_n: int = DEFAULT_BLOCK_N,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Fused tier-1 EC matmul p = x @ W_tilde + x_tilde @ dW."""
-    m, k = x.shape
-    _, n = w_tilde.shape
+    """Fused tier-1 EC matmul p = l1 @ r1 + l2 @ r2.
+
+    Either operand pair may be a (mb, nb, cap_m, cap_n) capacity-block stack
+    (read in place, tiles chosen to divide the capacity block); 2-D operands
+    are padded to the tile grid and the result is un-padded.
+    """
+    m, k = matrix_shape(l1)
+    _, n = matrix_shape(r1)
     bm, bk, bn = _pick_blocks(m, k, n, block_m, block_k, block_n)
-    xp = _pad_to(x, (bm, bk))
-    xtp = _pad_to(x_tilde, (bm, bk))
-    wtp = _pad_to(w_tilde, (bk, bn))
-    dwp = _pad_to(dw, (bk, bn))
+    if l1.ndim == 4:
+        bm, bk = _fit(bm, l1.shape[2]), _fit(bk, l1.shape[3])
+    if r1.ndim == 4:
+        bk, bn = _fit(bk, r1.shape[2]), _fit(bn, r1.shape[3])
+    left = [a if a.ndim == 4 else _pad_to(a, (bm, bk)) for a in (l1, l2)]
+    right = [a if a.ndim == 4 else _pad_to(a, (bk, bn)) for a in (r1, r2)]
     out = _ec_matmul(
-        xp, xtp, wtp, dwp, block_m=bm, block_k=bk, block_n=bn,
+        *left, *right, block_m=bm, block_k=bk, block_n=bn,
         interpret=on_cpu() if interpret is None else interpret)
     return out[:m, :n]
 
@@ -116,13 +131,12 @@ def rram_ec_tile_mvm(
     """Tier-1 EC step for ONE capacity tile in the engine's (n, batch) layout.
 
     Computes ``at_blk @ x_blk + da_blk @ x_t`` as a single fused
-    :func:`rram_ec_matmul` call (the transposed y^T = x^T At^T + xt^T dA^T
-    form), so the streamed scan body and the host-loop fallback share one
-    kernel-backed tile step.  ``x_blk``/``x_t``: (cap_n, batch);
+    :func:`rram_ec_matmul` call with the tile on the left, so the streamed
+    scan body and the host-loop fallback share one kernel-backed tile step
+    and no operand is transposed.  ``x_blk``/``x_t``: (cap_n, batch);
     ``at_blk``/``da_blk``: (cap_m, cap_n).  Returns fp32 (cap_m, batch).
     """
-    return rram_ec_matmul(x_blk.T, x_t.T, at_blk.T, da_blk.T,
-                          interpret=interpret).T
+    return rram_ec_matmul(at_blk, da_blk, x_blk, x_t, interpret=interpret)
 
 
 def rram_ec_tile_rmvm(
@@ -139,7 +153,7 @@ def rram_ec_tile_rmvm(
     :func:`rram_ec_matmul` call -- the ``z^T = y^T At + y_t^T dA`` form, i.e.
     the same kernel read in the transposed direction, so the transposed
     streamed scan body and the host-loop fallback share one kernel-backed
-    tile step with the forward path's operands untouched.
+    tile step; only the (cap_m, batch) input panels are transposed.
     ``y_blk``/``y_t``: (cap_m, batch); ``at_blk``/``da_blk``:
     (cap_m, cap_n).  Returns fp32 (cap_n, batch).
     """

@@ -10,23 +10,30 @@ the virtualization reassignment loop (DESIGN.md section 2).
     fast path: one HBM read of W instead of (write W_tilde + read W_tilde).
 
   * ``ec_matmul``: the two-tier-EC serving path.  Computes the fused tier-1
-    combination p = x @ W_tilde + x_tilde @ dW (dW = W - W_tilde precomputed at
-    "programming" time), reading x/x_tilde once per tile and issuing two MXU
-    dots per block -- 33% fewer FLOPs than the paper's three analog products.
+    combination p = l1 @ r1 + l2 @ r2 -- the image pair (A_tilde, dA) on one
+    side, the input pair (x, x_tilde) on the other -- issuing two MXU dots per
+    block, 33% fewer FLOPs than the paper's three analog products.  Either
+    pair may be a (mb, nb, cap_m, cap_n) capacity-block stack, read in place
+    as the matrix it tiles, so a programmed image is never transposed or
+    re-assembled into a dense copy.
 
 Block shapes default to (512, 512) weight tiles (the paper's best-performing
 MCA cell size, conveniently 4x the 128x128 MXU tile) and 256-row activation
 panels; fp32 accumulation in the output ref across the K grid dimension.
+
+Every f32 dot runs at the simulation's matmul precision,
+:data:`repro.core.crossbar.PRECISION`.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.crossbar import PRECISION
 
 __all__ = ["encode_matmul", "ec_matmul"]
 
@@ -34,17 +41,8 @@ DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_K = 512   # MCA cell rows (contraction)
 DEFAULT_BLOCK_N = 512   # MCA cell cols (output features)
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams (and introduced
-# pltpu.InterpretParams); accept either side of the rename so the kernels run
-# on jax 0.4.x and current releases alike.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
-def _interpret_mode():
-    """Best-available interpret flag for pallas_call on this jax version."""
-    cls = getattr(pltpu, "InterpretParams", None)
-    return cls() if cls is not None else True
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # --------------------------------------------------------------------------- #
@@ -69,7 +67,8 @@ def _encode_matmul_kernel(x_ref, w_ref, eps_ref, o_ref, *, sigma, levels, nsteps
     q = jnp.round(w / scale * (levels - 1)) / (levels - 1) * scale
     w_tilde = q * (1.0 + sigma * eps_ref[...].astype(jnp.float32))
     x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(x, w_tilde, preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(x, w_tilde, preferred_element_type=jnp.float32,
+                          precision=PRECISION)
 
 
 @functools.partial(
@@ -110,8 +109,7 @@ def encode_matmul(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(x, w, eps)
 
@@ -120,8 +118,7 @@ def encode_matmul(
 # encode_matmul_rng: encode + matmul with IN-KERNEL noise generation
 # --------------------------------------------------------------------------- #
 
-def _encode_matmul_rng_kernel(seed_ref, x_ref, w_ref, o_ref, *, sigma, levels,
-                              use_prng):
+def _encode_matmul_rng_kernel(seed_ref, x_ref, w_ref, o_ref, *, sigma, levels):
     """Like _encode_matmul_kernel but the programming noise is drawn inside
     the kernel (pltpu PRNG seeded per tile + Box-Muller), so the eps array
     never exists in HBM: the weight tile is read exactly once per MCA
@@ -138,24 +135,23 @@ def _encode_matmul_rng_kernel(seed_ref, x_ref, w_ref, o_ref, *, sigma, levels,
     scale = jnp.where(scale == 0.0, 1.0, scale)
     q = jnp.round(w / scale * (levels - 1)) / (levels - 1) * scale
 
-    if use_prng:
-        pltpu.prng_seed(seed_ref[0], i, j, s_)
-        # Two uniform draws -> Box-Muller standard normal.
-        bits1 = pltpu.prng_random_bits(w.shape)
-        bits2 = pltpu.prng_random_bits(w.shape)
-        u1 = (bits1.astype(jnp.uint32) >> 8).astype(jnp.float32) / (1 << 24)
-        u2 = (bits2.astype(jnp.uint32) >> 8).astype(jnp.float32) / (1 << 24)
-        u1 = jnp.maximum(u1, 1e-7)
-        eta = jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(2.0 * jnp.pi * u2)
-    else:
-        # Old-jax generic interpreter: pltpu PRNG primitives have no CPU
-        # lowering; match the TPU interpreter's documented semantics
-        # (prng_random_bits stubbed to zeros).
-        eta = jnp.zeros_like(w)
+    # Mosaic seeds from at most two words: the seed and the linear tile id.
+    tile = (i * pl.num_programs(1) + j) * pl.num_programs(2) + s_
+    pltpu.prng_seed(seed_ref[0], tile)
+    # Two uniform draws -> Box-Muller standard normal.
+    bits1 = pltpu.prng_random_bits(w.shape)
+    bits2 = pltpu.prng_random_bits(w.shape)
+    # Top 24 bits as a non-negative int32 (Mosaic casts int32, not uint32,
+    # to float32).
+    u1 = jax.lax.shift_right_logical(bits1, 8).astype(jnp.float32) / (1 << 24)
+    u2 = jax.lax.shift_right_logical(bits2, 8).astype(jnp.float32) / (1 << 24)
+    u1 = jnp.maximum(u1, 1e-7)
+    eta = jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(2.0 * jnp.pi * u2)
 
     w_tilde = q * (1.0 + sigma * eta)
     x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(x, w_tilde, preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(x, w_tilde, preferred_element_type=jnp.float32,
+                          precision=PRECISION)
 
 
 @functools.partial(
@@ -180,8 +176,9 @@ def encode_matmul_rng(
     Validation caveat (DESIGN.md section 7): the CPU TPU-interpreter stubs
     ``prng_random_bits`` to zeros, so only the sigma=0 path (exact per-tile
     quantized matmul) and determinism are checkable off-TPU; the Box-Muller
-    noise path exercises real hardware PRNG.  ``interpret`` accepts
-    ``pltpu.InterpretParams()`` on CPU.
+    noise path exercises real hardware PRNG.  ``interpret=True`` runs the
+    TPU interpreter (``pltpu.InterpretParams()``), which lowers the PRNG
+    primitives on CPU.
     """
     m, k = x.shape
     k2, n = w.shape
@@ -189,13 +186,9 @@ def encode_matmul_rng(
     assert m % block_m == 0 and k % block_k == 0 and n % block_n == 0
     grid = (m // block_m, n // block_n, k // block_k)
     if interpret is True:
-        interpret = _interpret_mode()
-    # The generic (non-TPU) interpreter on old jax cannot lower the pltpu PRNG
-    # primitives; fall back to the zero-noise stub there.
-    use_prng = not (interpret is True and not hasattr(pltpu, "InterpretParams"))
+        interpret = pltpu.InterpretParams()
     return pl.pallas_call(
-        functools.partial(_encode_matmul_rng_kernel, sigma=sigma, levels=levels,
-                          use_prng=use_prng),
+        functools.partial(_encode_matmul_rng_kernel, sigma=sigma, levels=levels),
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -204,8 +197,7 @@ def encode_matmul_rng(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(seed, x, w)
 
@@ -214,58 +206,88 @@ def encode_matmul_rng(
 # ec_matmul: fused tier-1 error-corrected matmul
 # --------------------------------------------------------------------------- #
 
-def _ec_matmul_kernel(x_ref, xt_ref, wt_ref, dw_ref, o_ref):
-    """p_block = x @ W_tilde + x_tilde @ dW, fp32 accumulation over K grid."""
+def _ec_matmul_kernel(l1_ref, l2_ref, r1_ref, r2_ref, o_ref):
+    """p_block = l1 @ r1 + l2 @ r2, fp32 accumulation over the K grid axis."""
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    xt = xt_ref[...].astype(jnp.float32)
-    wt = wt_ref[...].astype(jnp.float32)
-    dw = dw_ref[...].astype(jnp.float32)
-    acc = jnp.dot(x, wt, preferred_element_type=jnp.float32)
-    acc += jnp.dot(xt, dw, preferred_element_type=jnp.float32)
+    f32 = jnp.float32
+    acc = jnp.dot(l1_ref[...].astype(f32), r1_ref[...].astype(f32),
+                  preferred_element_type=f32, precision=PRECISION)
+    acc += jnp.dot(l2_ref[...].astype(f32), r2_ref[...].astype(f32),
+                   preferred_element_type=f32, precision=PRECISION)
     o_ref[...] += acc
+
+
+def matrix_shape(a: jnp.ndarray):
+    """(rows, cols) of a 2-D operand or of the matrix a capacity-block stack
+    (mb, nb, cap_m, cap_n) tiles."""
+    if a.ndim == 2:
+        return a.shape
+    mb, nb, cm, cn = a.shape
+    return mb * cm, nb * cn
+
+
+def _tile_spec(a: jnp.ndarray, block, index):
+    """BlockSpec of one ``block`` tile of the matrix ``a`` holds.
+
+    ``index`` maps the grid point to the tile's (row, col) block index in the
+    matrix; for a capacity-block stack it is split into the capacity block
+    and the tile inside it (``block`` must divide the capacity block).
+    """
+    if a.ndim == 2:
+        return pl.BlockSpec(block, index)
+    _, _, cm, cn = a.shape
+    assert cm % block[0] == 0 and cn % block[1] == 0, (a.shape, block)
+    per_m, per_n = cm // block[0], cn // block[1]
+
+    def stacked(*g):
+        r, c = index(*g)
+        return r // per_m, c // per_n, r % per_m, c % per_n
+
+    return pl.BlockSpec((pl.squeezed, pl.squeezed) + tuple(block), stacked)
 
 
 @functools.partial(
     jax.jit, static_argnames=("block_m", "block_k", "block_n", "interpret"))
 def ec_matmul(
-    x: jnp.ndarray,
-    x_tilde: jnp.ndarray,
-    w_tilde: jnp.ndarray,
-    dw: jnp.ndarray,
+    l1: jnp.ndarray,
+    l2: jnp.ndarray,
+    r1: jnp.ndarray,
+    r2: jnp.ndarray,
     *,
     block_m: int = DEFAULT_BLOCK_M,
     block_k: int = DEFAULT_BLOCK_K,
     block_n: int = DEFAULT_BLOCK_N,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Fused tier-1 EC product p = x @ W_tilde + x_tilde @ (W - W_tilde).
+    """Fused tier-1 EC product p = l1 @ r1 + l2 @ r2.
 
-    x, x_tilde: (m, k); w_tilde, dw: (k, n).  Returns fp32 (m, n).
+    ``l1``, ``l2``: (m, k); ``r1``, ``r2``: (k, n); either pair may instead
+    be a capacity-block stack of that matrix (see :func:`matrix_shape`).
+    With the input pair on the left, (x^T, x_tilde^T) against
+    (A_tilde^T, dA^T), it is the paper's x @ W_tilde + x_tilde @ dW; with the
+    image pair on the left it is A_tilde x + dA x_tilde.  Returns fp32 (m, n).
     """
-    m, k = x.shape
-    _, n = w_tilde.shape
-    assert x_tilde.shape == x.shape and dw.shape == w_tilde.shape
+    m, k = matrix_shape(l1)
+    _, n = matrix_shape(r1)
+    assert l2.shape == l1.shape and r2.shape == r1.shape
     assert m % block_m == 0 and k % block_k == 0 and n % block_n == 0, (
         (m, k, n), (block_m, block_k, block_n))
     grid = (m // block_m, n // block_n, k // block_k)
+    left = [_tile_spec(a, (block_m, block_k), lambda i, j, s: (i, s))
+            for a in (l1, l2)]
+    right = [_tile_spec(a, (block_k, block_n), lambda i, j, s: (s, j))
+             for a in (r1, r2)]
     return pl.pallas_call(
         _ec_matmul_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, s: (i, s)),
-            pl.BlockSpec((block_m, block_k), lambda i, j, s: (i, s)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, s: (s, j)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, s: (s, j)),
-        ],
+        in_specs=left + right,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(x, x_tilde, w_tilde, dw)
+    )(l1, l2, r1, r2)

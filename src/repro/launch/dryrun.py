@@ -23,7 +23,6 @@ from typing import Dict, Optional
 
 import jax
 
-from repro.core.compat import set_mesh
 import jax.numpy as jnp
 
 from repro.analysis.model_flops import model_flops
@@ -32,6 +31,10 @@ from repro.configs import ARCHS, get_arch
 from repro.configs.base import RRAMBackendConfig
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_cell
+
+# The chip the production mesh is made of (the dry run compiles on host
+# devices standing in for it).
+MODELED_KIND = "TPU v5 lite"
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
@@ -58,7 +61,7 @@ def run_lm_cell(arch_name: str, shape_name: str, multi_pod: bool,
                       runtime_kw=runtime_kw, tcfg=tcfg)
 
     t0 = time.perf_counter()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn,
                          in_shardings=cell.in_shardings,
                          out_shardings=cell.out_shardings,
@@ -70,7 +73,8 @@ def run_lm_cell(arch_name: str, shape_name: str, multi_pod: bool,
         t_compile = time.perf_counter() - t0
 
     mf = model_flops(arch, shape_name)
-    rec = analyze_compiled(compiled, mesh.size, model_flops=mf["model_flops"])
+    rec = analyze_compiled(compiled, mesh.size, MODELED_KIND,
+                           model_flops=mf["model_flops"])
     rec.update({
         "arch": arch_name, "shape": shape_name,
         "mesh": list(mesh.devices.shape), "axes": list(mesh.axis_names),
@@ -129,7 +133,7 @@ def run_meliso_cell(multi_pod: bool, n: int = 65536,
     key_abs = jax.eval_shape(lambda: jax.random.key(0, impl=impl))
 
     t0 = time.perf_counter()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn).lower(a_abs, x_abs, key_abs)
         t_lower = time.perf_counter() - t0
         t0 = time.perf_counter()
@@ -139,7 +143,8 @@ def run_meliso_cell(multi_pod: bool, n: int = 65536,
     # Useful compute: tier-1 EC = 2 matmuls (fused) or 3 (faithful) + denoise.
     mm = 2 if (ec and ec_mode == "fused") else (3 if ec else 1)
     useful = 2.0 * n * n * mm
-    rec = analyze_compiled(compiled, mesh.size, model_flops=useful)
+    rec = analyze_compiled(compiled, mesh.size, MODELED_KIND,
+                           model_flops=useful)
     rec.update({
         "arch": "meliso-mvm", "shape": f"mvm_{n}",
         "mesh": list(mesh.devices.shape), "axes": list(mesh.axis_names),

@@ -12,16 +12,11 @@ import jax
 __all__ = ["make_mesh", "make_production_mesh"]
 
 
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the jax version has them.
-
-    jax < 0.5 has no ``jax.sharding.AxisType``; meshes there are implicitly
-    Auto, so omitting the argument is the exact equivalent.
-    """
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axis types (over ``devices`` if given)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, RRAMBackendConfig
+from repro.core.crossbar import matmul
 from .params import ParamSpec, spec
 
 __all__ = [
@@ -104,16 +105,17 @@ def dense(p: Dict, x: jnp.ndarray, rt: Optional[Runtime] = None) -> jnp.ndarray:
     """
     w = p["w"]
     if rt is None or rt.rram is None or not rt.rram.enabled or "w_tilde" not in p:
-        return x @ w
+        return matmul(x, w)
     cfg = rt.rram
     cd = x.dtype
     xt = _encode_act(x, rt.next_key(), cfg) if cfg.encode_inputs else x
     if cfg.ec:
-        out = x @ p["w_tilde"].astype(cd) + xt @ p["dw"].astype(cd)
+        out = (matmul(x, p["w_tilde"].astype(cd))
+               + matmul(xt, p["dw"].astype(cd)))
         out32 = out.astype(jnp.float32)
         out = (out32 - cfg.lam * _k_stencil(out32, -1.0)).astype(cd)
     else:
-        out = xt @ p["w_tilde"].astype(cd)
+        out = matmul(xt, p["w_tilde"].astype(cd))
     return out
 
 

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.compat import shard_map
+from repro.core.crossbar import matmul
 from .common import (
     Runtime, attention, attention_specs, cross_entropy_loss,
     embed_spec, rmsnorm, rmsnorm_spec, unembed_spec, _k_stencil,
@@ -81,16 +81,16 @@ def _expert_mm(pd: Dict, x: jnp.ndarray, rt: Optional[Runtime]) -> jnp.ndarray:
     """x (E, C, D) @ w (E, D, F), honoring the RRAM EC backend."""
     w = pd["w"]
     if rt is None or rt.rram is None or not rt.rram.enabled or "w_tilde" not in pd:
-        return jnp.einsum("ecd,edf->ecf", x, w.astype(x.dtype))
+        return matmul(x, w.astype(x.dtype))
     cfg = rt.rram
     from .common import _encode_act
     xt = _encode_act(x, rt.next_key(), cfg) if cfg.encode_inputs else x
     if cfg.ec:
-        out = (jnp.einsum("ecd,edf->ecf", x, pd["w_tilde"].astype(x.dtype))
-               + jnp.einsum("ecd,edf->ecf", xt, pd["dw"].astype(x.dtype)))
+        out = (matmul(x, pd["w_tilde"].astype(x.dtype))
+               + matmul(xt, pd["dw"].astype(x.dtype)))
         o32 = out.astype(jnp.float32)
         return (o32 - cfg.lam * _k_stencil(o32, -1.0)).astype(x.dtype)
-    return jnp.einsum("ecd,edf->ecf", xt, pd["w_tilde"].astype(x.dtype))
+    return matmul(xt, pd["w_tilde"].astype(x.dtype))
 
 
 MOE_TOKEN_CHUNK = 8192
@@ -191,7 +191,7 @@ def moe_apply(p: Dict, x: jnp.ndarray, cfg: ModelConfig,
         jax.tree.map(lambda _: P(None, None, mp), p["wu"]),
         jax.tree.map(lambda _: P(None, mp, None), p["wd"]),
     )
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(batch_spec, None, None), P()),

@@ -130,28 +130,38 @@ def program_rram(
         return tree, total
 
     # Grouped programming: bucket the walk by (ndim, shape) and encode each
-    # bucket's kernels as one stacked dispatch.  Stacked (L, m, n) kernels
+    # bucket's kernels in one dispatch.  Stacked (L, m, n) kernels
     # keep their per-layer split keys, 2-D kernels their per-kernel fold --
     # member draws match the ungrouped walk exactly.
     buckets: Dict[Tuple, list] = {}
     for job in jobs:
         sub = job[1]
         buckets.setdefault((sub.ndim,) + tuple(sub.shape), []).append(job)
+
+    def encode(w, k):
+        """One (m, n) kernel -> (w_tilde, dw) in their storage dtypes."""
+        wt = engine.encode_dense(w.astype(jnp.float32), k)
+        return wt.astype(w.dtype), \
+            (w.astype(jnp.float32) - wt).astype(cfg.dw_dtype)
+
+    def encode_job(w, k):
+        if w.ndim == 2:
+            return encode(w, k)
+        keys = jax.random.split(k, w.shape[0])
+        return jax.lax.map(lambda o: encode(*o), (w, keys))
+
+    # One dispatch per bucket.  Kernels and layers are encoded one after
+    # another and written straight to their own outputs, so programming
+    # holds O(one kernel) of float32 transients and no stacked copies.
+    encode_all = jax.jit(lambda ws, ks: [encode_job(w, k)
+                                         for w, k in zip(ws, ks)])
     for bkey, bjobs in buckets.items():   # insertion order == walk order
-        stack = jnp.stack([j[1].astype(jnp.float32) for j in bjobs])
-        if bkey[0] == 2:
-            keys = jnp.stack([j[2] for j in bjobs])
-            wts = jax.jit(jax.vmap(engine.encode_dense))(stack, keys)
-            m, n = bkey[1:]
-            total = total + per_layer_stats(m, n, len(bjobs))
-        else:
-            layers, m, n = bkey[1:]
-            keys = jnp.stack([jax.random.split(j[2], layers) for j in bjobs])
-            wts = jax.jit(jax.vmap(jax.vmap(engine.encode_dense)))(stack,
-                                                                   keys)
-            total = total + per_layer_stats(m, n, len(bjobs) * layers)
-        for (slot, sub, _), wt in zip(bjobs, wts):
-            fill(slot, sub, wt)
+        m, n = bkey[-2:]
+        layers = bkey[1] if bkey[0] == 3 else 1
+        total = total + per_layer_stats(m, n, len(bjobs) * layers)
+        outs = encode_all([j[1] for j in bjobs], [j[2] for j in bjobs])
+        for (slot, _, _), (wt, dw) in zip(bjobs, outs):
+            slot["w_tilde"], slot["dw"] = wt, dw
     return tree, total
 
 

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, pack_result)
+                   init_history, jit_core, pack_result)
 from .pdhg import _power_norm
 
 __all__ = ["admm", "admm_pipeline", "random_box_qp"]
@@ -224,9 +224,9 @@ def admm(
         (x0[:, None] if squeeze else x0).astype(jnp.float32)
     key = jax.random.PRNGKey(0) if key is None else key
 
-    core = jax.jit(admm_pipeline(op, lo=lo_v, hi=hi_v, rho=rho, mu=mu,
-                                 tol=tol, maxiter=maxiter,
-                                 power_iters=power_iters))
+    core = jit_core(op, lambda o: admm_pipeline(
+        o, lo=lo_v, hi=hi_v, rho=rho, mu=mu, tol=tol, maxiter=maxiter,
+        power_iters=power_iters))
     x, z, hist, k, mvms, pi_mvms, rel0 = core(bb, qq, x0b, key)
     res = pack_result(op, "admm", x, hist, k, mvms, tol, squeeze,
                       mvms_single=int(pi_mvms), rel0=rel0, mvms_t=int(mvms),

@@ -29,7 +29,8 @@ key and two solvers issued the same draws never correlate across iterations.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional, Tuple, Union
+import functools
+from typing import Any, Callable, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +40,7 @@ from repro.engine import AnalogMatrix, TransposedAnalogMatrix
 
 __all__ = [
     "LinearOperator", "SolveLedger", "SolveResult", "as_operator",
-    "col_norms", "init_history", "use_pallas",
+    "col_norms", "init_history", "jit_core", "use_pallas",
 ]
 
 _TINY = 1e-30
@@ -88,6 +89,12 @@ class LinearOperator:
     analog: bool
     rmatvec: Optional[Callable[[jnp.ndarray, jax.Array], jnp.ndarray]] = None
     input_stats_t: Optional[Callable[[int], WriteStats]] = None
+    # The device arrays the matvecs read, and ``bind(operands)`` -> the same
+    # operator reading the given ones: :func:`jit_core` passes them into a
+    # solver's jitted core as arguments instead of capturing them as
+    # compile-time constants (a resident image is gigabytes).
+    operands: Any = ()
+    bind: Optional[Callable[[Any], "LinearOperator"]] = None
 
     @property
     def n(self) -> int:
@@ -109,7 +116,23 @@ class LinearOperator:
             input_stats_t=self.input_stats,
             dense=(lambda: self.dense().T) if self.dense is not None else None,
             analog=self.analog,
+            operands=self.operands,
+            bind=(lambda ops: self.bind(ops).T) if self.bind else None,
         )
+
+
+def jit_core(op: LinearOperator, build: Callable[[LinearOperator], Callable]
+             ) -> Callable:
+    """``jax.jit(build(op))`` with ``op.operands`` passed as arguments.
+
+    A jitted function captures the arrays it closes over as constants of the
+    compiled program: a second device copy, and host copies while it
+    compiles.  Binding the operator to the jit's own arguments keeps a
+    programmed image a single buffer however large it is."""
+    if op.bind is None:
+        return jax.jit(build(op))
+    core = jax.jit(lambda operands, *args: build(op.bind(operands))(*args))
+    return functools.partial(core, op.operands)
 
 
 def _zero_stats(_batch: int = 1) -> WriteStats:
@@ -148,6 +171,7 @@ def as_operator(
         # reductions are plain per-column jnp ops, GSPMD keeps the x/r/p
         # panels sharded across the whole jitted while_loop -- no gathers.
         eng = A.engine
+        fields = ("at_blocks", "da_blocks", "at_dense", "da_dense", "age")
         return LinearOperator(
             matvec=lambda v, k: eng.mvm(A, v, key=k),
             rmatvec=lambda u, k: eng.rmvm(A, u, key=k),
@@ -158,6 +182,9 @@ def as_operator(
                 A, batch, transpose=True),
             dense=A.dense,
             analog=True,
+            operands={f: getattr(A, f) for f in fields
+                      if getattr(A, f) is not None},
+            bind=lambda ops: as_operator(dataclasses.replace(A, **ops)),
         )
     if callable(A) and not hasattr(A, "shape"):
         if shape is None:
@@ -174,7 +201,7 @@ def as_operator(
                           shape=a.shape,
                           write_stats=WriteStats.zero(),
                           input_stats=_zero_stats, dense=lambda: a,
-                          analog=False)
+                          analog=False, operands=a, bind=as_operator)
 
 
 @dataclasses.dataclass(frozen=True)

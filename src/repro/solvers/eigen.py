@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, pack_result)
+                   init_history, jit_core, pack_result)
 from .stationary import _power_iterate
 
 __all__ = ["lanczos", "lobpcg", "operator_norm", "lanczos_pipeline",
@@ -171,8 +171,8 @@ def lanczos(
     if maxiter < 2:
         raise ValueError("lanczos needs maxiter >= 2")
     key = jax.random.PRNGKey(0) if key is None else key
-    core = jax.jit(lanczos_pipeline(op, tol=tol, maxiter=maxiter,
-                                    seed_iters=seed_iters))
+    core = jit_core(op, lambda o: lanczos_pipeline(
+        o, tol=tol, maxiter=maxiter, seed_iters=seed_iters))
     y_pair, theta2, hist, k, mvms = core(key)
     res = pack_result(op, "lanczos", y_pair, hist, k, jnp.int32(0), tol,
                       squeeze=False, mvms_single=int(mvms))
@@ -288,8 +288,8 @@ def lobpcg(
         if x0b.shape != (n_, k):
             raise ValueError(f"x0 has shape {x0b.shape}, expected ({n_}, {k})")
     squeeze = squeeze or (x0 is None and k == 1)
-    core = jax.jit(lobpcg_pipeline(op, tol=tol, maxiter=maxiter,
-                                   largest=(which == "largest")))
+    core = jit_core(op, lambda o: lobpcg_pipeline(
+        o, tol=tol, maxiter=maxiter, largest=(which == "largest")))
     x_blk, theta, hist, it, mvms, rel0 = core(x0b, key)
     res = pack_result(op, "lobpcg", x_blk, hist, it, mvms, tol,
                       squeeze=squeeze, rel0=rel0)

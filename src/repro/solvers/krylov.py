@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, pack_result, use_pallas)
+                   init_history, jit_core, pack_result, use_pallas)
 
 __all__ = ["cg", "bicgstab", "gmres", "cg_pipeline"]
 
@@ -173,8 +173,8 @@ def cg(
     op = as_operator(A)
     bb, x0b, squeeze = _prep(b, x0)
     key = jax.random.PRNGKey(0) if key is None else key
-    core = jax.jit(cg_pipeline(op, tol=tol, maxiter=maxiter,
-                               backend=backend, divergence=divergence))
+    core = jit_core(op, lambda o: cg_pipeline(
+        o, tol=tol, maxiter=maxiter, backend=backend, divergence=divergence))
     x, hist, k, mvms, rel0 = core(bb, x0b, key)
     return pack_result(op, "cg", x, hist, k, mvms, tol, squeeze, rel0=rel0)
 
@@ -233,8 +233,8 @@ def bicgstab(
     op = as_operator(A)
     bb, x0b, squeeze = _prep(b, x0)
     key = jax.random.PRNGKey(0) if key is None else key
-    core = jax.jit(functools.partial(_bicgstab_core, op, tol=tol,
-                                     maxiter=maxiter))
+    core = jit_core(op, lambda o: functools.partial(
+        _bicgstab_core, o, tol=tol, maxiter=maxiter))
     x, hist, k, mvms, rel0 = core(bb, x0b, key)
     return pack_result(op, "bicgstab", x, hist, k, mvms, tol, squeeze,
                        rel0=rel0)
@@ -333,7 +333,7 @@ def gmres(
     op = as_operator(A)
     bb, x0b, squeeze = _prep(b, x0)
     key = jax.random.PRNGKey(0) if key is None else key
-    core = jax.jit(functools.partial(_gmres_core, op, tol=tol,
-                                     maxiter=maxiter, restart=restart))
+    core = jit_core(op, lambda o: functools.partial(
+        _gmres_core, o, tol=tol, maxiter=maxiter, restart=restart))
     x, hist, c, mvms, rel0 = core(bb, x0b, key)
     return pack_result(op, "gmres", x, hist, c, mvms, tol, squeeze, rel0=rel0)

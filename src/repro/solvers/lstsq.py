@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, pack_result)
+                   init_history, jit_core, pack_result)
 
 __all__ = ["lsqr", "lsmr", "lsqr_pipeline", "lsmr_pipeline"]
 
@@ -264,8 +264,8 @@ def _lstsq_solve(core_fn, name: str, A, b, *, tol, maxiter, x0, key):
     x0b = jnp.zeros((n, bb.shape[1]), jnp.float32) if x0 is None else \
         (x0[:, None] if squeeze else x0).astype(jnp.float32)
     key = jax.random.PRNGKey(0) if key is None else key
-    core = jax.jit(core_fn(op, tol=tol, maxiter=maxiter,
-                           explicit_x0=explicit_x0))
+    core = jit_core(op, lambda o: core_fn(o, tol=tol, maxiter=maxiter,
+                                          explicit_x0=explicit_x0))
     x, hist, k, mvms, rel0 = core(bb, x0b, key)
     # Forward MVMs: init + one per iteration; transposed MVMs mirror them
     # exactly, plus the full-panel ||A'b|| normalization when x0 was given.

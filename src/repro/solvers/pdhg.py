@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, pack_result)
+                   init_history, jit_core, pack_result)
 
 __all__ = ["pdhg", "pdhg_pipeline", "random_feasible_lp"]
 
@@ -284,9 +284,9 @@ def pdhg(
         (y0[:, None] if squeeze else y0).astype(jnp.float32)
     key = jax.random.PRNGKey(0) if key is None else key
 
-    core = jax.jit(pdhg_pipeline(op, tau=tau, sigma=sigma, eta=eta, tol=tol,
-                                 maxiter=maxiter, power_iters=power_iters,
-                                 divergence=divergence))
+    core = jit_core(op, lambda o: pdhg_pipeline(
+        o, tau=tau, sigma=sigma, eta=eta, tol=tol, maxiter=maxiter,
+        power_iters=power_iters, divergence=divergence))
     x, y, hist, k, mvms, pi_mvms, rel0 = core(bb, cc, x0b, y0b, key)
     res = pack_result(op, "pdhg", x, hist, k, mvms, tol, squeeze,
                       mvms_single=int(pi_mvms), rel0=rel0, mvms_t=int(mvms),

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, pack_result, use_pallas)
+                   init_history, jit_core, pack_result, use_pallas)
 
 __all__ = ["richardson", "jacobi", "spectral_bounds", "estimate_omega"]
 
@@ -186,8 +186,8 @@ def richardson(
         (x0[:, None] if squeeze else x0).astype(jnp.float32)
     key = jax.random.PRNGKey(0) if key is None else key
 
-    core = jax.jit(functools.partial(
-        _stationary_core, op, None, tol=tol, maxiter=maxiter,
+    core = jit_core(op, lambda o: functools.partial(
+        _stationary_core, o, None, tol=tol, maxiter=maxiter,
         use_pallas=pallas, power_iters=power_iters, omega=omega))
     x, hist, k, mvms, pi_mvms = core(bb, x0b, key)
     return pack_result(op, "richardson", x, hist, k, mvms, tol, squeeze,
@@ -224,8 +224,8 @@ def jacobi(
         (x0[:, None] if squeeze else x0).astype(jnp.float32)
     key = jax.random.PRNGKey(0) if key is None else key
 
-    core = jax.jit(functools.partial(
-        _stationary_core, op, lambda r: dinv * r, tol=tol, maxiter=maxiter,
+    core = jit_core(op, lambda o: functools.partial(
+        _stationary_core, o, lambda r: dinv * r, tol=tol, maxiter=maxiter,
         use_pallas=False, power_iters=0, omega=omega))
     x, hist, k, mvms, _pi = core(bb, x0b, key)
     return pack_result(op, "jacobi", x, hist, k, mvms, tol, squeeze)

@@ -25,7 +25,6 @@ PRELUDE = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.core.compat import set_mesh
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ("data", "model"))
 """)
@@ -401,8 +400,7 @@ def test_compressed_psum_and_ring_matmul():
         def red(x):
             out, resid = compressed_psum(x, "data", None)
             return out, resid
-        from repro.core.compat import shard_map
-        f = jax.jit(shard_map(red, mesh=mesh,
+        f = jax.jit(jax.shard_map(red, mesh=mesh,
                               in_specs=P(("data",), None),
                               out_specs=(P("data", None), P("data", None))))
         out, resid = f(g)
@@ -417,7 +415,7 @@ def test_compressed_psum_and_ring_matmul():
             return ring_collective_matmul(xx, ww, "model")
         # the ring result is value-replicated over 'model' but the static vma
         # checker cannot prove it -> check_vma=False
-        rm = jax.jit(shard_map(ring, mesh=mesh,
+        rm = jax.jit(jax.shard_map(ring, mesh=mesh,
                                in_specs=(P(None, None), P("model", None)),
                                out_specs=P(None, None), check_vma=False))
         y = rm(x, w)
@@ -456,7 +454,7 @@ def test_sharded_train_step_matches_single_device():
         psh = jax.tree.map(lambda ps: NamedSharding(mesh, ps),
                            param_pspecs(specs, mesh, "fsdp_tp"))
         prm_s = jax.tree.map(lambda a, s: jax.device_put(a, s), prm, psh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p2, o2, m2 = jax.jit(step)(prm_s, opt, batch)
         print(json.dumps({
             "loss1": float(m1["loss"]), "loss2": float(m2["loss"]),
@@ -510,7 +508,7 @@ def test_moe_shard_map_matches_local():
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model))
         out_local, aux_local = M.moe_apply(lp, x, cfg, Runtime())
         rt = Runtime(mesh=mesh, batch_axes=("data",))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out_sm, aux_sm = jax.jit(
                 lambda p, xx: M.moe_apply(p, xx, cfg, rt))(lp, x)
         err = float(jnp.max(jnp.abs(out_local - out_sm)))

@@ -113,6 +113,26 @@ def test_stencil_matches_thomas_at_tiny_lam():
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("method", ["thomas", "stencil"])
+@pytest.mark.parametrize("n", [64, 100])
+def test_tier2_row_blocks(method, n):
+    """Panels longer than one row block: the Thomas sweeps carry their row
+    across blocks (both directions) and the stencil reads its halo rows
+    from the neighbouring blocks."""
+    from repro.kernels.tridiag import stencil_denoise, thomas_solve
+    p = rand((n, 8), jnp.float32, 16)
+    lam = 0.3
+    if method == "thomas":
+        got = thomas_solve(p, lam=lam, block_b=8, block_r=16, interpret=True)
+        want = kref.tridiag_solve_ref(p, lam)
+    else:
+        got = stencil_denoise(p, lam=lam, block_b=8, block_r=16,
+                              interpret=True)
+        want = kref.stencil_denoise_ref(p, lam)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_encode_matmul_rng_inkernel_noise():
     """Single-pass encode kernel (in-kernel PRNG): on CPU the TPU interpreter
     stubs prng_random_bits to zeros, so we validate the sigma=0 exact path,

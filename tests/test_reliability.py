@@ -1,5 +1,7 @@
 """Unit tests for repro.reliability: aging, probes, refresh, fault-tolerant
 solves, and the serving refresh scheduler."""
+import math
+
 import numpy as np
 import pytest
 
@@ -66,7 +68,9 @@ def test_aged_blocks_replayable_and_monotone():
     A = _handle(a, device="ag-si")        # fault_rate 2e-7: faults show fast
     led = attach_age(A)
     dev = A.engine.cfg.device
-    n1 = int(0.5 / (dev.fault_rate * a.size))
+    # Age at which the expected number of stuck cells, size * (1 - (1 -
+    # rate)^N), is 20: P(no stuck cell) ~ e^-20, so the draw cannot decide.
+    n1 = math.ceil(math.log1p(-20 / a.size) / math.log1p(-dev.fault_rate))
     aged1 = aged_blocks(A.at_blocks, led.advanced(n1), dev)
     aged1b = aged_blocks(A.at_blocks, led.advanced(n1), dev)
     np.testing.assert_array_equal(np.asarray(aged1), np.asarray(aged1b))

@@ -42,12 +42,12 @@ class TestWalkerRegressions:
         f.defvjp(fwd, bwd)
         jx = jax.make_jaxpr(f)(jnp.ones((8,)))
         assert [e.primitive.name for e in jx.jaxpr.eqns] == \
-            ["custom_vjp_call_jaxpr"]                 # primal-only: un-inlined
+            ["custom_vjp_call"]                 # primal-only: un-inlined
         assert jaxpr_max_elements(jx) == 1024 * 1024
 
     @staticmethod
     def _rewritten(params_patch):
-        """A real jaxpr whose pjit eqn hides its sub-jaxpr per ``patch``."""
+        """A real jaxpr whose jit eqn hides its sub-jaxpr per ``patch``."""
         def inner(x):
             return jnp.sin(jnp.outer(x, x)).sum()
 
@@ -123,7 +123,7 @@ class TestDispatchCount:
                                   max_top_level=1)
         assert not report.ok
         assert report.summary["top_level_eqns"] == 4
-        assert report.summary["per_primitive"] == {"pjit": 4}
+        assert report.summary["per_primitive"] == {"jit": 4}
         assert "4 top-level equations > budget 1" in str(report.violations[0])
 
     def test_single_fused_dispatch_clean(self):
@@ -246,8 +246,7 @@ class TestPrecisionLint:
         assert V.precision_lint(V.trace(acc, jnp.ones((5,)))).ok
 
     def test_flags_f64_leak(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             jx = V.trace(lambda x: x.astype(jnp.float64).sum() * 2.0,
                          jnp.ones((4,), jnp.float32))
         report = V.precision_lint(jx)
@@ -259,12 +258,11 @@ class TestPrecisionLint:
 
 # ----------------------------------------------------------- CollectiveAudit
 def _shard_mapped(body):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
-    return shard_map(body, mesh=mesh, in_specs=P("data", "model"),
-                     out_specs=P("data", "model"), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=P("data", "model"),
+                         out_specs=P("data", "model"), check_vma=False)
 
 
 class TestCollectiveAudit:
