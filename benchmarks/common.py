@@ -1,9 +1,8 @@
-"""Shared benchmark utilities: timing, CSV rows, and JSON metadata."""
+"""Shared benchmark utilities: CSV rows and JSON metadata."""
 from __future__ import annotations
 
 import os
-import time
-from typing import Callable, Dict, List
+from typing import Dict, List
 
 import jax
 
@@ -25,24 +24,8 @@ def run_metadata() -> Dict:
     }
 
 
-def time_call(fn: Callable, *args, warmup: int = 1, iters: int = 3) -> float:
-    """Median wall time (us) of a jax callable (block_until_ready)."""
-    for _ in range(warmup):
-        out = fn(*args)
-        jax.block_until_ready(out)
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        times.append((time.perf_counter() - t0) * 1e6)
-    times.sort()
-    return times[len(times) // 2]
-
-
 def emit(rows: List[Dict]) -> None:
     for r in rows:
         name = r.pop("name")
-        us = r.pop("us_per_call", "")
         derived = ";".join(f"{k}={v}" for k, v in r.items())
-        print(f"{name},{us},{derived}")
+        print(f"{name},{derived}")

@@ -12,11 +12,11 @@ and reports, for identical per-member keys:
   * ``experts`` -- L parallel expert kernels (the MoE pattern) executed by
     one grouped broadcast MVM vs L solo MVMs;
   * dispatch counts for both paths (grouped is 1 by construction -- the
-    DispatchCount invariant pins it -- per-layer is L), their ratio, the
-    wall-clock speedup, and grouped-vs-solo parity (``rel_l2``).
+    DispatchCount invariant pins it -- per-layer is L), their ratio, and
+    grouped-vs-solo parity (``rel_l2``).
 
 Results land in ``BENCH_model_dispatch.json`` at the repo root (checked in;
-``tools/check_perf.py`` gates dispatch counts and timing against it).
+``tools/check_perf.py`` gates dispatch counts against it).
 
     PYTHONPATH=src python -m benchmarks.model_dispatch            # full sweep
     PYTHONPATH=src python -m benchmarks.model_dispatch --smoke    # CI fast job
@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from repro.core import CrossbarConfig, MCAGeometry, get_device, rel_l2
 from repro.engine import AnalogEngine
 
-from .common import run_metadata, time_call
+from .common import run_metadata
 
 CAP = 32                                   # capacity block edge (1x1 tile MCA)
 GEOM = MCAGeometry(tile_rows=1, tile_cols=1, cell_rows=CAP, cell_cols=CAP)
@@ -53,8 +53,7 @@ def _solo_handles(engine: AnalogEngine, stack: jnp.ndarray, key: jax.Array):
             for g in range(stack.shape[0])]
 
 
-def _bench_chain(arch: str, d: int, L: int, cfg: CrossbarConfig,
-                 iters: int) -> Dict:
+def _bench_chain(arch: str, d: int, L: int, cfg: CrossbarConfig) -> Dict:
     """Whole-model forward: L chained square layers, relu between members."""
     key = jax.random.fold_in(jax.random.PRNGKey(13), d * 1000 + L)
     stack = jax.random.normal(key, (L, d, d), jnp.float32) / float(d)
@@ -71,18 +70,12 @@ def _bench_chain(arch: str, d: int, L: int, cfg: CrossbarConfig,
             h = jax.nn.relu(engine.mvm(A, h, key=jax.random.fold_in(k_mvm, g)))
         return h
 
-    us_group = time_call(
-        lambda: engine.chain_mvm(G, x, key=k_mvm, activation="relu"),
-        iters=iters)
-    us_solo = time_call(solo_forward, iters=iters)
     y_group = engine.chain_mvm(G, x, key=k_mvm, activation="relu")
     y_solo = solo_forward()
-    return _row("chain", arch, d, L, us_group, us_solo,
-                float(rel_l2(y_group, y_solo)))
+    return _row("chain", arch, d, L, float(rel_l2(y_group, y_solo)))
 
 
-def _bench_experts(arch: str, d: int, L: int, cfg: CrossbarConfig,
-                   iters: int) -> Dict:
+def _bench_experts(arch: str, d: int, L: int, cfg: CrossbarConfig) -> Dict:
     """MoE pattern: L parallel expert kernels, one broadcast input."""
     key = jax.random.fold_in(jax.random.PRNGKey(17), d * 1000 + L)
     stack = jax.random.normal(key, (L, d, d), jnp.float32) / float(d)
@@ -98,25 +91,16 @@ def _bench_experts(arch: str, d: int, L: int, cfg: CrossbarConfig,
             engine.mvm(A, x, key=jax.random.fold_in(k_mvm, g))
             for g, A in enumerate(handles)])
 
-    us_group = time_call(lambda: engine.group_mvm(G, x, key=k_mvm),
-                         iters=iters)
-    us_solo = time_call(solo_experts, iters=iters)
     y_group = engine.group_mvm(G, x, key=k_mvm)
     y_solo = solo_experts()
-    return _row("experts", arch, d, L, us_group, us_solo,
-                float(rel_l2(y_group, y_solo)))
+    return _row("experts", arch, d, L, float(rel_l2(y_group, y_solo)))
 
 
-def _row(mode: str, arch: str, d: int, L: int, us_group: float,
-         us_solo: float, parity: float) -> Dict:
+def _row(mode: str, arch: str, d: int, L: int, parity: float) -> Dict:
     return {
         "name": f"model_dispatch/{mode}/{arch}/L{L}",
-        "us_per_call": round(us_group, 1),
         "layers": L,
         "width": d,
-        "us_group": round(us_group, 1),
-        "us_solo": round(us_solo, 1),
-        "speedup": round(us_solo / max(us_group, 1e-9), 2),
         "dispatches_group": 1,
         "dispatches_solo": L,
         "dispatch_reduction": L,
@@ -124,7 +108,7 @@ def _row(mode: str, arch: str, d: int, L: int, us_group: float,
     }
 
 
-def run(quick: bool = True, iters: int = 3) -> List[Dict]:
+def run(quick: bool = True) -> List[Dict]:
     cfg = CrossbarConfig(device=get_device("taox-hfox"), geom=GEOM,
                          k_iters=5, ec=True)
     layers = LAYERS_SMOKE if quick else LAYERS_FULL
@@ -132,8 +116,8 @@ def run(quick: bool = True, iters: int = 3) -> List[Dict]:
     rows: List[Dict] = []
     for arch, d in archs.items():
         for L in layers:
-            rows.append(_bench_chain(arch, d, L, cfg, iters))
-            rows.append(_bench_experts(arch, d, L, cfg, iters))
+            rows.append(_bench_chain(arch, d, L, cfg))
+            rows.append(_bench_experts(arch, d, L, cfg))
     _write_json(rows, quick)
     return rows
 
@@ -165,15 +149,13 @@ def _write_json(rows: List[Dict], quick: bool) -> str:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
-                    help="small sweep / single timing iter (CI fast job); "
+                    help="small sweep (CI fast job); "
                          "writes to the temp dir, leaving the checked-in "
                          "full-sweep JSON untouched")
     args = ap.parse_args()
-    rows = run(quick=args.smoke, iters=1 if args.smoke else 3)
+    rows = run(quick=args.smoke)
     for r in rows:
-        print(f"{r['name']}: group {r['us_group']:.0f}us vs solo "
-              f"{r['us_solo']:.0f}us ({r['speedup']:.1f}x wall, "
-              f"{r['dispatch_reduction']}x dispatches), "
+        print(f"{r['name']}: {r['dispatch_reduction']}x dispatches, "
               f"parity {r['rel_l2_group_vs_solo']:.2e}")
     print(f"wrote {_out_path(args.smoke)}")
     # Acceptance contract: grouped execution cuts dispatches >= 5x once a

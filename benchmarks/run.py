@@ -1,5 +1,5 @@
 """Benchmark entry point: one module per paper table/figure (+ the LM-step
-framework bench).  Prints ``name,us_per_call,derived`` CSV.
+framework bench).  Prints ``name,derived`` CSV.
 
     PYTHONPATH=src python -m benchmarks.run            # quick (CI) mode
     PYTHONPATH=src python -m benchmarks.run --full     # full paper protocol
@@ -26,10 +26,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
-    from . import (lm_step, lstsq_convergence, model_dispatch,
-                   pdhg_convergence, reliability, serving, solver_convergence,
-                   streamed_scaling, strong_scaling, table1_ec, weak_scaling,
-                   writeverify_sweep)
+    from . import (lstsq_convergence, model_dispatch, pdhg_convergence,
+                   reliability, serving, solver_convergence, streamed_scaling,
+                   strong_scaling, table1_ec, weak_scaling, writeverify_sweep)
     modules = [
         ("table1_ec", table1_ec),
         ("writeverify_sweep", writeverify_sweep),
@@ -40,11 +39,10 @@ def main() -> None:
         ("strong_scaling", strong_scaling),
         ("streamed_scaling", streamed_scaling),
         ("model_dispatch", model_dispatch),
-        ("lm_step", lm_step),
         ("serving", serving),
         ("reliability", reliability),
     ]
-    print("name,us_per_call,derived")
+    print("name,derived")
     for name, mod in modules:
         if args.only and args.only not in name:
             continue

@@ -19,7 +19,6 @@ mixed-precision refinement, and sweeps all four devices.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List
 
 import jax
@@ -75,13 +74,10 @@ def run(quick: bool = True) -> List[Dict]:
             engine = AnalogEngine(cfg)
             A = engine.program(a, jax.random.fold_in(key, 7))
             for sname, solve in _solver_menu(quick):
-                t0 = time.perf_counter()
                 res = solve(A, b, tol, maxiter)
-                us = (time.perf_counter() - t0) * 1e6
                 led = res.ledger
                 rows.append({
                     "name": f"solver/{dev}/{'ec' if ec else 'raw'}/{sname}",
-                    "us_per_call": round(us, 1),
                     "iters": res.iterations,
                     "converged": res.converged,
                     "resid": f"{res.final_residual:.3e}",
@@ -102,5 +98,5 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
-    print("name,us_per_call,derived")
+    print("name,derived")
     emit(run(quick=not args.full))

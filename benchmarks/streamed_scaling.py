@@ -5,11 +5,11 @@ block-by-block against a streamed producer.  Pre-scan, that was a Python
 double loop -- O(mb * nb) host->device dispatches per MVM, re-paid every
 solver iteration -- so the framework was dispatch-bound long before it was
 compute-bound.  This benchmark sweeps the capacity-block count and reports,
-for the same producer and keys:
+for the same producer and keys, the scan-fused pipeline (ONE dispatch/MVM)
+against the compat host loop (mb * nb dispatches, forced via an explicit
+``traceable = False`` marker):
 
-  * ``us_scan``  -- wall-clock of the scan-fused pipeline (ONE dispatch/MVM);
-  * ``us_loop``  -- wall-clock of the compat host loop (mb * nb dispatches),
-                    forced via an explicit ``traceable = False`` marker;
+  * dispatches per MVM of each path;
   * producer invocations per *warm* MVM (0 scanned vs mb * nb looped) -- the
     host-work proxy for the dispatch count;
   * ``rel_l2``   -- parity between the two paths (same keys => same draws).
@@ -34,7 +34,7 @@ from repro.core import CrossbarConfig, MCAGeometry, get_device, rel_l2
 from repro.core.matrices import ImplicitBandedMatrix
 from repro.engine import AnalogEngine
 
-from .common import run_metadata, time_call
+from .common import run_metadata
 
 CAP = 32                                   # capacity block edge (1x1 tile MCA)
 GEOM = MCAGeometry(tile_rows=1, tile_cols=1, cell_rows=CAP, cell_cols=CAP)
@@ -54,7 +54,7 @@ def _counting(fn):
     return wrapped, calls
 
 
-def _bench_grid(nb: int, cfg: CrossbarConfig, iters: int) -> Dict:
+def _bench_grid(nb: int, cfg: CrossbarConfig) -> Dict:
     n = nb * CAP
     key = jax.random.fold_in(jax.random.PRNGKey(42), n)
     imp = ImplicitBandedMatrix(n=n, cap_m=CAP, cap_n=CAP, seed=nb)
@@ -75,10 +75,8 @@ def _bench_grid(nb: int, cfg: CrossbarConfig, iters: int) -> Dict:
     assert not A_loop.block_traceable
 
     k_mvm = jax.random.fold_in(key, 2)
-    us_scan = time_call(lambda: eng_scan.mvm(A_scan, x, key=k_mvm),
-                        iters=iters)
-    us_loop = time_call(lambda: eng_loop.mvm(A_loop, x, key=k_mvm),
-                        iters=iters)
+    for eng, A in ((eng_scan, A_scan), (eng_loop, A_loop)):    # warm up
+        jax.block_until_ready(eng.mvm(A, x, key=k_mvm))
 
     # Host-work per warm MVM (the dispatch-count proxy): one measured call.
     c0 = scan_calls["n"]
@@ -90,12 +88,8 @@ def _bench_grid(nb: int, cfg: CrossbarConfig, iters: int) -> Dict:
 
     return {
         "name": f"streamed_scaling/grid{nb}x{nb}/n{n}",
-        "us_per_call": round(us_scan, 1),
         "n": n,
         "blocks": nb * nb,
-        "us_scan": round(us_scan, 1),
-        "us_loop": round(us_loop, 1),
-        "speedup": round(us_loop / max(us_scan, 1e-9), 2),
         "producer_calls_per_mvm_scan": scan_per_mvm,
         "producer_calls_per_mvm_loop": loop_per_mvm,
         "dispatches_per_mvm_scan": 1,
@@ -104,11 +98,11 @@ def _bench_grid(nb: int, cfg: CrossbarConfig, iters: int) -> Dict:
     }
 
 
-def run(quick: bool = True, iters: int = 3) -> List[Dict]:
+def run(quick: bool = True) -> List[Dict]:
     cfg = CrossbarConfig(device=get_device("taox-hfox"), geom=GEOM,
                          k_iters=5, ec=True)
     grids = GRIDS_SMOKE if quick else GRIDS_FULL
-    rows = [_bench_grid(nb, cfg, iters) for nb in grids]
+    rows = [_bench_grid(nb, cfg) for nb in grids]
     _write_json(rows, quick)
     return rows
 
@@ -141,15 +135,14 @@ def _write_json(rows: List[Dict], quick: bool) -> str:
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
-                    help="small grids / single timing iter (CI fast job); "
+                    help="small grids (CI fast job); "
                          "writes to the temp dir, leaving the checked-in "
                          "full-sweep JSON untouched")
     args = ap.parse_args()
-    rows = run(quick=args.smoke, iters=1 if args.smoke else 3)
+    rows = run(quick=args.smoke)
     for r in rows:
-        print(f"{r['name']}: scan {r['us_scan']:.0f}us vs loop "
-              f"{r['us_loop']:.0f}us ({r['speedup']:.1f}x), "
-              f"parity {r['rel_l2_scan_vs_loop']:.2e}")
+        print(f"{r['name']}: {r['dispatches_per_mvm_loop']} dispatches "
+              f"looped vs 1 scanned, parity {r['rel_l2_scan_vs_loop']:.2e}")
     print(f"wrote {_out_path(args.smoke)}")
 
 

@@ -13,7 +13,6 @@ Validation targets (DESIGN.md section 7 / paper claims):
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List
 
 import jax
@@ -36,14 +35,11 @@ def one_cell(a, x, b, device_name, ec, k_iters, reps, key) -> Dict:
     engine = AnalogEngine(cfg)
     A = engine.program(a, key)                    # one-time conductance write
     e2s, eis = [], []
-    t0 = time.perf_counter()
     for r in range(reps):
-        # Execute-many: every rep reuses the programmed image (zero re-encode);
-        # us_per_call therefore times the serving hot path.
+        # Execute-many: every rep reuses the programmed image (zero re-encode).
         y = engine.mvm(A, x, key=jax.random.fold_in(key, r))
         e2s.append(float(rel_l2(y, b)))
         eis.append(float(rel_linf(y, b)))
-    us = (time.perf_counter() - t0) / reps * 1e6
     per_call = A.input_write_stats(batch=1)
     # E_w/L_w keep the legacy one-shot accounting (program + one input write)
     # so the paper's Table-1 ratios are directly comparable.
@@ -53,7 +49,6 @@ def one_cell(a, x, b, device_name, ec, k_iters, reps, key) -> Dict:
         "L_w": float(A.write_stats.latency_s) + float(per_call.latency_s),
         "E_program": float(A.write_stats.energy_j),
         "E_per_mvm": float(per_call.energy_j),
-        "us_per_call": us,
     }
 
 

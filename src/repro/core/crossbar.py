@@ -19,6 +19,15 @@ accounting that follows the paper's conventions:
 The Pallas kernel in :mod:`repro.kernels.rram_mvm` implements the same
 encode+multiply semantics per (cell_rows x cell_cols) VMEM tile; this module is
 its oracle at system level.
+
+Each stage names its operations with a ``jax.named_scope`` inside the stage
+function, so every path that calls it carries the name into the compiled
+program's op metadata and a profiler trace: ``meliso.produce`` (the block
+producer), ``meliso.encode`` (programming), ``meliso.dac`` (input DAC),
+``meliso.tier1`` (EC products and their accumulation); ``meliso.tier2`` is
+set in :func:`repro.core.error_correction.denoise_least_square` and
+``meliso.psum`` in :mod:`repro.core.distributed`.  A scope is metadata: it
+adds no jaxpr equation and no work.
 """
 from __future__ import annotations
 
@@ -116,15 +125,17 @@ def encode_tiled(
     r_, c_ = geom.cell_rows, geom.cell_cols
     m, n = a.shape
     assert m % r_ == 0 and n % c_ == 0, (a.shape, (r_, c_))
-    # Per-tile quantization without physical transposes: the (mt, r, nt, c)
-    # view is a pure reshape, the per-tile scale reduces axes (1, 3) in place
-    # (two whole-matrix transposes removed -- EXPERIMENTS.md Perf M1).
-    tiles = a.reshape(m // r_, r_, n // c_, c_)
-    q = quantize(tiles, dev.levels, axis=(1, 3))
-    sigma = effective_sigma(dev, cfg.k_iters).astype(a.dtype)
-    eta = jax.random.normal(key, tiles.shape, dtype=a.dtype)
-    enc = q * (1.0 + sigma * eta)
-    return enc.reshape(m, n)
+    with jax.named_scope("meliso.encode"):
+        # Per-tile quantization without physical transposes: the
+        # (mt, r, nt, c) view is a pure reshape, the per-tile scale reduces
+        # axes (1, 3) in place (two whole-matrix transposes removed --
+        # EXPERIMENTS.md Perf M1).
+        tiles = a.reshape(m // r_, r_, n // c_, c_)
+        q = quantize(tiles, dev.levels, axis=(1, 3))
+        sigma = effective_sigma(dev, cfg.k_iters).astype(a.dtype)
+        eta = jax.random.normal(key, tiles.shape, dtype=a.dtype)
+        enc = q * (1.0 + sigma * eta)
+        return enc.reshape(m, n)
 
 
 def _encode_vec(x: jnp.ndarray, key: jax.Array, cfg: CrossbarConfig) -> jnp.ndarray:
@@ -132,10 +143,11 @@ def _encode_vec(x: jnp.ndarray, key: jax.Array, cfg: CrossbarConfig) -> jnp.ndar
     DAC write with its own range, so a batched MVM quantizes every column
     exactly as it would be quantized alone."""
     dev = cfg.device
-    q = quantize(x, dev.levels, axis=0)
-    sigma = effective_sigma(dev, cfg.k_iters).astype(x.dtype)
-    eta = jax.random.normal(key, x.shape, dtype=x.dtype)
-    return q * (1.0 + sigma * eta)
+    with jax.named_scope("meliso.dac"):
+        q = quantize(x, dev.levels, axis=0)
+        sigma = effective_sigma(dev, cfg.k_iters).astype(x.dtype)
+        eta = jax.random.normal(key, x.shape, dtype=x.dtype)
+        return q * (1.0 + sigma * eta)
 
 
 # --------------------------------------------------------------------------- #
@@ -318,9 +330,10 @@ def program_blocks(
 
     def enc_row(ops):
         band, row_keys = ops
-        row_blocks = band.reshape(cap_m, nb, cap_n).transpose(1, 0, 2)
-        at_row = jax.vmap(enc_one)(row_blocks, row_keys)
-        return at_row, row_blocks - at_row
+        with jax.named_scope("meliso.encode"):
+            row_blocks = band.reshape(cap_m, nb, cap_n).transpose(1, 0, 2)
+            at_row = jax.vmap(enc_one)(row_blocks, row_keys)
+            return at_row, row_blocks - at_row
 
     return jax.lax.map(enc_row, (a_pad.reshape(mb, cap_m, np_), keys))
 
@@ -362,18 +375,21 @@ def programmed_block_mvm(
         def per_col(at_blk, da_blk, x_blk, k):
             _, k_x = jax.random.split(k)
             x_t = _encode_vec(x_blk, k_x, cfg) if cfg.encode_inputs else x_blk
-            if not cfg.ec:
-                return matmul(at_blk, x_t)
-            if use_kernel:
-                from repro.kernels import ops as kops
-                return kops.rram_ec_tile_mvm(x_blk, x_t, at_blk, da_blk)
-            if cfg.ec_mode == "faithful":
-                # The paper's three analog products, with A = A_tilde + dA.
-                return (matmul(at_blk, x_blk) + matmul(at_blk + da_blk, x_t)
-                        - matmul(at_blk, x_t))
-            return matmul(at_blk, x_blk) + matmul(da_blk, x_t)  # fused, 2
+            with jax.named_scope("meliso.tier1"):
+                if not cfg.ec:
+                    return matmul(at_blk, x_t)
+                if use_kernel:
+                    from repro.kernels import ops as kops
+                    return kops.rram_ec_tile_mvm(x_blk, x_t, at_blk, da_blk)
+                if cfg.ec_mode == "faithful":
+                    # The paper's three analog products, A = A_tilde + dA.
+                    return (matmul(at_blk, x_blk)
+                            + matmul(at_blk + da_blk, x_t)
+                            - matmul(at_blk, x_t))
+                return matmul(at_blk, x_blk) + matmul(da_blk, x_t)  # fused
         partials = jax.vmap(per_col)(at_row, da_row, x_chunks, row_keys)
-        return jnp.sum(partials, axis=0)                     # sum over column blocks
+        with jax.named_scope("meliso.tier1"):
+            return jnp.sum(partials, axis=0)             # sum over column blocks
 
     y_blocks = jax.vmap(per_row)(at_blocks, da_blocks, keys)   # (mb, cap_m, batch)
     p = y_blocks.reshape(mb * cap_m, batch)[:m]
@@ -421,19 +437,21 @@ def programmed_block_rmvm(
         def per_row(at_blk, da_blk, y_blk, k):
             _, k_x = jax.random.split(k)
             y_t = _encode_vec(y_blk, k_x, cfg) if cfg.encode_inputs else y_blk
-            if not cfg.ec:
-                return matmul(at_blk.T, y_t)
-            if use_kernel:
-                from repro.kernels import ops as kops
-                return kops.rram_ec_tile_rmvm(y_blk, y_t, at_blk, da_blk)
-            if cfg.ec_mode == "faithful":
-                # The paper's three analog products, transposed.
-                return (matmul(at_blk.T, y_blk)
-                        + matmul((at_blk + da_blk).T, y_t)
-                        - matmul(at_blk.T, y_t))
-            return matmul(at_blk.T, y_blk) + matmul(da_blk.T, y_t)  # fused
+            with jax.named_scope("meliso.tier1"):
+                if not cfg.ec:
+                    return matmul(at_blk.T, y_t)
+                if use_kernel:
+                    from repro.kernels import ops as kops
+                    return kops.rram_ec_tile_rmvm(y_blk, y_t, at_blk, da_blk)
+                if cfg.ec_mode == "faithful":
+                    # The paper's three analog products, transposed.
+                    return (matmul(at_blk.T, y_blk)
+                            + matmul((at_blk + da_blk).T, y_t)
+                            - matmul(at_blk.T, y_t))
+                return matmul(at_blk.T, y_blk) + matmul(da_blk.T, y_t)
         partials = jax.vmap(per_row)(at_col, da_col, y_chunks, col_keys)
-        return jnp.sum(partials, axis=0)                     # sum over row blocks
+        with jax.named_scope("meliso.tier1"):
+            return jnp.sum(partials, axis=0)                # sum over row blocks
 
     z_blocks = jax.vmap(per_col)(at_blocks.swapaxes(0, 1),
                                  da_blocks.swapaxes(0, 1),
@@ -697,6 +715,14 @@ def grouped_streamed_block_rmvm(
 # the jit caching (``block_fn`` is a static argument there).
 
 
+def _produce(block_fn: Callable[[jax.Array, jax.Array], jnp.ndarray],
+             i, j) -> jnp.ndarray:
+    """``block_fn(i, j)``: every traced call of a producer goes through here,
+    so its operations are named ``meliso.produce`` in traces."""
+    with jax.named_scope("meliso.produce"):
+        return block_fn(i, j)
+
+
 def producer_is_traceable(block_fn, cap_m: int, cap_n: int) -> bool:
     """True when ``block_fn(i, j)`` abstractly traces to a (cap_m, cap_n)
     block from two int32 scalars (the traceable-producer protocol).
@@ -727,7 +753,7 @@ def produce_blocks(block_fn: Callable[[jax.Array, jax.Array], jnp.ndarray],
     """
     def row_step(_, i):
         def col_step(_, j):
-            return None, block_fn(i, j)
+            return None, _produce(block_fn, i, j)
         _, row = jax.lax.scan(col_step, None, jnp.arange(nb))
         return None, row
 
@@ -772,7 +798,7 @@ def streamed_program_blocks(
         def col_step(_, col_xs):
             k, j = col_xs
             k_a, _k_x = jax.random.split(k)
-            return None, encode_tiled(block_fn(i, j), k_a, cfg)
+            return None, encode_tiled(_produce(block_fn, i, j), k_a, cfg)
 
         _, at_row = jax.lax.scan(col_step, None, (row_keys, j0 + jnp.arange(nb)))
         return None, at_row
@@ -843,25 +869,26 @@ def streamed_block_mvm(
         def col_step(acc, col_xs):
             if oneshot:
                 k, j, x_blk = col_xs
-                a_blk = block_fn(i, j)
+                a_blk = _produce(block_fn, i, j)
                 k_a, k_x = jax.random.split(k)
                 at_blk = encode_tiled(a_blk, k_a, cfg)
             else:
                 at_blk, k, j, x_blk = col_xs
                 _k_a, k_x = jax.random.split(k)
-                a_blk = block_fn(i, j) if cfg.ec else None
+                a_blk = _produce(block_fn, i, j) if cfg.ec else None
             x_t = _encode_vec(x_blk, k_x, cfg) if cfg.encode_inputs else x_blk
-            if not cfg.ec:
-                return acc + matmul(at_blk, x_t), None
-            if use_kernel:
-                from repro.kernels import ops as kops
-                return acc + kops.rram_ec_tile_mvm(
-                    x_blk, x_t, at_blk, a_blk - at_blk), None
-            if cfg.ec_mode == "faithful":
-                return acc + (matmul(at_blk, x_blk) + matmul(a_blk, x_t)
-                              - matmul(at_blk, x_t)), None
-            return acc + (matmul(at_blk, x_blk)
-                          + matmul(a_blk - at_blk, x_t)), None
+            with jax.named_scope("meliso.tier1"):
+                if not cfg.ec:
+                    return acc + matmul(at_blk, x_t), None
+                if use_kernel:
+                    from repro.kernels import ops as kops
+                    return acc + kops.rram_ec_tile_mvm(
+                        x_blk, x_t, at_blk, a_blk - at_blk), None
+                if cfg.ec_mode == "faithful":
+                    return acc + (matmul(at_blk, x_blk) + matmul(a_blk, x_t)
+                                  - matmul(at_blk, x_t)), None
+                return acc + (matmul(at_blk, x_blk)
+                              + matmul(a_blk - at_blk, x_t)), None
 
         acc0 = jnp.zeros((cap_m, batch), jnp.float32)
         col_xs = (row_keys, j0 + jnp.arange(nb), x_chunks) if oneshot else \
@@ -939,25 +966,27 @@ def streamed_block_rmvm(
         def row_step(acc, row_xs):
             if oneshot:
                 k, i, y_blk = row_xs
-                a_blk = block_fn(i, j)
+                a_blk = _produce(block_fn, i, j)
                 k_a, k_x = jax.random.split(k)
                 at_blk = encode_tiled(a_blk, k_a, cfg)
             else:
                 at_blk, k, i, y_blk = row_xs
                 _k_a, k_x = jax.random.split(k)
-                a_blk = block_fn(i, j) if cfg.ec else None
+                a_blk = _produce(block_fn, i, j) if cfg.ec else None
             y_t = _encode_vec(y_blk, k_x, cfg) if cfg.encode_inputs else y_blk
-            if not cfg.ec:
-                return acc + matmul(at_blk.T, y_t), None
-            if use_kernel:
-                from repro.kernels import ops as kops
-                return acc + kops.rram_ec_tile_rmvm(
-                    y_blk, y_t, at_blk, a_blk - at_blk), None
-            if cfg.ec_mode == "faithful":
-                return acc + (matmul(at_blk.T, y_blk) + matmul(a_blk.T, y_t)
-                              - matmul(at_blk.T, y_t)), None
-            return acc + (matmul(at_blk.T, y_blk)
-                          + matmul((a_blk - at_blk).T, y_t)), None
+            with jax.named_scope("meliso.tier1"):
+                if not cfg.ec:
+                    return acc + matmul(at_blk.T, y_t), None
+                if use_kernel:
+                    from repro.kernels import ops as kops
+                    return acc + kops.rram_ec_tile_rmvm(
+                        y_blk, y_t, at_blk, a_blk - at_blk), None
+                if cfg.ec_mode == "faithful":
+                    return acc + (matmul(at_blk.T, y_blk)
+                                  + matmul(a_blk.T, y_t)
+                                  - matmul(at_blk.T, y_t)), None
+                return acc + (matmul(at_blk.T, y_blk)
+                              + matmul((a_blk - at_blk).T, y_t)), None
 
         acc0 = jnp.zeros((cap_n, batch), jnp.float32)
         row_xs = (col_keys, i0 + jnp.arange(mb), y_chunks) if oneshot else \

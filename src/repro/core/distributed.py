@@ -106,6 +106,13 @@ def _row_index(row_axes: Tuple[str, ...]) -> jax.Array:
     return idx
 
 
+def _psum_partials(p: jnp.ndarray, axes) -> jnp.ndarray:
+    """The psum of tier-1 partials over the contraction ``axes``, named
+    ``meliso.psum`` in traces."""
+    with jax.named_scope("meliso.psum"):
+        return jax.lax.psum(p, axis_name=axes)
+
+
 def _mean_stats(stats: WriteStats, axes: Tuple[str, ...]) -> WriteStats:
     n_ranks = jax.lax.psum(1, axis_name=axes)
     return WriteStats(
@@ -174,7 +181,7 @@ def make_distributed_programmed_mvm(
         batch = x_blk.shape[1]
         p = local_dense_mvm(at_blk, da_blk, x_blk, k, cfg,
                             tier2=False, use_kernel=use_kernel)
-        p = jax.lax.psum(p, axis_name=col_axis)
+        p = _psum_partials(p, col_axis)
         if cfg.ec:
             p = denoise_least_square(
                 p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)
@@ -230,7 +237,7 @@ def make_distributed_rmvm(
         batch = y_blk.shape[1]
         p = local_dense_rmvm(at_blk, da_blk, y_blk, k, cfg,
                              tier2=False, use_kernel=use_kernel)
-        p = jax.lax.psum(p, axis_name=tuple(row_axes))
+        p = _psum_partials(p, tuple(row_axes))
         if cfg.ec:
             p = denoise_least_square(
                 p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)
@@ -333,7 +340,7 @@ def make_distributed_group_mvm(
         p = jax.vmap(lambda at, da, x, k: local_dense_mvm(
             at, da, x, k, cfg, tier2=False, use_kernel=use_kernel))(
             at_slab, da_slab, x_slab, dev_keys)
-        p = jax.lax.psum(p, axis_name=col_axis)      # ONE psum for the group
+        p = _psum_partials(p, col_axis)      # ONE psum for the group
         if cfg.ec:
             p = jax.vmap(lambda q: denoise_least_square(
                 q, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method))(p)
@@ -379,7 +386,7 @@ def make_distributed_group_rmvm(
         p = jax.vmap(lambda at, da, y, k: local_dense_rmvm(
             at, da, y, k, cfg, tier2=False, use_kernel=use_kernel))(
             at_slab, da_slab, y_slab, dev_keys)
-        p = jax.lax.psum(p, axis_name=tuple(row_axes))   # ONE psum per group
+        p = _psum_partials(p, tuple(row_axes))   # ONE psum per group
         if cfg.ec:
             p = jax.vmap(lambda q: denoise_least_square(
                 q, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method))(p)
@@ -497,7 +504,7 @@ def make_distributed_streamed_mvm(
             block_fn, at_loc, x_blk, key, cfg, m=m_loc, n=n_loc,
             use_kernel=use_kernel, tier2=False,
             block_offset=(i0, j0), grid=(mb, nb))
-        p = jax.lax.psum(p, axis_name=col_axis)
+        p = _psum_partials(p, col_axis)
         if cfg.ec:
             p = denoise_least_square(
                 p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)
@@ -564,7 +571,7 @@ def make_distributed_streamed_rmvm(
             block_fn, at_loc, y_blk, key, cfg, m=m_loc, n=n_loc,
             use_kernel=use_kernel, tier2=False,
             block_offset=(i0, j0), grid=(mb, nb))
-        p = jax.lax.psum(p, axis_name=tuple(row_axes))
+        p = _psum_partials(p, tuple(row_axes))
         if cfg.ec:
             p = denoise_least_square(
                 p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)

@@ -154,12 +154,13 @@ def denoise_least_square(
     method: str = "neumann",
 ) -> jnp.ndarray:
     """Paper Algorithm 5 (second-order EC). ``p`` is (n,) or (n, batch)."""
-    if method == "dense":
-        return _dense_inverse_apply(p, lam, h)
-    if method == "thomas":
-        return _thomas_solve(p, lam, h)
-    if method == "neumann":
-        return _neumann_apply(p, lam, h)
+    with jax.named_scope("meliso.tier2"):
+        if method == "dense":
+            return _dense_inverse_apply(p, lam, h)
+        if method == "thomas":
+            return _thomas_solve(p, lam, h)
+        if method == "neumann":
+            return _neumann_apply(p, lam, h)
     raise ValueError(f"unknown denoise method {method!r}")
 
 

@@ -165,6 +165,13 @@ __all__ = ["AnalogEngine", "AnalogMatrix", "AnalogMatrixGroup",
 EXECUTION_MODES = ("local", "streamed", "distributed")
 BACKENDS = ("reference", "pallas")
 
+#: The host span (``jax.profiler.TraceAnnotation``) around each solo or group
+#: execute, from entry to the return of the jitted call; its arguments are
+#: ``path`` (``"<execution>/<backend>"``), ``direction`` and ``cols``.  It
+#: records nothing unless the profiler is tracing.
+SPAN_EXECUTE = "meliso.engine.execute"
+_DIRECTION = {False: "forward", True: "transposed"}
+
 #: Per-handle bound on cached jitted execute pipelines.  Long-lived serving
 #: handles see many (backend, direction, batch-bucket) combinations; each
 #: cached entry pins a compiled XLA executable, so an unbounded dict is a
@@ -590,21 +597,24 @@ def _pallas_corrected(at, da, xb, key, cfg, m, n, transpose):
 
     mp, np_ = kops.matrix_shape(at)
     pad_to = mp if transpose else np_
-    x_pad = jnp.pad(xb, ((0, pad_to - xb.shape[0]), (0, 0)))
-    if cfg.encode_inputs:
-        fold = 2 if transpose else 1
-        x_t = crossbar._encode_vec(x_pad, jax.random.fold_in(key, fold), cfg)
-    else:
-        x_t = x_pad
-    if cfg.ec:
-        if transpose:
-            p = kops.rram_ec_matmul(x_pad.T, x_t.T, at, da).T[:n]
+    with jax.named_scope("meliso.dac"):
+        x_pad = jnp.pad(xb, ((0, pad_to - xb.shape[0]), (0, 0)))
+        if cfg.encode_inputs:
+            fold = 2 if transpose else 1
+            x_t = crossbar._encode_vec(x_pad, jax.random.fold_in(key, fold),
+                                       cfg)
         else:
-            p = kops.rram_ec_matmul(at, da, x_pad, x_t)[:m]
-    else:
-        dense = _assemble(at, mp, np_)
-        p = crossbar.matmul(dense.T, x_t)[:n] if transpose \
-            else crossbar.matmul(dense, x_t)[:m]
+            x_t = x_pad
+    with jax.named_scope("meliso.tier1"):
+        if cfg.ec:
+            if transpose:
+                p = kops.rram_ec_matmul(x_pad.T, x_t.T, at, da).T[:n]
+            else:
+                p = kops.rram_ec_matmul(at, da, x_pad, x_t)[:m]
+        else:
+            dense = _assemble(at, mp, np_)
+            p = crossbar.matmul(dense.T, x_t)[:n] if transpose \
+                else crossbar.matmul(dense, x_t)[:m]
     if cfg.ec:
         if cfg.denoise_method == "neumann":
             p = kops.denoise_stencil(p, lam=cfg.lam, h=cfg.h)
@@ -1277,7 +1287,17 @@ class AnalogEngine:
             return x, False
         raise ValueError(f"{direction}: input must be 1-, 2- or 3-D")
 
+    def _execute_span(self, transpose: bool):
+        """The host span :data:`SPAN_EXECUTE` of one execute."""
+        return jax.profiler.TraceAnnotation(
+            SPAN_EXECUTE, path=f"{self.execution}/{self.backend}",
+            direction=_DIRECTION[transpose])
+
     def _group_execute(self, G, x, key, with_stats=False, transpose=False):
+        with self._execute_span(transpose) as span:
+            return self._group_run(G, x, key, with_stats, transpose, span)
+
+    def _group_run(self, G, x, key, with_stats, transpose, span):
         if not isinstance(G, AnalogMatrixGroup):
             raise TypeError("group_mvm takes an AnalogMatrixGroup; use "
                             "engine.mvm for solo handles")
@@ -1295,6 +1315,7 @@ class AnalogEngine:
                 "the group holds mesh-sharded operands but this engine "
                 f"executes {self.execution!r}; build it with this engine")
         xb, squeeze = self._group_input(G, x, transpose)
+        span.set_metadata(cols=xb.shape[2])
         keys = self._group_keys(G, key, x)
         G.calls += 1
         m, n = G.shape
@@ -1425,6 +1446,10 @@ class AnalogEngine:
             return A.parent.engine._execute(A.parent, x, key,
                                             with_stats=with_stats,
                                             transpose=not transpose)
+        with self._execute_span(transpose) as span:
+            return self._execute_solo(A, x, key, with_stats, transpose, span)
+
+    def _execute_solo(self, A, x, key, with_stats, transpose, span):
         if A.engine is not self and A.engine.cfg != self.cfg:
             raise ValueError("AnalogMatrix was programmed by an incompatible "
                              "engine configuration")
@@ -1444,6 +1469,7 @@ class AnalogEngine:
                 f"executes {self.execution!r}; program it with this engine")
         squeeze = x.ndim == 1
         xb = x[:, None] if squeeze else x
+        span.set_metadata(cols=xb.shape[1])
         contraction = A.m if transpose else A.n
         if xb.shape[0] != contraction:
             direction = "A.T @ y" if transpose else "A @ x"
@@ -1601,24 +1627,25 @@ class AnalogEngine:
                 x_t = crossbar._encode_vec(x_blk, k_x, cfg) \
                     if cfg.encode_inputs else x_blk
                 from repro.kernels import ops as kops
-                if transpose:
+                with jax.named_scope("meliso.tier1"):
+                    if transpose:
+                        if not cfg.ec:
+                            return at_blk.T @ x_t
+                        if use_kernel:
+                            return kops.rram_ec_tile_rmvm(x_blk, x_t, at_blk,
+                                                          a_blk - at_blk)
+                        if cfg.ec_mode == "faithful":
+                            return (at_blk.T @ x_blk + a_blk.T @ x_t
+                                    - at_blk.T @ x_t)
+                        return at_blk.T @ x_blk + (a_blk - at_blk).T @ x_t
                     if not cfg.ec:
-                        return at_blk.T @ x_t
+                        return at_blk @ x_t
                     if use_kernel:
-                        return kops.rram_ec_tile_rmvm(x_blk, x_t, at_blk,
-                                                      a_blk - at_blk)
+                        return kops.rram_ec_tile_mvm(x_blk, x_t, at_blk,
+                                                     a_blk - at_blk)
                     if cfg.ec_mode == "faithful":
-                        return (at_blk.T @ x_blk + a_blk.T @ x_t
-                                - at_blk.T @ x_t)
-                    return at_blk.T @ x_blk + (a_blk - at_blk).T @ x_t
-                if not cfg.ec:
-                    return at_blk @ x_t
-                if use_kernel:
-                    return kops.rram_ec_tile_mvm(x_blk, x_t, at_blk,
-                                                 a_blk - at_blk)
-                if cfg.ec_mode == "faithful":
-                    return at_blk @ x_blk + a_blk @ x_t - at_blk @ x_t
-                return at_blk @ x_blk + (a_blk - at_blk) @ x_t
+                        return at_blk @ x_blk + a_blk @ x_t - at_blk @ x_t
+                    return at_blk @ x_blk + (a_blk - at_blk) @ x_t
 
             # Jitted once per engine (per direction/backend): execute-many
             # calls reuse the trace.

@@ -112,12 +112,14 @@ def rram_ec_matmul(
         bm, bk = _fit(bm, l1.shape[2]), _fit(bk, l1.shape[3])
     if r1.ndim == 4:
         bk, bn = _fit(bk, r1.shape[2]), _fit(bn, r1.shape[3])
-    left = [a if a.ndim == 4 else _pad_to(a, (bm, bk)) for a in (l1, l2)]
-    right = [a if a.ndim == 4 else _pad_to(a, (bk, bn)) for a in (r1, r2)]
-    out = _ec_matmul(
-        *left, *right, block_m=bm, block_k=bk, block_n=bn,
-        interpret=on_cpu() if interpret is None else interpret)
-    return out[:m, :n]
+    with jax.named_scope("meliso.tier1"):
+        left = [a if a.ndim == 4 else _pad_to(a, (bm, bk)) for a in (l1, l2)]
+        right = [a if a.ndim == 4 else _pad_to(a, (bk, bn))
+                 for a in (r1, r2)]
+        out = _ec_matmul(
+            *left, *right, block_m=bm, block_k=bk, block_n=bn,
+            interpret=on_cpu() if interpret is None else interpret)
+        return out[:m, :n]
 
 
 def rram_ec_tile_mvm(
@@ -157,8 +159,9 @@ def rram_ec_tile_rmvm(
     ``y_blk``/``y_t``: (cap_m, batch); ``at_blk``/``da_blk``:
     (cap_m, cap_n).  Returns fp32 (cap_n, batch).
     """
-    return rram_ec_matmul(y_blk.T, y_t.T, at_blk, da_blk,
-                          interpret=interpret).T
+    with jax.named_scope("meliso.tier1"):
+        return rram_ec_matmul(y_blk.T, y_t.T, at_blk, da_blk,
+                              interpret=interpret).T
 
 
 def rram_ec_group_mvm(
@@ -182,7 +185,8 @@ def rram_ec_group_mvm(
         x, x_t, at, da = ops
         return rram_ec_tile_mvm(x, x_t, at, da, interpret=interpret)
 
-    return jax.lax.map(one, (x_g, x_t_g, at_g, da_g))
+    with jax.named_scope("meliso.tier1"):
+        return jax.lax.map(one, (x_g, x_t_g, at_g, da_g))
 
 
 def rram_ec_group_rmvm(
@@ -201,7 +205,8 @@ def rram_ec_group_rmvm(
         y, y_t, at, da = ops
         return rram_ec_tile_rmvm(y, y_t, at, da, interpret=interpret)
 
-    return jax.lax.map(one, (y_g, y_t_g, at_g, da_g))
+    with jax.named_scope("meliso.tier1"):
+        return jax.lax.map(one, (y_g, y_t_g, at_g, da_g))
 
 
 def solver_richardson_update(
@@ -241,10 +246,11 @@ def denoise_thomas(
     """Exact tier-2 solve for (n, batch) panels."""
     n, b = p.shape
     bb = min(block_b, max(1, b))
-    pp = _pad_to(p, (1, bb))
-    out = _thomas(pp, lam=lam, h=h, block_b=bb,
-                  interpret=on_cpu() if interpret is None else interpret)
-    return out[:, :b]
+    with jax.named_scope("meliso.tier2"):
+        pp = _pad_to(p, (1, bb))
+        out = _thomas(pp, lam=lam, h=h, block_b=bb,
+                      interpret=on_cpu() if interpret is None else interpret)
+        return out[:, :b]
 
 
 def denoise_stencil(
@@ -254,7 +260,8 @@ def denoise_stencil(
     """Truncated-Neumann tier-2 denoise for (n, batch) panels."""
     n, b = p.shape
     bb = min(block_b, max(1, b))
-    pp = _pad_to(p, (1, bb))
-    out = _stencil(pp, lam=lam, h=h, block_b=bb,
-                   interpret=on_cpu() if interpret is None else interpret)
-    return out[:, :b]
+    with jax.named_scope("meliso.tier2"):
+        pp = _pad_to(p, (1, bb))
+        out = _stencil(pp, lam=lam, h=h, block_b=bb,
+                       interpret=on_cpu() if interpret is None else interpret)
+        return out[:, :b]

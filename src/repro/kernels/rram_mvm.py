@@ -101,6 +101,7 @@ def encode_matmul(
     return pl.pallas_call(
         functools.partial(
             _encode_matmul_kernel, sigma=sigma, levels=levels, nsteps=grid[2]),
+        name="encode_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, s: (i, s)),
@@ -189,6 +190,7 @@ def encode_matmul_rng(
         interpret = pltpu.InterpretParams()
     return pl.pallas_call(
         functools.partial(_encode_matmul_rng_kernel, sigma=sigma, levels=levels),
+        name="encode_matmul_rng",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -284,6 +286,7 @@ def ec_matmul(
              for a in (r1, r2)]
     return pl.pallas_call(
         _ec_matmul_kernel,
+        name="ec_matmul",
         grid=grid,
         in_specs=left + right,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),

@@ -64,6 +64,7 @@ def richardson_update(
     row = pl.BlockSpec((block_n, bt), lambda i: (i, 0))
     return pl.pallas_call(
         _richardson_kernel,
+        name="richardson_update",
         grid=grid,
         in_specs=[row, row, row, pl.BlockSpec((1, 1), lambda i: (0, 0))],
         out_specs=(row, row),
@@ -102,6 +103,7 @@ def cg_update(
     row = pl.BlockSpec((block_n, bt), lambda i: (i, 0))
     return pl.pallas_call(
         _cg_kernel,
+        name="cg_update",
         grid=grid,
         in_specs=[row, row, row, row, pl.BlockSpec((1, bt), lambda i: (0, 0))],
         out_specs=(row, row),

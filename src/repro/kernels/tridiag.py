@@ -90,6 +90,7 @@ def _sweep_call(src, coef, *, a_coef, block_r, block_b, reverse, interpret):
     return pl.pallas_call(
         functools.partial(_sweep, a_coef=a_coef, block_r=block_r,
                           reverse=reverse),
+        name="thomas_solve",
         grid=(b // block_b, nr),
         in_specs=[pl.BlockSpec((block_r, block_b), rows),
                   pl.BlockSpec((block_r, 1), coef_rows)],
@@ -178,6 +179,7 @@ def stencil_denoise(
     last = nr * per - 1
     return pl.pallas_call(
         functools.partial(_stencil_kernel, lam=lam, h=h, n=n, block_r=br),
+        name="stencil_denoise",
         grid=(b // block_b, nr),
         in_specs=[
             pl.BlockSpec((br, block_b), lambda j, r: (r, j)),

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, jit_core, pack_result)
+                   init_history, jit_core, pack_result, solver_core)
 from .pdhg import _power_norm
 
 __all__ = ["admm", "admm_pipeline", "random_box_qp"]
@@ -91,6 +91,7 @@ def random_box_qp(
     return a, b, q, lo, hi, x_star
 
 
+@solver_core
 def _admm_core(op: LinearOperator, b, q, x0, key, *, lo, hi, rho: float,
                mu, tol: float, maxiter: int, power_iters: int):
     batch = b.shape[1]

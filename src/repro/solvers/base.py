@@ -40,10 +40,14 @@ from repro.engine import AnalogMatrix, TransposedAnalogMatrix
 
 __all__ = [
     "LinearOperator", "SolveLedger", "SolveResult", "as_operator",
-    "col_norms", "init_history", "jit_core", "use_pallas",
+    "col_norms", "init_history", "jit_core", "use_pallas", "solver_core",
+    "dispatched", "SPAN_DISPATCH",
 ]
 
 _TINY = 1e-30
+
+#: The host span around each call of a jitted solver core.
+SPAN_DISPATCH = "meliso.solver.dispatch"
 
 
 def use_pallas(backend: Optional[str]) -> bool:
@@ -121,18 +125,39 @@ class LinearOperator:
         )
 
 
+def solver_core(core: Callable) -> Callable:
+    """Decorator of a solver core: its operations are named ``meliso.solver``
+    in traces.  The matvecs it calls keep their own stage names (the
+    innermost scope names an operation)."""
+    @functools.wraps(core)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("meliso.solver"):
+            return core(*args, **kwargs)
+    return scoped
+
+
+def dispatched(core: Callable) -> Callable:
+    """``core`` with each call inside the host span :data:`SPAN_DISPATCH`
+    (which records nothing unless the profiler is tracing)."""
+    def dispatch(*args):
+        with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+            return core(*args)
+    return dispatch
+
+
 def jit_core(op: LinearOperator, build: Callable[[LinearOperator], Callable]
              ) -> Callable:
-    """``jax.jit(build(op))`` with ``op.operands`` passed as arguments.
+    """``jax.jit(build(op))`` with ``op.operands`` passed as arguments, each
+    call :func:`dispatched`.
 
     A jitted function captures the arrays it closes over as constants of the
     compiled program: a second device copy, and host copies while it
     compiles.  Binding the operator to the jit's own arguments keeps a
     programmed image a single buffer however large it is."""
     if op.bind is None:
-        return jax.jit(build(op))
+        return dispatched(jax.jit(build(op)))
     core = jax.jit(lambda operands, *args: build(op.bind(operands))(*args))
-    return functools.partial(core, op.operands)
+    return dispatched(functools.partial(core, op.operands))
 
 
 def _zero_stats(_batch: int = 1) -> WriteStats:

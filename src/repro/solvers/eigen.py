@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, jit_core, pack_result)
+                   init_history, jit_core, pack_result, solver_core)
 from .stationary import _power_iterate
 
 __all__ = ["lanczos", "lobpcg", "operator_norm", "lanczos_pipeline",
@@ -59,6 +59,7 @@ def _unconverged(rel, tol):
 # Lanczos
 # --------------------------------------------------------------------------- #
 
+@solver_core
 def _lanczos_core(op: LinearOperator, key, *, tol: float, maxiter: int,
                   seed_iters: int):
     n = op.n
@@ -198,6 +199,7 @@ def _rayleigh_ritz(s_basis, a_s, nev: int, largest: bool):
     return theta[sel], s_basis @ c_sel, a_s @ c_sel
 
 
+@solver_core
 def _lobpcg_core(op: LinearOperator, x0, key, *, tol: float, maxiter: int,
                  largest: bool):
     nev = x0.shape[1]

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, jit_core, pack_result, use_pallas)
+                   init_history, jit_core, pack_result, solver_core, use_pallas)
 
 __all__ = ["cg", "bicgstab", "gmres", "cg_pipeline"]
 
@@ -67,6 +67,7 @@ def _prep(b, x0):
 # Conjugate gradients (SPD)
 # --------------------------------------------------------------------------- #
 
+@solver_core
 def _cg_core(op: LinearOperator, b, x0, key, *, tol: float, maxiter: int,
              use_pallas: bool, divergence: Optional[float] = None):
     batch = b.shape[1]
@@ -183,6 +184,7 @@ def cg(
 # BiCGSTAB (general square A)
 # --------------------------------------------------------------------------- #
 
+@solver_core
 def _bicgstab_core(op: LinearOperator, b, x0, key, *, tol: float,
                    maxiter: int):
     batch = b.shape[1]
@@ -287,6 +289,7 @@ def _gmres_cycle(op: LinearOperator, x, r, key, m: int):
     return x + dx
 
 
+@solver_core
 def _gmres_core(op: LinearOperator, b, x0, key, *, tol: float, maxiter: int,
                 restart: int):
     batch = b.shape[1]

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, jit_core, pack_result)
+                   init_history, jit_core, pack_result, solver_core)
 
 __all__ = ["lsqr", "lsmr", "lsqr_pipeline", "lsmr_pipeline"]
 
@@ -112,6 +112,7 @@ def _bidiag_step(op, u, v, alpha, key, k):
 # LSQR (Paige & Saunders 1982)
 # --------------------------------------------------------------------------- #
 
+@solver_core
 def _lsqr_core(op: LinearOperator, b, x0, key, *, tol: float, maxiter: int,
                explicit_x0: bool):
     batch = b.shape[1]
@@ -175,6 +176,7 @@ def lsqr_pipeline(
 # LSMR (Fong & Saunders 2011)
 # --------------------------------------------------------------------------- #
 
+@solver_core
 def _lsmr_core(op: LinearOperator, b, x0, key, *, tol: float, maxiter: int,
                explicit_x0: bool):
     batch = b.shape[1]

@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, jit_core, pack_result)
+                   init_history, jit_core, pack_result, solver_core)
 
 __all__ = ["pdhg", "pdhg_pipeline", "random_feasible_lp"]
 
@@ -113,6 +113,7 @@ def _power_norm(op: LinearOperator, key: jax.Array, iters: int) -> jnp.ndarray:
     return jnp.sqrt(jnp.maximum(lam, _TINY))
 
 
+@solver_core
 def _pdhg_core(op: LinearOperator, b, c, x0, y0, key, *, tau, sigma, eta,
                tol: float, maxiter: int, power_iters: int,
                divergence: Optional[float] = None):

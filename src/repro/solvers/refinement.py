@@ -24,8 +24,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .base import (SolveResult, as_operator, col_norms, init_history,
-                   pack_result, use_pallas)
+from .base import (SolveResult, as_operator, col_norms, dispatched,
+                   init_history, pack_result, solver_core, use_pallas)
 from .krylov import _cg_core
 from .stationary import _stationary_core, spectral_bounds
 
@@ -92,6 +92,7 @@ def refine(
             _stationary_core, op, None, omega=omega, tol=inner_tol,
             maxiter=inner_iters, use_pallas=pallas, power_iters=0)
 
+    @solver_core
     def core(b, x0, key):
         batch = b.shape[1]
         bn = jnp.maximum(col_norms(b), _TINY)
@@ -119,6 +120,6 @@ def refine(
         k, x, _r, _rel, hist, mvms = jax.lax.while_loop(cond, body, state0)
         return x, hist, k, mvms, rel0
 
-    x, hist, k, mvms, rel0 = jax.jit(core)(bb, x0b, key)
+    x, hist, k, mvms, rel0 = dispatched(jax.jit(core))(bb, x0b, key)
     return pack_result(op, f"refine[{inner}]", x, hist, k, mvms, tol, squeeze,
                        mvms_single=mvms_single, rel0=rel0)

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import (LinearOperator, SolveResult, as_operator, col_norms,
-                   init_history, jit_core, pack_result, use_pallas)
+                   init_history, jit_core, pack_result, solver_core, use_pallas)
 
 __all__ = ["richardson", "jacobi", "spectral_bounds", "estimate_omega"]
 
@@ -110,6 +110,7 @@ def estimate_omega(A, *, key: Optional[jax.Array] = None,
     return float(2.0 / (1.05 * lmax + max(lmin, 0.0)))
 
 
+@solver_core
 def _stationary_core(op: LinearOperator, scale_fn, b, x0, key, omega,
                      tol: float, maxiter: int, use_pallas: bool,
                      power_iters: int):

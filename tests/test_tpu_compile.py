@@ -7,6 +7,8 @@ slices, unsupported casts.  Nothing runs.  The kernels are called with
 ``interpret=False`` because the wrappers would choose interpret mode on the
 CPU backend the suite runs on.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -47,6 +49,19 @@ def _compile(fn, sharding, *shapes, dtype=jnp.float32):
             if isinstance(s, tuple) else s for s in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text      # the kernel is in the program
+    return text
+
+
+def _kernel_ops(text):
+    """(instruction, innermost meliso scope or "") of each kernel call."""
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = .*custom-call\(", line)
+        if m and 'custom_call_target="tpu_custom_call"' in line:
+            path = re.search(r'op_name="([^"]*)"', line)
+            scopes = re.findall(r"meliso\.\w+", path.group(1) if path else "")
+            out.append((m.group(1), scopes[-1] if scopes else ""))
+    return out
 
 
 @pytest.mark.parametrize("batch", [1, 64])
@@ -96,3 +111,62 @@ def test_solver_updates(one_chip):
     _compile(lambda x, r, p, ap, al: kops.solver_cg_update(
         x, r, p, ap, al, interpret=False), one_chip, col, col, col, col,
         (1,))
+
+
+# Each kernel's op in a device trace is its HLO instruction, named after its
+# pallas_call's ``name=``: a refactor of the wrappers cannot rename it.
+KERNELS = {
+    "ec_matmul": (lambda a, d, x, xt: kops.rram_ec_matmul(
+        a, d, x, xt, interpret=False), [(4, 4, CAP, CAP)] * 2
+        + [(4 * CAP, 8)] * 2),
+    "stencil_denoise": (lambda p: kops.denoise_stencil(
+        p, lam=1e-12, interpret=False), [(N_DENOISE, 8)]),
+    "thomas_solve": (lambda p: kops.denoise_thomas(
+        p, lam=1e-12, interpret=False), [(N_DENOISE, 8)]),
+    "cg_update": (lambda x, r, p, ap, al: kops.solver_cg_update(
+        x, r, p, ap, al, interpret=False), [(N_SOLVE, 1)] * 4 + [(1,)]),
+    "richardson_update": (lambda x, b, y: kops.solver_richardson_update(
+        x, b, y, 0.3, interpret=False), [(N_SOLVE, 1)] * 3),
+    "encode_matmul": (lambda x, w, e: kops.rram_encode_matmul(
+        x, w, e, sigma=0.1, levels=8, interpret=False),
+        [(256, CAP), (CAP, CAP), (CAP, CAP)]),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_kernel_op_names(one_chip, kernel):
+    fn, shapes = KERNELS[kernel]
+    ops = _kernel_ops(_compile(fn, one_chip, *shapes))
+    assert ops and all(re.fullmatch(rf"%{kernel}(\.\d+)?", name)
+                       for name, _ in ops), ops
+
+
+def test_encode_matmul_rng_op_name(one_chip):
+    seed = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    ops = _kernel_ops(_compile(
+        lambda s, x, w: encode_matmul_rng(s, x, w, sigma=0.1, levels=8,
+                                          interpret=False),
+        one_chip, seed, (256, CAP), (CAP, CAP)))
+    assert [name for name, _ in ops] == ["%encode_matmul_rng.1"], ops
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_resident_mvm_kernel_names_and_stages(one_chip, monkeypatch, batch):
+    """The resident pallas MVM compiled for the chip: the tier-1 kernel is
+    ``%ec_matmul`` (what the trace's ``%ec_matmul.<k> custom-call`` events
+    are matched by) under ``meliso.tier1``, tier-2 ``%stencil_denoise``
+    under ``meliso.tier2``."""
+    from repro.core import CrossbarConfig, MCAGeometry, get_device
+    from repro.engine import _pallas_corrected
+    monkeypatch.setattr(kops, "on_cpu", lambda: False)
+    cfg = CrossbarConfig(device=get_device("epiram"),
+                         geom=MCAGeometry(4, 4, 512, 512))
+    n = 4 * CAP
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    ops = _kernel_ops(_compile(
+        lambda at, da, x, k: _pallas_corrected(at, da, x, k, cfg, n, n,
+                                               False),
+        one_chip, (4, 4, CAP, CAP), (4, 4, CAP, CAP), (n, batch), key))
+    kinds = {re.sub(r"\.\d+$", "", name): scope for name, scope in ops}
+    assert kinds == {"%ec_matmul": "meliso.tier1",
+                     "%stencil_denoise": "meliso.tier2"}, ops
