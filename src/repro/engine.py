@@ -167,8 +167,10 @@ BACKENDS = ("reference", "pallas")
 
 #: The host span (``jax.profiler.TraceAnnotation``) around each solo or group
 #: execute, from entry to the return of the jitted call; its arguments are
-#: ``path`` (``"<execution>/<backend>"``), ``direction`` and ``cols``.  It
-#: records nothing unless the profiler is tracing.
+#: ``path`` (``"<execution>/<backend>"``), ``direction``, ``cols`` and, where
+#: a tier-1 kernel runs, ``tier1`` (``"vpu"`` or ``"mxu"``,
+#: :func:`repro.kernels.ops.tier1_form`).  It records nothing unless the
+#: profiler is tracing.
 SPAN_EXECUTE = "meliso.engine.execute"
 _DIRECTION = {False: "forward", True: "transposed"}
 
@@ -1287,6 +1289,23 @@ class AnalogEngine:
             return x, False
         raise ValueError(f"{direction}: input must be 1-, 2- or 3-D")
 
+    def tier1_form(self, cols: int, transpose: bool = False):
+        """The form of the tier-1 kernel an execute of ``cols`` input columns
+        runs (:func:`repro.kernels.ops.tier1_form`), or None where none runs:
+        the reference backend, no EC, or a mesh that cannot host the kernel."""
+        if self.backend != "pallas" or not self.cfg.ec or (
+                self.execution == "distributed"
+                and not self._dist_use_kernel()):
+            return None
+        from repro.kernels import ops as kops
+        return kops.tier1_form(cols, transposed=transpose)
+
+    def _span_cols(self, span, cols: int, transpose: bool) -> None:
+        """Record ``cols`` and the tier-1 form on the execute span."""
+        form = self.tier1_form(cols, transpose)
+        span.set_metadata(cols=cols, **({} if form is None
+                                         else {"tier1": form}))
+
     def _execute_span(self, transpose: bool):
         """The host span :data:`SPAN_EXECUTE` of one execute."""
         return jax.profiler.TraceAnnotation(
@@ -1315,7 +1334,7 @@ class AnalogEngine:
                 "the group holds mesh-sharded operands but this engine "
                 f"executes {self.execution!r}; build it with this engine")
         xb, squeeze = self._group_input(G, x, transpose)
-        span.set_metadata(cols=xb.shape[2])
+        self._span_cols(span, xb.shape[2], transpose)
         keys = self._group_keys(G, key, x)
         G.calls += 1
         m, n = G.shape
@@ -1469,7 +1488,7 @@ class AnalogEngine:
                 f"executes {self.execution!r}; program it with this engine")
         squeeze = x.ndim == 1
         xb = x[:, None] if squeeze else x
-        span.set_metadata(cols=xb.shape[1])
+        self._span_cols(span, xb.shape[1], transpose)
         contraction = A.m if transpose else A.n
         if xb.shape[0] != contraction:
             direction = "A.T @ y" if transpose else "A @ x"

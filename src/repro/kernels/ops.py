@@ -14,7 +14,9 @@ import jax
 import jax.numpy as jnp
 
 from .rram_mvm import DEFAULT_BLOCK_K, DEFAULT_BLOCK_M, DEFAULT_BLOCK_N
+from .rram_mvm import DEFAULT_MATVEC_BLOCK_K, DEFAULT_MATVEC_BLOCK_M
 from .rram_mvm import ec_matmul as _ec_matmul
+from .rram_mvm import ec_matvec as _ec_matvec
 from .rram_mvm import encode_matmul as _encode_matmul
 from .rram_mvm import matrix_shape
 from .solver_update import cg_update as _cg_update
@@ -24,6 +26,7 @@ from .tridiag import thomas_solve as _thomas
 
 __all__ = [
     "on_cpu",
+    "tier1_form",
     "rram_encode_matmul",
     "rram_ec_matmul",
     "rram_ec_tile_mvm",
@@ -62,6 +65,19 @@ def _fit(block: int, cap: int) -> int:
     return block if cap % block == 0 else cap
 
 
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def tier1_form(cols: int, transposed: bool = False) -> str:
+    """The tier-1 form :func:`rram_ec_matmul` takes for a corrected MVM of
+    ``cols`` input columns: ``"vpu"`` (the single-column VPU form) where one
+    column meets the image on the left -- a forward MVM at batch 1 --, else
+    ``"mxu"``.  A transposed MVM puts the input on the left and the image on
+    the right, whose columns are the image's, so it keeps the MXU form."""
+    return "vpu" if cols == 1 and not transposed else "mxu"
+
+
 def rram_encode_matmul(
     x: jnp.ndarray,
     w: jnp.ndarray,
@@ -94,32 +110,60 @@ def rram_ec_matmul(
     r1: jnp.ndarray,
     r2: jnp.ndarray,
     *,
-    block_m: int = DEFAULT_BLOCK_M,
-    block_k: int = DEFAULT_BLOCK_K,
-    block_n: int = DEFAULT_BLOCK_N,
+    block_m: int | None = None,
+    block_k: int | None = None,
+    block_n: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused tier-1 EC matmul p = l1 @ r1 + l2 @ r2.
 
     Either operand pair may be a (mb, nb, cap_m, cap_n) capacity-block stack
     (read in place, tiles chosen to divide the capacity block); 2-D operands
-    are padded to the tile grid and the result is un-padded.
+    are padded to the tile grid and the result is un-padded.  The form
+    follows the right-hand pair's column count (:func:`tier1_form`): one
+    column runs the VPU form (``ec_matvec``, tiles ``block_m`` x ``block_k``,
+    default ``DEFAULT_MATVEC_BLOCK_*``, ``block_n`` unused), any other the
+    MXU form (``ec_matmul``, default ``DEFAULT_BLOCK_*``).
     """
     m, k = matrix_shape(l1)
     _, n = matrix_shape(r1)
-    bm, bk, bn = _pick_blocks(m, k, n, block_m, block_k, block_n)
-    if l1.ndim == 4:
-        bm, bk = _fit(bm, l1.shape[2]), _fit(bk, l1.shape[3])
-    if r1.ndim == 4:
-        bk, bn = _fit(bk, r1.shape[2]), _fit(bn, r1.shape[3])
+    interpret = on_cpu() if interpret is None else interpret
     with jax.named_scope("meliso.tier1"):
+        if tier1_form(n) == "vpu":
+            out = _tier1_vpu(l1, l2, r1, r2, m, k,
+                             block_m or DEFAULT_MATVEC_BLOCK_M,
+                             block_k or DEFAULT_MATVEC_BLOCK_K, interpret)
+            return out[:m]
+        bm, bk, bn = _pick_blocks(m, k, n, block_m or DEFAULT_BLOCK_M,
+                                  block_k or DEFAULT_BLOCK_K,
+                                  block_n or DEFAULT_BLOCK_N)
+        if l1.ndim == 4:
+            bm, bk = _fit(bm, l1.shape[2]), _fit(bk, l1.shape[3])
+        if r1.ndim == 4:
+            bk, bn = _fit(bk, r1.shape[2]), _fit(bn, r1.shape[3])
         left = [a if a.ndim == 4 else _pad_to(a, (bm, bk)) for a in (l1, l2)]
         right = [a if a.ndim == 4 else _pad_to(a, (bk, bn))
                  for a in (r1, r2)]
-        out = _ec_matmul(
-            *left, *right, block_m=bm, block_k=bk, block_n=bn,
-            interpret=on_cpu() if interpret is None else interpret)
+        out = _ec_matmul(*left, *right, block_m=bm, block_k=bk, block_n=bn,
+                         interpret=interpret)
         return out[:m, :n]
+
+
+def _tier1_vpu(l1, l2, r1, r2, m, k, bm, bk, interpret):
+    """The VPU form of :func:`rram_ec_matmul` for a one-column right pair:
+    the column becomes a lane-major (1, k) row, tiles fit the capacity block
+    or the (padded) matrix.  Returns (m_padded, 1)."""
+    if l1.ndim == 4:
+        bm, bk = _fit(bm, l1.shape[2]), _fit(bk, l1.shape[3])
+        left = [l1, l2]
+    else:
+        bm = min(bm, _round_up(m, 8))
+        bk = min(bk, _round_up(k, 128))
+        left = [_pad_to(a, (bm, bk)) for a in (l1, l2)]
+    kp = matrix_shape(left[0])[1]
+    rows = [_pad_to(r.reshape(1, k), (1, kp)) for r in (r1, r2)]
+    return _ec_matvec(*left, *rows, block_m=bm, block_k=bk,
+                      interpret=interpret)
 
 
 def rram_ec_tile_mvm(

@@ -1,32 +1,51 @@
 """Pallas TPU kernels for the RRAM crossbar MVM simulation.
 
-Two kernels, both tiled so that one (block_k x block_n) weight tile == one MCA
-array: the VMEM tile *is* the crossbar, and the grid iteration over K-blocks is
-the virtualization reassignment loop (DESIGN.md section 2).
+The MXU kernels are tiled so that one (block_k x block_n) weight tile == one
+MCA array: the VMEM tile *is* the crossbar, and the grid iteration over
+K-blocks is the virtualization reassignment loop (DESIGN.md section 2).
 
   * ``encode_matmul``: y = x_tilde @ W_tilde with the encode (per-tile
     conductance quantization + programming noise) computed **in-VMEM**, so the
     encoded weights never round-trip to HBM.  This is the analog-simulation
     fast path: one HBM read of W instead of (write W_tilde + read W_tilde).
 
-  * ``ec_matmul``: the two-tier-EC serving path.  Computes the fused tier-1
-    combination p = l1 @ r1 + l2 @ r2 -- the image pair (A_tilde, dA) on one
-    side, the input pair (x, x_tilde) on the other -- issuing two MXU dots per
-    block, 33% fewer FLOPs than the paper's three analog products.  Either
-    pair may be a (mb, nb, cap_m, cap_n) capacity-block stack, read in place
-    as the matrix it tiles, so a programmed image is never transposed or
-    re-assembled into a dense copy.
+  * the fused tier-1 EC product p = l1 @ r1 + l2 @ r2 of the serving path --
+    the image pair (A_tilde, dA) on one side, the input pair (x, x_tilde) on
+    the other, 33% fewer FLOPs than the paper's three analog products.
+    Either pair may be a (mb, nb, cap_m, cap_n) capacity-block stack, read in
+    place as the matrix it tiles, so a programmed image is never transposed
+    or re-assembled into a dense copy.  It has two forms, both one
+    ``pallas_call`` named ``ec_matmul``:
+
+      - ``ec_matmul`` (the MXU form): two MXU dots per block.  It serves every
+        right-hand pair of more than one column -- a batch of inputs against
+        the image on the left, or the transposed MVM, whose right-hand pair
+        is the image itself.
+      - ``ec_matvec`` (the VPU form): a right-hand pair of ONE column, i.e. a
+        batch-1 forward MVM with the image on the left (every solver
+        iteration).  An MXU pass would pad that column to a 128-wide panel
+        and spend several bf16 passes per f32 product on it; here each image
+        tile is multiplied elementwise by the input, held as a lane-major
+        (1, k) row, and folded into a lane-wide f32 accumulator with VPU adds,
+        so the product streams the image at HBM speed.
+
+    :func:`repro.kernels.ops.rram_ec_matmul` picks the form from the shape
+    (:func:`repro.kernels.ops.tier1_form`).
 
 Block shapes default to (512, 512) weight tiles (the paper's best-performing
 MCA cell size, conveniently 4x the 128x128 MXU tile) and 256-row activation
-panels; fp32 accumulation in the output ref across the K grid dimension.
+panels; fp32 accumulation in the output ref across the K grid dimension.  The
+VPU form reads larger (DEFAULT_MATVEC_BLOCK_M, DEFAULT_MATVEC_BLOCK_K) image
+tiles, chosen by a sweep on a TPU v5e (PERF.md).
 
 Every f32 dot runs at the simulation's matmul precision,
-:data:`repro.core.crossbar.PRECISION`.
+:data:`repro.core.crossbar.PRECISION`; the VPU form's products are full f32
+multiplies, so it is as exact (only the order of summation differs).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -35,11 +54,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.crossbar import PRECISION
 
-__all__ = ["encode_matmul", "ec_matmul"]
+__all__ = ["encode_matmul", "ec_matmul", "ec_matvec"]
 
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_K = 512   # MCA cell rows (contraction)
 DEFAULT_BLOCK_N = 512   # MCA cell cols (output features)
+DEFAULT_MATVEC_BLOCK_M = 1024   # image rows per VPU-form tile
+DEFAULT_MATVEC_BLOCK_K = 2048   # image cols (contraction) per VPU-form tile
 
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -292,5 +313,107 @@ def ec_matmul(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         compiler_params=_PARAMS,
+        interpret=interpret,
+    )(l1, l2, r1, r2)
+
+
+# --------------------------------------------------------------------------- #
+# ec_matvec: the single-column fused tier-1 product on the VPU
+# --------------------------------------------------------------------------- #
+
+_LANES = 128
+_STRIP_ROWS = 32   # rows of the partial sum one strip keeps in registers
+_VMEM_SLACK = 4 << 20   # room for Mosaic's own scratch
+
+
+def _ec_matvec_kernel(l1_ref, l2_ref, r1_ref, r2_ref, o_ref, acc_ref, *,
+                      rows):
+    """One (bm, 1) output block of l1 @ r1 + l2 @ r2 for a single column.
+
+    ``r1``/``r2`` are (1, bk) lane-major rows.  Strip by strip of ``rows``
+    rows, the (rows, bk) image tiles are multiplied elementwise by the
+    broadcast rows and their bk / w lane chunks folded into a (rows, w) f32
+    partial sum, added to the (bm, w) accumulator in VMEM scratch: VPU
+    multiplies and adds only.  The last K step reduces across the lanes.
+    """
+    k_step = pl.program_id(1)
+    bm, bk = l1_ref.shape
+    w = acc_ref.shape[1]
+    f32 = jnp.float32
+
+    @pl.when(k_step == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def strip(i, carry):
+        r = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        acc = acc_ref[r, :]
+        for c in range(bk // w):
+            cols = pl.ds(c * w, w)
+            acc += l1_ref[r, cols].astype(f32) * r1_ref[:, cols].astype(f32)
+            acc += l2_ref[r, cols].astype(f32) * r2_ref[:, cols].astype(f32)
+        acc_ref[r, :] = acc
+        return carry
+
+    jax.lax.fori_loop(0, bm // rows, strip, 0)
+
+    @pl.when(k_step == pl.num_programs(1) - 1)
+    def _out():
+        o_ref[...] = jnp.sum(acc_ref[...], axis=1, keepdims=True)
+
+
+def _matvec_vmem_bytes(block_m: int, block_k: int, lanes: int,
+                       itemsize: int) -> int:
+    """VMEM the VPU form's tiles take: two buffers of each image tile, of
+    each (1, bk) row and of the (bm, 1) output (both padded to (8, 128)
+    tiles), the accumulator, and Mosaic's slack."""
+    rows_bytes = 2 * 8 * max(block_k, _LANES) * 4
+    blocks = 2 * block_m * block_k * itemsize + rows_bytes \
+        + block_m * _LANES * 4
+    return 2 * blocks + block_m * lanes * 4 + _VMEM_SLACK
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block_m", "block_k", "interpret"))
+def ec_matvec(
+    l1: jnp.ndarray,
+    l2: jnp.ndarray,
+    r1: jnp.ndarray,
+    r2: jnp.ndarray,
+    *,
+    block_m: int = DEFAULT_MATVEC_BLOCK_M,
+    block_k: int = DEFAULT_MATVEC_BLOCK_K,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Single-column fused tier-1 EC product p = l1 @ r1 + l2 @ r2, on the VPU.
+
+    ``l1``, ``l2``: (m, k) or a capacity-block stack of that matrix (the
+    image pair, read in place); ``r1``, ``r2``: (1, k), the input column and
+    its DAC image as lane-major rows.  Each product is a full f32 multiply and
+    the sums are f32, as exact as :func:`ec_matmul` at ``HIGHEST``; only the
+    order of summation differs.  Returns fp32 (m, 1).
+    """
+    m, k = matrix_shape(l1)
+    assert l2.shape == l1.shape and r1.shape == r2.shape == (1, k), (
+        l1.shape, l2.shape, r1.shape, r2.shape)
+    assert m % block_m == 0 and k % block_k == 0, ((m, k), (block_m, block_k))
+    lanes = _LANES if block_k % _LANES == 0 else block_k
+    grid = (m // block_m, k // block_k)
+    left = [_tile_spec(a, (block_m, block_k), lambda i, s: (i, s))
+            for a in (l1, l2)]
+    row = pl.BlockSpec((1, block_k), lambda i, s: (0, s))
+    vmem = _matvec_vmem_bytes(block_m, block_k, lanes, l1.dtype.itemsize)
+    return pl.pallas_call(
+        functools.partial(_ec_matvec_kernel,
+                          rows=math.gcd(block_m, _STRIP_ROWS)),
+        name="ec_matmul",
+        grid=grid,
+        in_specs=left + [row, row],
+        out_specs=pl.BlockSpec((block_m, 1), lambda i, s: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_m, lanes), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
     )(l1, l2, r1, r2)

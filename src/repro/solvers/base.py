@@ -99,6 +99,11 @@ class LinearOperator:
     # compile-time constants (a resident image is gigabytes).
     operands: Any = ()
     bind: Optional[Callable[[Any], "LinearOperator"]] = None
+    # For an engine-backed operator: the form of the tier-1 kernel its
+    # matvec (``tier1``) and rmatvec (``tier1_t``) run for a given column
+    # count (``AnalogEngine.tier1_form``; None where no kernel runs).
+    tier1: Optional[Callable[[int], Optional[str]]] = None
+    tier1_t: Optional[Callable[[int], Optional[str]]] = None
 
     @property
     def n(self) -> int:
@@ -122,6 +127,8 @@ class LinearOperator:
             analog=self.analog,
             operands=self.operands,
             bind=(lambda ops: self.bind(ops).T) if self.bind else None,
+            tier1=self.tier1_t,
+            tier1_t=self.tier1,
         )
 
 
@@ -136,11 +143,22 @@ def solver_core(core: Callable) -> Callable:
     return scoped
 
 
-def dispatched(core: Callable) -> Callable:
+def _panel_cols(args) -> int:
+    """Columns of the first (n, batch) panel among a core's arguments (its
+    right-hand side); a core that takes none iterates one vector."""
+    return next((a.shape[1] for a in args if getattr(a, "ndim", 0) == 2), 1)
+
+
+def dispatched(core: Callable, tier1: Optional[Callable] = None) -> Callable:
     """``core`` with each call inside the host span :data:`SPAN_DISPATCH`
-    (which records nothing unless the profiler is tracing)."""
+    (which records nothing unless the profiler is tracing).  Where the
+    operator runs a tier-1 kernel, ``tier1`` (:attr:`LinearOperator.tier1`)
+    gives the span's ``tier1`` argument from the right-hand side's columns:
+    the form its matvecs take."""
     def dispatch(*args):
-        with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+        form = tier1(_panel_cols(args)) if tier1 is not None else None
+        with jax.profiler.TraceAnnotation(
+                SPAN_DISPATCH, **({} if form is None else {"tier1": form})):
             return core(*args)
     return dispatch
 
@@ -155,9 +173,9 @@ def jit_core(op: LinearOperator, build: Callable[[LinearOperator], Callable]
     compiles.  Binding the operator to the jit's own arguments keeps a
     programmed image a single buffer however large it is."""
     if op.bind is None:
-        return dispatched(jax.jit(build(op)))
+        return dispatched(jax.jit(build(op)), op.tier1)
     core = jax.jit(lambda operands, *args: build(op.bind(operands))(*args))
-    return dispatched(functools.partial(core, op.operands))
+    return dispatched(functools.partial(core, op.operands), op.tier1)
 
 
 def _zero_stats(_batch: int = 1) -> WriteStats:
@@ -210,6 +228,8 @@ def as_operator(
             operands={f: getattr(A, f) for f in fields
                       if getattr(A, f) is not None},
             bind=lambda ops: as_operator(dataclasses.replace(A, **ops)),
+            tier1=eng.tier1_form,
+            tier1_t=functools.partial(eng.tier1_form, transpose=True),
         )
     if callable(A) and not hasattr(A, "shape"):
         if shape is None:

@@ -11,6 +11,7 @@ from repro.kernels import (
     rram_encode_matmul,
 )
 from repro.kernels import ref as kref
+from repro.kernels.ops import tier1_form
 
 KEY = jax.random.PRNGKey(42)
 
@@ -51,8 +52,19 @@ def test_encode_matmul_levels(levels):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=1e-4)
 
 
+def _block_stack(a, cap_m, cap_n):
+    """The (mb, nb, cap_m, cap_n) capacity-block stack of the matrix ``a``."""
+    m, k = a.shape
+    return a.reshape(m // cap_m, cap_m, k // cap_n, cap_n).transpose(0, 2, 1, 3)
+
+
 @pytest.mark.slow
-@pytest.mark.parametrize("m,k,n", [(8, 8, 8), (16, 40, 24), (32, 16, 48)])
+@pytest.mark.parametrize("m,k,n", [
+    (8, 8, 8), (16, 40, 24), (32, 16, 48),
+    (24, 256, 1),   # one column: the VPU form, K over two tiles
+    (20, 300, 1),   # the VPU form at padded m and k
+    (32, 512, 1),   # the VPU form, also reading a capacity-block stack
+])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ec_matmul_sweep(m, k, n, dtype):
     x = rand((m, k), dtype, 6)
@@ -60,11 +72,54 @@ def test_ec_matmul_sweep(m, k, n, dtype):
     w = rand((k, n), dtype, 8)
     wt = w * (1 + 0.05 * rand((k, n), dtype, 9))
     dw = (w - wt).astype(dtype)
-    got = rram_ec_matmul(x, xt, wt, dw, block_m=8, block_k=8, block_n=8)
+    block_k = 128 if n == 1 else 8
+    got = rram_ec_matmul(x, xt, wt, dw, block_m=8, block_k=block_k, block_n=8)
     want = kref.ec_matmul_ref(x, xt, wt, dw)
     tol = 5e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol * 10)
+    if n == 1 and m % 16 == 0 and k % 256 == 0:
+        got = rram_ec_matmul(_block_stack(x, 16, 256), _block_stack(xt, 16, 256),
+                             wt, dw, block_m=8, block_k=128)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=tol, atol=tol * 10)
+
+
+def _kernel_grids(fn, *args):
+    """The grid of every pallas_call ``fn`` traces to."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(eqn.params["grid_mapping"].grid)
+            for p in eqn.params.values():
+                inner = getattr(p, "jaxpr", p)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+@pytest.mark.parametrize("cols,transposed,form", [
+    (1, False, "vpu"), (1, True, "mxu"), (2, False, "mxu"),
+    (64, False, "mxu"), (64, True, "mxu")])
+def test_tier1_form(cols, transposed, form):
+    """The VPU form is taken for one column with the image on the left, and
+    the wrapper runs the form the predicate names: a 2-D (rows, K) grid for
+    the VPU form, a 3-D one for the MXU form."""
+    assert tier1_form(cols, transposed) == form
+    image = rand((32, 256), jnp.float32, 17)
+    if transposed:
+        y = rand((32, cols), jnp.float32, 18)
+        call = lambda a, v: rram_ec_matmul(v.T, v.T, a, a)
+    else:
+        y = rand((256, cols), jnp.float32, 18)
+        call = lambda a, v: rram_ec_matmul(a, a, v, v)
+    grids = _kernel_grids(call, image, y)
+    assert grids and all(len(g) == (2 if form == "vpu" else 3)
+                         for g in grids), grids
 
 
 def test_ec_matmul_unpadded_shapes():

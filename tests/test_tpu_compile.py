@@ -82,6 +82,20 @@ def test_ec_matmul_block_stack(one_chip, batch):
              (4 * CAP, batch), (4 * CAP, batch))
 
 
+def test_ec_matvec_resident_stack(one_chip):
+    """The single-column (VPU) form at the resident widths: batch 1 against
+    the (16, 16, 2,048, 2,048) block stack, at the shipped tiles, is one
+    ``%ec_matmul`` kernel with a 2-D (rows, K) grid."""
+    n = 16 * CAP
+    text = _compile(lambda a, d, x, xt: kops.rram_ec_matmul(a, d, x, xt,
+                                                            interpret=False),
+                    one_chip, (16, 16, CAP, CAP), (16, 16, CAP, CAP),
+                    (n, 1), (n, 1))
+    ops = _kernel_ops(text)
+    assert len(ops) == 1 and re.fullmatch(r"%ec_matmul(\.\d+)?", ops[0][0])
+    assert ops[0][1] == "meliso.tier1", ops
+
+
 def test_ec_group_step(one_chip):
     _compile(lambda x, xt, a, d: kops.rram_ec_group_mvm(x, xt, a, d,
                                                         interpret=False),
@@ -170,3 +184,5 @@ def test_resident_mvm_kernel_names_and_stages(one_chip, monkeypatch, batch):
     kinds = {re.sub(r"\.\d+$", "", name): scope for name, scope in ops}
     assert kinds == {"%ec_matmul": "meliso.tier1",
                      "%stencil_denoise": "meliso.tier2"}, ops
+    # One tier-1 kernel event per MVM, whichever form the batch takes.
+    assert len([scope for _, scope in ops if scope == "meliso.tier1"]) == 1, ops

@@ -48,6 +48,23 @@ def op_scopes(hlo_text: str):
     return out
 
 
+def tier1_products(hlo_text: str):
+    """(instruction, innermost meliso scope or "") of every product of the
+    tier-1 correction: each dot (the MXU form) and each multiply of the
+    ``ec_matmul`` kernel's body (the single-column VPU form, whose body the
+    CPU interprets into the program)."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = INSTRUCTION.match(line)
+        name = OP_NAME.search(line)
+        path = name.group(1) if name else ""
+        if m and (m.group(2) == "dot" or (m.group(2) == "multiply"
+                                          and "/ec_matmul/" in path)):
+            scopes = SCOPE.findall(path)
+            out.append((m.group(1), scopes[-1] if scopes else ""))
+    return out
+
+
 def compiled(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
@@ -103,12 +120,12 @@ def test_stage_scopes_in_compiled_hlo(case):
     """Each path's compiled program names its stages, and every product of
     the tier-1 correction sits under ``meliso.tier1``."""
     build, expected = CASES[case]
-    ops = op_scopes(build())
-    found = {scope for _, _, scope in ops if scope}
+    text = build()
+    found = {scope for _, _, scope in op_scopes(text) if scope}
     assert expected <= found, f"missing {expected - found}; found {found}"
-    dots = [(name, scope) for name, opcode, scope in ops if opcode == "dot"]
-    assert dots, "no dot in the compiled program"
-    assert all(scope == "meliso.tier1" for _, scope in dots), dots
+    products = tier1_products(text)
+    assert products, "no tier-1 product in the compiled program"
+    assert all(scope == "meliso.tier1" for _, scope in products), products
 
 
 def test_psum_scope_on_a_2x2_mesh():
@@ -165,18 +182,47 @@ def test_dispatch_spans_in_a_profile(tmp_path):
         op, tol=1e-4, maxiter=4, backend="pallas"))
     jax.block_until_ready(engine.mvm(handle, x, key=key))
     jax.block_until_ready(core(x, jnp.zeros_like(x), key))
+    jax.block_until_ready(engine.mvm(handle, jnp.ones((N, 3)), key=key))
+    jax.block_until_ready(engine.rmvm(handle, x, key=key))
     jax.profiler.start_trace(str(tmp_path))
     try:
         jax.block_until_ready(engine.mvm(handle, x, key=key))
         jax.block_until_ready(engine.rmvm(handle, jnp.ones((N, 3)), key=key))
+        jax.block_until_ready(engine.mvm(handle, jnp.ones((N, 3)), key=key))
+        jax.block_until_ready(engine.rmvm(handle, x, key=key))
         jax.block_until_ready(core(x, jnp.zeros_like(x), key))
     finally:
         jax.profiler.stop_trace()
     events = _host_events(str(tmp_path))
     executes = [stats for name, stats in events if name == SPAN_EXECUTE]
-    assert [(s["path"], s["direction"], s["cols"]) for s in executes] == [
-        ("local/pallas", "forward", 1), ("local/pallas", "transposed", 3)]
-    assert [name for name, _ in events].count(SPAN_DISPATCH) == 1
+    assert [(s["path"], s["direction"], s["cols"], s["tier1"])
+            for s in executes] == [
+        ("local/pallas", "forward", 1, "vpu"),
+        ("local/pallas", "transposed", 3, "mxu"),
+        ("local/pallas", "forward", 3, "mxu"),
+        ("local/pallas", "transposed", 1, "mxu")]
+    dispatches = [stats for name, stats in events if name == SPAN_DISPATCH]
+    assert [s["tier1"] for s in dispatches] == ["vpu"]
+
+
+def test_no_tier1_argument_without_a_kernel(tmp_path):
+    """The reference backend runs no tier-1 kernel: its spans carry no
+    ``tier1`` argument."""
+    a, x, cfg, key = _system()
+    handle = AnalogEngine(cfg).program(a, key)
+    core = jit_core(as_operator(handle), lambda op: cg_pipeline(
+        op, tol=1e-4, maxiter=4))
+    jax.block_until_ready(handle.engine.mvm(handle, x, key=key))
+    jax.block_until_ready(core(x, jnp.zeros_like(x), key))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(handle.engine.mvm(handle, x, key=key))
+        jax.block_until_ready(core(x, jnp.zeros_like(x), key))
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    assert sorted(name for name, _ in events) == [SPAN_EXECUTE, SPAN_DISPATCH]
+    assert all("tier1" not in stats for _, stats in events), events
 
 
 
