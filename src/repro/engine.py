@@ -167,8 +167,9 @@ BACKENDS = ("reference", "pallas")
 
 #: The host span (``jax.profiler.TraceAnnotation``) around each solo or group
 #: execute, from entry to the return of the jitted call; its arguments are
-#: ``path`` (``"<execution>/<backend>"``), ``direction``, ``cols`` and, where
-#: a tier-1 kernel runs, ``tier1`` (``"vpu"`` or ``"mxu"``,
+#: ``path`` (``"<execution>/<backend>"``), ``direction``, ``cols``, on a
+#: distributed engine ``mesh`` (:attr:`AnalogEngine.mesh_label`, ``"2x2"``)
+#: and, where a tier-1 kernel runs, ``tier1`` (``"vpu"`` or ``"mxu"``,
 #: :func:`repro.kernels.ops.tier1_form`).  It records nothing unless the
 #: profiler is tracing.
 SPAN_EXECUTE = "meliso.engine.execute"
@@ -1301,10 +1302,13 @@ class AnalogEngine:
         return kops.tier1_form(cols, transposed=transpose)
 
     def _span_cols(self, span, cols: int, transpose: bool) -> None:
-        """Record ``cols`` and the tier-1 form on the execute span."""
+        """Record ``cols``, the tier-1 form and the mesh on the execute
+        span."""
         form = self.tier1_form(cols, transpose)
-        span.set_metadata(cols=cols, **({} if form is None
-                                         else {"tier1": form}))
+        mesh = self.mesh_label
+        span.set_metadata(cols=cols,
+                          **({} if form is None else {"tier1": form}),
+                          **({} if mesh is None else {"mesh": mesh}))
 
     def _execute_span(self, transpose: bool):
         """The host span :data:`SPAN_EXECUTE` of one execute."""
@@ -1422,6 +1426,14 @@ class AnalogEngine:
         (:meth:`chain_mvm`)."""
         return lambda x, key: self.chain_mvm(G, x, key=key,
                                              activation=activation)
+
+    @property
+    def mesh_label(self) -> Optional[str]:
+        """The mesh a distributed execution spans, as its shape (``"2x2"``);
+        None for single-device modes."""
+        if self.execution != "distributed":
+            return None
+        return "x".join(str(s) for s in self.mesh.devices.shape)
 
     @property
     def collective_axes(self) -> Tuple[str, ...]:

@@ -46,7 +46,8 @@ __all__ = [
 
 _TINY = 1e-30
 
-#: The host span around each call of a jitted solver core.
+#: The host span around each call of a jitted solver core; its arguments
+#: ``tier1`` and ``mesh`` are those of :func:`dispatched`.
 SPAN_DISPATCH = "meliso.solver.dispatch"
 
 
@@ -104,6 +105,9 @@ class LinearOperator:
     # count (``AnalogEngine.tier1_form``; None where no kernel runs).
     tier1: Optional[Callable[[int], Optional[str]]] = None
     tier1_t: Optional[Callable[[int], Optional[str]]] = None
+    # The device mesh the matvecs span (``AnalogEngine.mesh_label``, "2x2"),
+    # None on one device.
+    mesh: Optional[str] = None
 
     @property
     def n(self) -> int:
@@ -129,6 +133,7 @@ class LinearOperator:
             bind=(lambda ops: self.bind(ops).T) if self.bind else None,
             tier1=self.tier1_t,
             tier1_t=self.tier1,
+            mesh=self.mesh,
         )
 
 
@@ -149,16 +154,20 @@ def _panel_cols(args) -> int:
     return next((a.shape[1] for a in args if getattr(a, "ndim", 0) == 2), 1)
 
 
-def dispatched(core: Callable, tier1: Optional[Callable] = None) -> Callable:
+def dispatched(core: Callable, op: LinearOperator) -> Callable:
     """``core`` with each call inside the host span :data:`SPAN_DISPATCH`
     (which records nothing unless the profiler is tracing).  Where the
-    operator runs a tier-1 kernel, ``tier1`` (:attr:`LinearOperator.tier1`)
-    gives the span's ``tier1`` argument from the right-hand side's columns:
-    the form its matvecs take."""
+    operator ``op`` runs a tier-1 kernel, :attr:`LinearOperator.tier1` gives
+    the span's ``tier1`` argument from the right-hand side's columns: the
+    form its matvecs take; where its matvecs span a mesh, the span's
+    ``mesh`` argument is :attr:`LinearOperator.mesh`."""
+    mesh = {} if op.mesh is None else {"mesh": op.mesh}
+
     def dispatch(*args):
-        form = tier1(_panel_cols(args)) if tier1 is not None else None
+        form = op.tier1(_panel_cols(args)) if op.tier1 is not None else None
         with jax.profiler.TraceAnnotation(
-                SPAN_DISPATCH, **({} if form is None else {"tier1": form})):
+                SPAN_DISPATCH, **mesh,
+                **({} if form is None else {"tier1": form})):
             return core(*args)
     return dispatch
 
@@ -173,9 +182,9 @@ def jit_core(op: LinearOperator, build: Callable[[LinearOperator], Callable]
     compiles.  Binding the operator to the jit's own arguments keeps a
     programmed image a single buffer however large it is."""
     if op.bind is None:
-        return dispatched(jax.jit(build(op)), op.tier1)
+        return dispatched(jax.jit(build(op)), op)
     core = jax.jit(lambda operands, *args: build(op.bind(operands))(*args))
-    return dispatched(functools.partial(core, op.operands), op.tier1)
+    return dispatched(functools.partial(core, op.operands), op)
 
 
 def _zero_stats(_batch: int = 1) -> WriteStats:
@@ -230,6 +239,7 @@ def as_operator(
             bind=lambda ops: as_operator(dataclasses.replace(A, **ops)),
             tier1=eng.tier1_form,
             tier1_t=functools.partial(eng.tier1_form, transpose=True),
+            mesh=eng.mesh_label,
         )
     if callable(A) and not hasattr(A, "shape"):
         if shape is None:

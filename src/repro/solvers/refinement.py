@@ -120,6 +120,6 @@ def refine(
         k, x, _r, _rel, hist, mvms = jax.lax.while_loop(cond, body, state0)
         return x, hist, k, mvms, rel0
 
-    x, hist, k, mvms, rel0 = dispatched(jax.jit(core), op.tier1)(bb, x0b, key)
+    x, hist, k, mvms, rel0 = dispatched(jax.jit(core), op)(bb, x0b, key)
     return pack_result(op, f"refine[{inner}]", x, hist, k, mvms, tol, squeeze,
                        mvms_single=mvms_single, rel0=rel0)

@@ -331,6 +331,53 @@ def test_distributed_producer_solve():
     assert res["E"] > 0
 
 
+def test_paper_cg_2x2_matches_1x1():
+    """The paper-cg-2x2 deployment at a small n: the resident=False
+    distributed CG on a 2x2 mesh, at the cell's EC widths (epiram, 64
+    levels, k_iters 5, fused two-tier EC, Neumann tier-2 at lam 1e-12),
+    against the same engine on a 1x1 mesh: the global key schedule makes
+    the MVM the same, the CG answer agrees within float32 rounding, and the
+    iteration count is the same.  The 2x2 answer stays sharded."""
+    res = run_child(PRELUDE + textwrap.dedent("""
+        from repro.core import CrossbarConfig, MCAGeometry, get_device, rel_l2
+        from repro.core.matrices import ImplicitBandedMatrix
+        from repro.engine import AnalogEngine
+        from repro.solvers.base import as_operator, jit_core
+        from repro.solvers.krylov import cg_pipeline
+        n, cap = 1024, 256                  # a 4x4 grid of 2x2 MCAs of 128^2
+        cfg = CrossbarConfig(device=get_device("epiram"),
+                             geom=MCAGeometry(2, 2, 128, 128), k_iters=5,
+                             ec=True, ec_mode="fused",
+                             denoise_method="neumann", lam=1e-12, h=-1.0)
+        producer = ImplicitBandedMatrix(n=n, cap_m=cap, cap_n=cap,
+                                        seed=n).block
+        key = jax.random.PRNGKey(7)
+        b = jax.random.normal(jax.random.PRNGKey(8), (n, 1))
+        out = {}
+        for name, shape in (("2x2", (2, 2)), ("1x1", (1, 1))):
+            m = make_mesh(shape, ("data", "model"),
+                          devices=jax.devices()[:shape[0] * shape[1]])
+            eng = AnalogEngine(cfg, execution="distributed", mesh=m)
+            A = eng.program(producer, key, shape=(n, n), resident=False)
+            y = eng.mvm(A, b, key=jax.random.PRNGKey(9))
+            core = jit_core(as_operator(A), lambda op: cg_pipeline(
+                op, tol=5e-3, maxiter=12))
+            x, _h, k, mvms, _r = core(b, jnp.zeros_like(b),
+                                      jax.random.PRNGKey(10))
+            out[name] = (np.asarray(y), np.asarray(x), int(k), x.sharding)
+        (y4, x4, k4, sh4), (y1, x1, k1, _) = out["2x2"], out["1x1"]
+        print(json.dumps({
+            "mvm_gap": float(np.max(np.abs(y4 - y1))),
+            "x_gap": float(np.max(np.abs(x4 - x1)) / np.max(np.abs(x1))),
+            "iters": [k4, k1], "x_devices": len(sh4.device_set),
+            "x_replicated": bool(sh4.is_fully_replicated)}))
+    """))
+    assert res["mvm_gap"] == 0.0, res
+    assert res["x_gap"] <= 1e-6, res
+    assert res["iters"][0] == res["iters"][1] >= 2, res
+    assert res["x_devices"] == 4 and not res["x_replicated"], res
+
+
 @pytest.mark.slow
 def test_distributed_scale_65536():
     """The acceptance-scale case: n=65,536 >= the paper's largest problem,

@@ -33,15 +33,28 @@ def topo():
 
 
 @pytest.fixture(scope="module")
-def one_chip(topo):
+def no_compile_cache():
     from jax.experimental.compilation_cache import compilation_cache
     enabled = jax.config.jax_enable_compilation_cache
     # A compile for a described chip is written to the persistent cache but
     # can never be read back without the chip: keep the cache out of it.
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_compile_cache):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo, no_compile_cache):
+    """A 2x2 ("data", "model") mesh over the four described chips."""
+    import numpy as np
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("data", "model"))
 
 
 def _compile(fn, sharding, *shapes, dtype=jnp.float32):
@@ -186,3 +199,38 @@ def test_resident_mvm_kernel_names_and_stages(one_chip, monkeypatch, batch):
                      "%stencil_denoise": "meliso.tier2"}, ops
     # One tier-1 kernel event per MVM, whichever form the batch takes.
     assert len([scope for _, scope in ops if scope == "meliso.tier1"]) == 1, ops
+
+
+def test_paper_cg_2x2_mvm_on_four_chips(four_chips):
+    """The paper-cg-2x2 cell's MVM compiled for the four chips of the mesh:
+    the 65,536^2 resident=False corrected MVM at the cell's widths, each
+    chip on its 16x16 window of 2,048^2 blocks.  One all-reduce, named
+    ``meliso.psum``, joins each chip's (32,768, 1) tier-1 partials, and no
+    chip holds any of the image: its temporaries are a few blocks' worth."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import CrossbarConfig, MCAGeometry, get_device
+    from repro.core.matrices import ImplicitBandedMatrix
+    from repro.engine import AnalogEngine
+    cfg = CrossbarConfig(device=get_device("epiram"),
+                         geom=MCAGeometry(4, 4, 512, 512), k_iters=5, ec=True,
+                         ec_mode="fused", denoise_method="neumann", lam=1e-12,
+                         h=-1.0)
+    producer = ImplicitBandedMatrix(n=N_SOLVE, cap_m=CAP, cap_n=CAP,
+                                    seed=N_SOLVE).block
+    engine = AnalogEngine(cfg, execution="distributed", mesh=four_chips)
+    handle = engine.program(producer, jax.random.PRNGKey(0),
+                            shape=(N_SOLVE, N_SOLVE), resident=False)
+    replicated = NamedSharding(four_chips, P())
+    x = jax.ShapeDtypeStruct((N_SOLVE, 1), jnp.float32, sharding=replicated)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)
+    compiled = jax.jit(engine.mvm_fn(handle)).lower(x, key).compile()
+    reduces = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (f32\[[\d,]+\]).* all-reduce\(",
+                     line)
+        if m:
+            path = re.search(r'op_name="([^"]*)"', line)
+            reduces.append((m.group(1), re.findall(r"meliso\.\w+",
+                                                   path.group(1))[-1:]))
+    assert reduces == [("f32[32768,1]", ["meliso.psum"])], reduces
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * CAP * CAP * 4

@@ -226,6 +226,53 @@ def test_no_tier1_argument_without_a_kernel(tmp_path):
 
 
 
+def test_mesh_argument_on_distributed_spans(tmp_path):
+    """Both host spans of a distributed engine carry its mesh as ``mesh``
+    (``"2x2"``), so a trace shows which dispatches crossed chips; a local
+    engine's spans carry none (4 forced host devices, in a child so this
+    process keeps one device)."""
+    code = textwrap.dedent(f"""
+        import json, sys
+        sys.path.insert(0, "tests")
+        import jax, jax.numpy as jnp
+        from conftest import analog_cfg, spd_system
+        from test_tracing import _host_events
+        from repro.engine import AnalogEngine
+        from repro.launch.mesh import make_mesh
+        from repro.solvers.base import as_operator, jit_core
+        from repro.solvers.krylov import cg_pipeline
+        a, _, b = spd_system(128)
+        x, key = b[:, None], jax.random.PRNGKey(3)
+        mesh = make_mesh((2, 2), ("data", "model"))
+        calls = []
+        for engine in (AnalogEngine(analog_cfg(128), execution="distributed",
+                                    mesh=mesh),
+                       AnalogEngine(analog_cfg(128))):
+            handle = engine.program(a, key)
+            core = jit_core(as_operator(handle), lambda op: cg_pipeline(
+                op, tol=1e-4, maxiter=4))
+            calls += [lambda e=engine, h=handle: e.mvm(h, x, key=key),
+                      lambda c=core: c(x, jnp.zeros_like(x), key)]
+        for call in calls:
+            jax.block_until_ready(call())
+        jax.profiler.start_trace({str(tmp_path)!r})
+        for call in calls:
+            jax.block_until_ready(call())
+        jax.profiler.stop_trace()
+        print(json.dumps([[name, stats.get("mesh")] for name, stats
+                          in _host_events({str(tmp_path)!r})]))
+    """)
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(REPO, "src"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, cwd=REPO, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    events = json.loads(out.stdout.splitlines()[-1])
+    assert events == [[SPAN_EXECUTE, "2x2"], [SPAN_DISPATCH, "2x2"],
+                      [SPAN_EXECUTE, None], [SPAN_DISPATCH, None]], events
+
+
 def test_cached_executable_keeps_its_own_stage_names(tmp_path, monkeypatch):
     """With the persistent compilation cache on, two programs that differ
     only in their stage names do not share a cached executable, so the
