@@ -169,9 +169,9 @@ BACKENDS = ("reference", "pallas")
 #: execute, from entry to the return of the jitted call; its arguments are
 #: ``path`` (``"<execution>/<backend>"``), ``direction``, ``cols``, on a
 #: distributed engine ``mesh`` (:attr:`AnalogEngine.mesh_label`, ``"2x2"``)
-#: and, where a tier-1 kernel runs, ``tier1`` (``"vpu"`` or ``"mxu"``,
-#: :func:`repro.kernels.ops.tier1_form`).  It records nothing unless the
-#: profiler is tracing.
+#: and, where a tier-1 kernel runs, ``tier1`` (``"vpu"``, ``"mxu_packed"``
+#: or ``"mxu"``, :func:`repro.kernels.ops.tier1_form`).  It records nothing
+#: unless the profiler is tracing.
 SPAN_EXECUTE = "meliso.engine.execute"
 _DIRECTION = {False: "forward", True: "transposed"}
 
@@ -611,7 +611,8 @@ def _pallas_corrected(at, da, xb, key, cfg, m, n, transpose):
     with jax.named_scope("meliso.tier1"):
         if cfg.ec:
             if transpose:
-                p = kops.rram_ec_matmul(x_pad.T, x_t.T, at, da).T[:n]
+                p = kops.rram_ec_matmul(x_pad.T, x_t.T, at, da,
+                                        transposed=True).T[:n]
             else:
                 p = kops.rram_ec_matmul(at, da, x_pad, x_t)[:m]
         else:

@@ -15,7 +15,10 @@ import jax.numpy as jnp
 
 from .rram_mvm import DEFAULT_BLOCK_K, DEFAULT_BLOCK_M, DEFAULT_BLOCK_N
 from .rram_mvm import DEFAULT_MATVEC_BLOCK_K, DEFAULT_MATVEC_BLOCK_M
+from .rram_mvm import DEFAULT_PACKED_BLOCK_K, DEFAULT_PACKED_BLOCK_M
+from .rram_mvm import PACKED_MAX_COLS
 from .rram_mvm import ec_matmul as _ec_matmul
+from .rram_mvm import ec_matmul_packed as _ec_matmul_packed
 from .rram_mvm import ec_matvec as _ec_matvec
 from .rram_mvm import encode_matmul as _encode_matmul
 from .rram_mvm import matrix_shape
@@ -71,11 +74,15 @@ def _round_up(x: int, mult: int) -> int:
 
 def tier1_form(cols: int, transposed: bool = False) -> str:
     """The tier-1 form :func:`rram_ec_matmul` takes for a corrected MVM of
-    ``cols`` input columns: ``"vpu"`` (the single-column VPU form) where one
-    column meets the image on the left -- a forward MVM at batch 1 --, else
-    ``"mxu"``.  A transposed MVM puts the input on the left and the image on
-    the right, whose columns are the image's, so it keeps the MXU form."""
-    return "vpu" if cols == 1 and not transposed else "mxu"
+    ``cols`` input columns with the image on the left (a forward MVM):
+    ``"vpu"`` (the VPU form) for one column, ``"mxu_packed"`` (the input
+    split once and packed two parts to a 128-lane panel) for 2-64 columns,
+    ``"mxu"`` (two f32 ``HIGHEST`` MXU dots per block) for more.  A
+    transposed MVM puts the input on the left and the image on the right,
+    whose columns are the image's, so it keeps the MXU form."""
+    if transposed or cols > PACKED_MAX_COLS:
+        return "mxu"
+    return "vpu" if cols == 1 else "mxu_packed"
 
 
 def rram_encode_matmul(
@@ -113,26 +120,36 @@ def rram_ec_matmul(
     block_m: int | None = None,
     block_k: int | None = None,
     block_n: int | None = None,
+    transposed: bool = False,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Fused tier-1 EC matmul p = l1 @ r1 + l2 @ r2.
 
     Either operand pair may be a (mb, nb, cap_m, cap_n) capacity-block stack
     (read in place, tiles chosen to divide the capacity block); 2-D operands
-    are padded to the tile grid and the result is un-padded.  The form
-    follows the right-hand pair's column count (:func:`tier1_form`): one
-    column runs the VPU form (``ec_matvec``, tiles ``block_m`` x ``block_k``,
-    default ``DEFAULT_MATVEC_BLOCK_*``, ``block_n`` unused), any other the
+    are padded to the tile grid and the result is un-padded.  ``transposed``
+    says the image pair is on the right (the transposed MVM).  The form
+    follows the right-hand pair's column count and the direction
+    (:func:`tier1_form`): one column runs the VPU form (``ec_matvec``, tiles
+    ``block_m`` x ``block_k``, default ``DEFAULT_MATVEC_BLOCK_*``,
+    ``block_n`` unused), 2-64 the packed MXU form (``ec_matmul_packed``,
+    default ``DEFAULT_PACKED_BLOCK_*``, ``block_n`` unused), any other the
     MXU form (``ec_matmul``, default ``DEFAULT_BLOCK_*``).
     """
     m, k = matrix_shape(l1)
     _, n = matrix_shape(r1)
     interpret = on_cpu() if interpret is None else interpret
+    form = tier1_form(n, transposed)
     with jax.named_scope("meliso.tier1"):
-        if tier1_form(n) == "vpu":
+        if form == "vpu":
             out = _tier1_vpu(l1, l2, r1, r2, m, k,
                              block_m or DEFAULT_MATVEC_BLOCK_M,
                              block_k or DEFAULT_MATVEC_BLOCK_K, interpret)
+            return out[:m]
+        if form == "mxu_packed":
+            out = _tier1_packed(l1, l2, r1, r2, m, k,
+                                block_m or DEFAULT_PACKED_BLOCK_M,
+                                block_k or DEFAULT_PACKED_BLOCK_K, interpret)
             return out[:m]
         bm, bk, bn = _pick_blocks(m, k, n, block_m or DEFAULT_BLOCK_M,
                                   block_k or DEFAULT_BLOCK_K,
@@ -149,21 +166,37 @@ def rram_ec_matmul(
         return out[:m, :n]
 
 
+def _image_tiles(l1, l2, m, k, bm, bk):
+    """The image pair as the VPU and packed forms read it, with their
+    (bm, bk) tile: a capacity-block stack in place, tiles fit to its block;
+    a 2-D pair padded to tiles no larger than it (rounded up to (8, 128))."""
+    if l1.ndim == 4:
+        return [l1, l2], _fit(bm, l1.shape[2]), _fit(bk, l1.shape[3])
+    bm = min(bm, _round_up(m, 8))
+    bk = min(bk, _round_up(k, 128))
+    return [_pad_to(a, (bm, bk)) for a in (l1, l2)], bm, bk
+
+
 def _tier1_vpu(l1, l2, r1, r2, m, k, bm, bk, interpret):
     """The VPU form of :func:`rram_ec_matmul` for a one-column right pair:
     the column becomes a lane-major (1, k) row, tiles fit the capacity block
     or the (padded) matrix.  Returns (m_padded, 1)."""
-    if l1.ndim == 4:
-        bm, bk = _fit(bm, l1.shape[2]), _fit(bk, l1.shape[3])
-        left = [l1, l2]
-    else:
-        bm = min(bm, _round_up(m, 8))
-        bk = min(bk, _round_up(k, 128))
-        left = [_pad_to(a, (bm, bk)) for a in (l1, l2)]
+    left, bm, bk = _image_tiles(l1, l2, m, k, bm, bk)
     kp = matrix_shape(left[0])[1]
     rows = [_pad_to(r.reshape(1, k), (1, kp)) for r in (r1, r2)]
     return _ec_matvec(*left, *rows, block_m=bm, block_k=bk,
                       interpret=interpret)
+
+
+def _tier1_packed(l1, l2, r1, r2, m, k, bm, bk, interpret):
+    """The packed MXU form of :func:`rram_ec_matmul` for a 2-64-column right
+    pair: tiles fit the capacity block or the (padded) matrix, the input is
+    padded to the image's K.  Returns (m_padded, cols)."""
+    left, bm, bk = _image_tiles(l1, l2, m, k, bm, bk)
+    kp = matrix_shape(left[0])[1]
+    right = [_pad_to(r, (kp, 1)) for r in (r1, r2)]
+    return _ec_matmul_packed(*left, *right, block_m=bm, block_k=bk,
+                             interpret=interpret)
 
 
 def rram_ec_tile_mvm(
@@ -205,7 +238,7 @@ def rram_ec_tile_rmvm(
     """
     with jax.named_scope("meliso.tier1"):
         return rram_ec_matmul(y_blk.T, y_t.T, at_blk, da_blk,
-                              interpret=interpret).T
+                              transposed=True, interpret=interpret).T
 
 
 def rram_ec_group_mvm(

@@ -10,8 +10,10 @@ from repro.kernels import (
     rram_ec_matmul,
     rram_encode_matmul,
 )
+from repro.core import crossbar
 from repro.kernels import ref as kref
 from repro.kernels.ops import tier1_form
+from repro.kernels.rram_mvm import split_bf16
 
 KEY = jax.random.PRNGKey(42)
 
@@ -86,13 +88,14 @@ def test_ec_matmul_sweep(m, k, n, dtype):
 
 
 def _kernel_grids(fn, *args):
-    """The grid of every pallas_call ``fn`` traces to."""
+    """(grid, operand count) of every pallas_call ``fn`` traces to."""
     grids = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                grids.append(eqn.params["grid_mapping"].grid)
+                grids.append((eqn.params["grid_mapping"].grid,
+                              len(eqn.invars)))
             for p in eqn.params.values():
                 inner = getattr(p, "jaxpr", p)
                 if hasattr(inner, "eqns"):
@@ -102,24 +105,100 @@ def _kernel_grids(fn, *args):
     return grids
 
 
+# Each form's pallas_call: (grid rank, operands).  The packed form reads the
+# image pair and four input panels.
+_FORM_CALL = {"vpu": (2, 4), "mxu_packed": (2, 6), "mxu": (3, 4)}
+
+
 @pytest.mark.parametrize("cols,transposed,form", [
-    (1, False, "vpu"), (1, True, "mxu"), (2, False, "mxu"),
-    (64, False, "mxu"), (64, True, "mxu")])
+    (1, False, "vpu"), (1, True, "mxu"), (2, False, "mxu_packed"),
+    (64, False, "mxu_packed"), (64, True, "mxu"), (65, False, "mxu"),
+    (128, False, "mxu")])
 def test_tier1_form(cols, transposed, form):
-    """The VPU form is taken for one column with the image on the left, and
-    the wrapper runs the form the predicate names: a 2-D (rows, K) grid for
-    the VPU form, a 3-D one for the MXU form."""
+    """The VPU form is taken for one column with the image on the left, the
+    packed MXU form for 2-64, the MXU form beyond and for every transposed
+    MVM, and the wrapper runs the form the predicate names: a 2-D (rows, K)
+    grid for the VPU and packed forms, a 3-D one for the MXU form."""
     assert tier1_form(cols, transposed) == form
     image = rand((32, 256), jnp.float32, 17)
     if transposed:
         y = rand((32, cols), jnp.float32, 18)
-        call = lambda a, v: rram_ec_matmul(v.T, v.T, a, a)
+        call = lambda a, v: rram_ec_matmul(v.T, v.T, a, a, transposed=True)
     else:
         y = rand((256, cols), jnp.float32, 18)
         call = lambda a, v: rram_ec_matmul(a, a, v, v)
     grids = _kernel_grids(call, image, y)
-    assert grids and all(len(g) == (2 if form == "vpu" else 3)
-                         for g in grids), grids
+    assert grids and all((len(g), ops) == _FORM_CALL[form]
+                         for g, ops in grids), grids
+
+
+def _gap(got, want):
+    """Largest entry gap over the largest entry of ``want`` (float64)."""
+    want = np.asarray(want, np.float64)
+    return np.abs(np.asarray(got, np.float64) - want).max() / np.abs(want).max()
+
+
+def _image_and_input(m, k, cols, seed):
+    """An image pair (A_tilde, dA ~1e-3 of it) and an input pair (x and its
+    DAC image ~1% off), f32."""
+    a = rand((m, k), jnp.float32, seed)
+    d = 1e-3 * rand((m, k), jnp.float32, seed + 1)
+    x = rand((k, cols), jnp.float32, seed + 2)
+    xt = x * (1 + 0.01 * rand((k, cols), jnp.float32, seed + 3))
+    return a, d, x, xt
+
+
+@pytest.mark.parametrize("cols", [2, 33, 64])
+@pytest.mark.parametrize("layout", ["2d", "stack", "padded"])
+def test_ec_matmul_packed(cols, layout):
+    """The packed form equals the f32 product crossbar.matmul forms at
+    HIGHEST within f32 rounding, on a 2-D image, a capacity-block stack
+    read in place (two row bands, two K steps each) and a 2-D image padded
+    to its tiles."""
+    m, k = (40, 300) if layout == "padded" else (64, 512)
+    a, d, x, xt = _image_and_input(m, k, cols, 20)
+    want = crossbar.matmul(a, x) + crossbar.matmul(d, xt)
+    if layout == "stack":
+        a, d = _block_stack(a, 32, 256), _block_stack(d, 32, 256)
+    got = rram_ec_matmul(a, d, x, xt, block_m=32, block_k=128)
+    assert got.shape == (m, cols)
+    assert _gap(got, want) < 2e-6
+
+
+def test_split_bf16_exact():
+    """The three bf16 parts of f32 values over 50 decades, both signs and
+    zero, sum to the value exactly, each part at most half a bf16 unit of
+    the one before."""
+    rng = np.random.default_rng(3)
+    a = rng.standard_normal(8192) * 10.0 ** rng.uniform(-25, 25, 8192)
+    a = jnp.asarray(np.append(a, [0.0, -0.0, 1.0, -3.0]), jnp.float32)
+    parts = split_bf16(a)
+    assert all(p.dtype == jnp.bfloat16 for p in parts)
+    a1, a2, a3 = (p.astype(jnp.float32) for p in parts)
+    np.testing.assert_array_equal(np.asarray(a1 + a2 + a3), np.asarray(a))
+    assert bool(jnp.all(jnp.abs(a2) <= jnp.abs(a1) * 2.0 ** -8))
+    assert bool(jnp.all(jnp.abs(a3) <= jnp.abs(a2) * 2.0 ** -8))
+
+
+def test_ec_matmul_packed_precision():
+    """The packed form keeps all six bf16 products of HIGHEST: its gap to
+    the float64 product is at the f32 product's level and far under that of
+    HIGH's three (a1x1 + a1x2 + a2x1), so dropping any one of the six
+    fails."""
+    a, d, x, xt = _image_and_input(64, 2048, 64, 30)
+    f64 = lambda t: np.asarray(t, np.float64)
+    exact = f64(a) @ f64(x) + f64(d) @ f64(xt)
+
+    def high(l, r):
+        l1, l2, _ = (f64(p.astype(jnp.float32)) for p in split_bf16(l))
+        r1, r2, _ = (f64(p.astype(jnp.float32)) for p in split_bf16(r))
+        return l1 @ r1 + l1 @ r2 + l2 @ r1
+
+    packed = _gap(rram_ec_matmul(a, d, x, xt, block_m=32, block_k=512),
+                  exact)
+    f32 = _gap(crossbar.matmul(a, x) + crossbar.matmul(d, xt), exact)
+    assert packed <= f32, (packed, f32)
+    assert _gap(high(a, x) + high(d, xt), exact) > 10 * packed
 
 
 def test_ec_matmul_unpadded_shapes():

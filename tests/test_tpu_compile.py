@@ -86,7 +86,7 @@ def test_ec_tile_step(one_chip, batch, direction):
              one_chip, (CAP, batch), (CAP, batch), (CAP, CAP), (CAP, CAP))
 
 
-@pytest.mark.parametrize("batch", [1, 64])
+@pytest.mark.parametrize("batch", [1, 8, 64, 128])
 def test_ec_matmul_block_stack(one_chip, batch):
     """The resident pallas MVM reads the (mb, nb, cap, cap) stack in place."""
     _compile(lambda a, d, x, xt: kops.rram_ec_matmul(a, d, x, xt,
@@ -104,6 +104,21 @@ def test_ec_matvec_resident_stack(one_chip):
                                                             interpret=False),
                     one_chip, (16, 16, CAP, CAP), (16, 16, CAP, CAP),
                     (n, 1), (n, 1))
+    ops = _kernel_ops(text)
+    assert len(ops) == 1 and re.fullmatch(r"%ec_matmul(\.\d+)?", ops[0][0])
+    assert ops[0][1] == "meliso.tier1", ops
+
+
+def test_ec_matmul_packed_resident_stack(one_chip):
+    """The packed MXU form at resident-mvm-b64's widths: 64 columns against
+    the (16, 16, 2,048, 2,048) f32 block stack, at the shipped tiles, is one
+    ``%ec_matmul`` kernel with a 2-D (rows, K) grid, its VMEM within the
+    limit it asks for."""
+    n = 16 * CAP
+    text = _compile(lambda a, d, x, xt: kops.rram_ec_matmul(a, d, x, xt,
+                                                            interpret=False),
+                    one_chip, (16, 16, CAP, CAP), (16, 16, CAP, CAP),
+                    (n, 64), (n, 64))
     ops = _kernel_ops(text)
     assert len(ops) == 1 and re.fullmatch(r"%ec_matmul(\.\d+)?", ops[0][0])
     assert ops[0][1] == "meliso.tier1", ops

@@ -199,7 +199,7 @@ def test_dispatch_spans_in_a_profile(tmp_path):
             for s in executes] == [
         ("local/pallas", "forward", 1, "vpu"),
         ("local/pallas", "transposed", 3, "mxu"),
-        ("local/pallas", "forward", 3, "mxu"),
+        ("local/pallas", "forward", 3, "mxu_packed"),
         ("local/pallas", "transposed", 1, "mxu")]
     dispatches = [stats for name, stats in events if name == SPAN_DISPATCH]
     assert [s["tier1"] for s in dispatches] == ["vpu"]
