@@ -11,8 +11,6 @@ from .write_verify import (
 )
 from .error_correction import (
     build_l_matrix,
-    corrected_matmul,
-    corrected_matvecmul,
     denoise_least_square,
     first_order_correct,
     tridiag_coeffs,
@@ -20,9 +18,7 @@ from .error_correction import (
 from .virtualization import (
     MCAGeometry,
     block_partition,
-    generate_mat_chunks,
     generate_vec_chunks,
-    reassemble,
     reassignment_count,
     zero_padding,
 )
@@ -35,15 +31,12 @@ from .crossbar import (
     matrix_write_cost,
     local_block_keys,
     local_dense_mvm,
-    local_dense_rmvm,
     local_program_dense,
     produce_blocks,
     producer_is_traceable,
     program_blocks,
     programmed_block_mvm,
-    programmed_block_rmvm,
     streamed_block_mvm,
-    streamed_block_rmvm,
     streamed_corrected_mvm,
     streamed_program_blocks,
     write_cost,
@@ -52,10 +45,8 @@ from .distributed import (
     distributed_corrected_mvm,
     make_distributed_program,
     make_distributed_programmed_mvm,
-    make_distributed_rmvm,
     make_distributed_streamed_mvm,
     make_distributed_streamed_program,
-    make_distributed_streamed_rmvm,
     mesh_grid_shape,
     pallas_shard_map_supported,
     shard_matrix,

@@ -56,22 +56,18 @@ __all__ = [
     "capacity_elements",
     "local_block_keys",
     "program_blocks",
+    "tier1_tile",
     "programmed_block_mvm",
-    "programmed_block_rmvm",
     "local_program_dense",
     "local_dense_mvm",
-    "local_dense_rmvm",
     "group_program_blocks",
     "grouped_block_mvm",
-    "grouped_block_rmvm",
     "grouped_streamed_program_blocks",
     "grouped_streamed_block_mvm",
-    "grouped_streamed_block_rmvm",
     "produce_blocks",
     "producer_is_traceable",
     "streamed_program_blocks",
     "streamed_block_mvm",
-    "streamed_block_rmvm",
     "corrected_mvm",
     "streamed_corrected_mvm",
 ]
@@ -103,6 +99,10 @@ class CrossbarConfig:
     h: float = -1.0
     encode_inputs: bool = True          # inputs (x) also pass through the DAC/encode
     skip_zero_pad_writes: bool = False  # beyond-paper: don't program all-zero chunks
+
+    def __post_init__(self):
+        if self.ec and self.ec_mode not in ("fused", "faithful"):
+            raise ValueError(f"unknown first-order EC mode {self.ec_mode!r}")
 
 
 # --------------------------------------------------------------------------- #
@@ -338,6 +338,55 @@ def program_blocks(
     return jax.lax.map(enc_row, (a_pad.reshape(mb, cap_m, np_), keys))
 
 
+def tier1_tile(
+    at_blk: jnp.ndarray,
+    da_blk: Optional[jnp.ndarray],
+    x_blk: jnp.ndarray,
+    x_t: jnp.ndarray,
+    cfg: CrossbarConfig,
+    *,
+    a_blk: Optional[jnp.ndarray] = None,
+    transpose: bool = False,
+    use_kernel: bool = False,
+) -> jnp.ndarray:
+    """The tier-1 product of ONE capacity block, in either direction.
+
+    Forward: ``At x + dA x_t`` from the block's image ``at_blk``, the input
+    chunk ``x_blk`` and its DAC image ``x_t`` ((cap_n, batch) -> (cap_m,
+    batch)).  ``transpose=True`` reads the image blocks transposed:
+    ``At^T y + dA^T y_t`` ((cap_m, batch) -> (cap_n, batch)).  ``dA`` is
+    the stored ``da_blk``, or -- where a stage holds only the producer
+    block ``a_blk`` (``da_blk=None``) -- re-derived as ``a_blk - at_blk``;
+    ``faithful`` takes its ``A`` as ``a_blk`` when given, else ``at_blk +
+    da_blk``.  ``use_kernel=True`` runs the fused Pallas tile step
+    (:func:`repro.kernels.ops.rram_ec_tile_mvm`).  Callers trace it under
+    the ``meliso.tier1`` scope, with whatever accumulates its result.
+    """
+    t = (lambda b: b.T) if transpose else (lambda b: b)
+    if not cfg.ec:
+        return matmul(t(at_blk), x_t)
+    if use_kernel:
+        from repro.kernels import ops as kops
+        return kops.rram_ec_tile_mvm(
+            x_blk, x_t, at_blk, a_blk - at_blk if da_blk is None else da_blk,
+            transpose=transpose)
+    if cfg.ec_mode == "faithful":
+        # The paper's three analog products, A = A_tilde + dA.
+        return (matmul(t(at_blk), x_blk)
+                + matmul(t(at_blk + da_blk if a_blk is None else a_blk), x_t)
+                - matmul(t(at_blk), x_t))
+    return (matmul(t(at_blk), x_blk)
+            + matmul(t(a_blk - at_blk if da_blk is None else da_blk), x_t))
+
+
+def _contraction(transpose: bool, mb: int, nb: int, cap_m: int, cap_n: int,
+                 m: int, n: int):
+    """``(blocks, block extent, length)`` of the contraction axis and of the
+    output axis: columns and rows forward, rows and columns transposed."""
+    rows, cols = (mb, cap_m, m), (nb, cap_n, n)
+    return (rows, cols) if transpose else (cols, rows)
+
+
 def programmed_block_mvm(
     at_blocks: jnp.ndarray,
     da_blocks: jnp.ndarray,
@@ -349,6 +398,7 @@ def programmed_block_mvm(
     n: int,
     tier2: bool = True,
     use_kernel: bool = False,
+    transpose: bool = False,
 ) -> jnp.ndarray:
     """Execute stage: corrected MVM against an already-programmed image.
 
@@ -361,102 +411,37 @@ def programmed_block_mvm(
     the per-block tier-1 product to the fused Pallas
     :func:`repro.kernels.ops.rram_ec_tile_mvm` tile step (requires
     ``cfg.ec``).  Returns (m, batch).
+
+    ``transpose=True`` runs the corrected ``A.T @ y`` against the same image
+    (zero re-encode): ``xb`` is then (m, batch), chunked over ROW blocks (the
+    contraction axis of A^T), block (i, j) consumes the same k_x half as in
+    a forward execution, row-block partials are summed and tier-2 runs over
+    the (n, batch) column output.
     """
     mb, nb, cap_m, cap_n = at_blocks.shape
     batch = xb.shape[1]
-    x_pad = jnp.pad(xb, ((0, nb * cap_n - n), (0, 0)))
-    x_chunks = x_pad.reshape(nb, cap_n, batch)
+    (n_in, cap_in, len_in), (n_out, cap_out, len_out) = _contraction(
+        transpose, mb, nb, cap_m, cap_n, m, n)
+    x_pad = jnp.pad(xb, ((0, n_in * cap_in - len_in), (0, 0)))
+    x_chunks = x_pad.reshape(n_in, cap_in, batch)
     keys = block_keys(key, mb, nb)
+    if transpose:
+        at_blocks, da_blocks, keys = (
+            b.swapaxes(0, 1) for b in (at_blocks, da_blocks, keys))
 
-    if cfg.ec and cfg.ec_mode not in ("fused", "faithful"):
-        raise ValueError(f"unknown first-order EC mode {cfg.ec_mode!r}")
-
-    def per_row(at_row, da_row, row_keys):
-        def per_col(at_blk, da_blk, x_blk, k):
+    def per_out(at_row, da_row, row_keys):
+        def per_in(at_blk, da_blk, x_blk, k):
             _, k_x = jax.random.split(k)
             x_t = _encode_vec(x_blk, k_x, cfg) if cfg.encode_inputs else x_blk
             with jax.named_scope("meliso.tier1"):
-                if not cfg.ec:
-                    return matmul(at_blk, x_t)
-                if use_kernel:
-                    from repro.kernels import ops as kops
-                    return kops.rram_ec_tile_mvm(x_blk, x_t, at_blk, da_blk)
-                if cfg.ec_mode == "faithful":
-                    # The paper's three analog products, A = A_tilde + dA.
-                    return (matmul(at_blk, x_blk)
-                            + matmul(at_blk + da_blk, x_t)
-                            - matmul(at_blk, x_t))
-                return matmul(at_blk, x_blk) + matmul(da_blk, x_t)  # fused
-        partials = jax.vmap(per_col)(at_row, da_row, x_chunks, row_keys)
+                return tier1_tile(at_blk, da_blk, x_blk, x_t, cfg,
+                                  transpose=transpose, use_kernel=use_kernel)
+        partials = jax.vmap(per_in)(at_row, da_row, x_chunks, row_keys)
         with jax.named_scope("meliso.tier1"):
-            return jnp.sum(partials, axis=0)             # sum over column blocks
+            return jnp.sum(partials, axis=0)      # sum over contraction blocks
 
-    y_blocks = jax.vmap(per_row)(at_blocks, da_blocks, keys)   # (mb, cap_m, batch)
-    p = y_blocks.reshape(mb * cap_m, batch)[:m]
-    if cfg.ec and tier2:
-        p = denoise_least_square(p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)
-    return p
-
-
-def programmed_block_rmvm(
-    at_blocks: jnp.ndarray,
-    da_blocks: jnp.ndarray,
-    yb: jnp.ndarray,
-    key: jax.Array,
-    cfg: CrossbarConfig,
-    *,
-    m: int,
-    n: int,
-    tier2: bool = True,
-    use_kernel: bool = False,
-) -> jnp.ndarray:
-    """Transposed execute stage: corrected ``A.T @ y`` against the programmed
-    image -- zero re-encode of the conductance image.
-
-    The exact mirror of :func:`programmed_block_mvm` run backwards through the
-    crossbar: ``yb`` is (m, batch), the input vector is the ROW-dimension
-    chunking of y (each row-block chunk passes through the DAC, consuming the
-    SAME k_x key half of block (i, j) as a forward execution would), the
-    tier-1 product is assembled from the stored operands as
-    ``p = A_tilde^T y + dA^T y_tilde``, ROW-block partials are summed (rows
-    are the contraction axis of A^T) and tier-2 denoising runs over the
-    assembled (n, batch) column output.  ``use_kernel=True`` dispatches the
-    per-block product to the fused Pallas
-    :func:`repro.kernels.ops.rram_ec_tile_rmvm` tile step.  Returns (n, batch).
-    """
-    mb, nb, cap_m, cap_n = at_blocks.shape
-    batch = yb.shape[1]
-    y_pad = jnp.pad(yb, ((0, mb * cap_m - m), (0, 0)))
-    y_chunks = y_pad.reshape(mb, cap_m, batch)
-    keys = block_keys(key, mb, nb)
-
-    if cfg.ec and cfg.ec_mode not in ("fused", "faithful"):
-        raise ValueError(f"unknown first-order EC mode {cfg.ec_mode!r}")
-
-    def per_col(at_col, da_col, col_keys):
-        def per_row(at_blk, da_blk, y_blk, k):
-            _, k_x = jax.random.split(k)
-            y_t = _encode_vec(y_blk, k_x, cfg) if cfg.encode_inputs else y_blk
-            with jax.named_scope("meliso.tier1"):
-                if not cfg.ec:
-                    return matmul(at_blk.T, y_t)
-                if use_kernel:
-                    from repro.kernels import ops as kops
-                    return kops.rram_ec_tile_rmvm(y_blk, y_t, at_blk, da_blk)
-                if cfg.ec_mode == "faithful":
-                    # The paper's three analog products, transposed.
-                    return (matmul(at_blk.T, y_blk)
-                            + matmul((at_blk + da_blk).T, y_t)
-                            - matmul(at_blk.T, y_t))
-                return matmul(at_blk.T, y_blk) + matmul(da_blk.T, y_t)
-        partials = jax.vmap(per_row)(at_col, da_col, y_chunks, col_keys)
-        with jax.named_scope("meliso.tier1"):
-            return jnp.sum(partials, axis=0)                # sum over row blocks
-
-    z_blocks = jax.vmap(per_col)(at_blocks.swapaxes(0, 1),
-                                 da_blocks.swapaxes(0, 1),
-                                 keys.swapaxes(0, 1))        # (nb, cap_n, batch)
-    p = z_blocks.reshape(nb * cap_n, batch)[:n]
+    y_blocks = jax.vmap(per_out)(at_blocks, da_blocks, keys)
+    p = y_blocks.reshape(n_out * cap_out, batch)[:len_out]
     if cfg.ec and tier2:
         p = denoise_least_square(p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)
     return p
@@ -484,8 +469,10 @@ def local_dense_mvm(
     *,
     tier2: bool = True,
     use_kernel: bool = False,
+    transpose: bool = False,
 ) -> jnp.ndarray:
-    """One device's execute stage over resident dense (m, n) operands.
+    """One device's execute stage over resident dense (m, n) operands, in
+    either direction.
 
     Partitions to capacity blocks and runs the shared
     :func:`programmed_block_mvm` pipeline -- the SAME implementation the
@@ -497,31 +484,8 @@ def local_dense_mvm(
     m, n = at.shape
     return programmed_block_mvm(
         block_partition(at, cfg.geom), block_partition(da, cfg.geom),
-        xb, key, cfg, m=m, n=n, tier2=tier2, use_kernel=use_kernel)
-
-
-def local_dense_rmvm(
-    at: jnp.ndarray,
-    da: jnp.ndarray,
-    yb: jnp.ndarray,
-    key: jax.Array,
-    cfg: CrossbarConfig,
-    *,
-    tier2: bool = True,
-    use_kernel: bool = False,
-) -> jnp.ndarray:
-    """One device's transposed execute stage over resident dense operands.
-
-    Partitions to capacity blocks and runs the shared
-    :func:`programmed_block_rmvm` pipeline -- the same implementation the
-    local execution mode uses, so the distributed transposed path has no
-    private copy of the tier-1 dataflow.  ``tier2=False`` defers denoising
-    until after the cross-device psum over the ROW axes."""
-    from .virtualization import block_partition
-    m, n = at.shape
-    return programmed_block_rmvm(
-        block_partition(at, cfg.geom), block_partition(da, cfg.geom),
-        yb, key, cfg, m=m, n=n, tier2=tier2, use_kernel=use_kernel)
+        xb, key, cfg, m=m, n=n, tier2=tier2, use_kernel=use_kernel,
+        transpose=transpose)
 
 
 # --------------------------------------------------------------------------- #
@@ -566,53 +530,27 @@ def grouped_block_mvm(
     n: int,
     tier2: bool = True,
     use_kernel: bool = False,
+    transpose: bool = False,
 ) -> jnp.ndarray:
     """Corrected MVM of every group member in one pipeline.
 
     ``at_blocks``/``da_blocks`` are (g, mb, nb, cap_m, cap_n) stacked images,
     ``xb`` is (g, n, batch) -- one input panel per member -- and ``keys`` one
-    execute key per member.  Returns (g, m, batch).  Member ``g`` reproduces
-    :func:`programmed_block_mvm` under ``keys[g]`` (the identical per-block
-    k_x halves), including tier-2 denoise per member.  ``use_kernel=True``
-    runs the fused Pallas tile step under a member ``lax.map`` (the kernel
-    sees one member at a time -- the extra image axis never reaches the
-    pallas grid).
+    execute key per member.  Returns (g, m, batch); with ``transpose=True``
+    ``xb`` is (g, m, batch) and the result (g, n, batch).  Member ``g``
+    reproduces :func:`programmed_block_mvm` under ``keys[g]`` (the identical
+    per-block k_x halves), including tier-2 denoise per member.
+    ``use_kernel=True`` runs the fused Pallas tile step under a member
+    ``lax.map`` (the kernel sees one member at a time -- the extra image
+    axis never reaches the pallas grid).
     """
     run = partial(programmed_block_mvm, cfg=cfg, m=m, n=n, tier2=tier2,
-                  use_kernel=use_kernel)
+                  use_kernel=use_kernel, transpose=transpose)
     if use_kernel:
         return jax.lax.map(lambda ops: run(*ops),
                            (at_blocks, da_blocks, xb, keys))
     return jax.vmap(lambda at, da, x, k: run(at, da, x, k))(
         at_blocks, da_blocks, xb, keys)
-
-
-def grouped_block_rmvm(
-    at_blocks: jnp.ndarray,
-    da_blocks: jnp.ndarray,
-    yb: jnp.ndarray,
-    keys: jax.Array,
-    cfg: CrossbarConfig,
-    *,
-    m: int,
-    n: int,
-    tier2: bool = True,
-    use_kernel: bool = False,
-) -> jnp.ndarray:
-    """Transposed grouped execute: ``A_g.T @ y_g`` for every member at once.
-
-    The exact mirror of :func:`grouped_block_mvm` over
-    :func:`programmed_block_rmvm`: ``yb`` is (g, m, batch), the result
-    (g, n, batch), and member ``g`` consumes the same per-block k_x halves a
-    solo transposed execute under ``keys[g]`` would.
-    """
-    run = partial(programmed_block_rmvm, cfg=cfg, m=m, n=n, tier2=tier2,
-                  use_kernel=use_kernel)
-    if use_kernel:
-        return jax.lax.map(lambda ops: run(*ops),
-                           (at_blocks, da_blocks, yb, keys))
-    return jax.vmap(lambda at, da, y, k: run(at, da, y, k))(
-        at_blocks, da_blocks, yb, keys)
 
 
 def _switched_producer(block_fns: Tuple[Callable, ...], g: jax.Array):
@@ -657,45 +595,23 @@ def grouped_streamed_block_mvm(
     n: int,
     use_kernel: bool = False,
     tier2: bool = True,
+    transpose: bool = False,
 ) -> jnp.ndarray:
     """Grouped streamed execute: every member's scan-fused MVM in one
     pipeline (dA re-derived per block from each member's own producer).
 
     ``at_blocks`` is the (g, mb, nb, cap_m, cap_n) stacked resident image,
-    ``xb`` (g, n, batch).  Member ``g`` reproduces :func:`streamed_block_mvm`
-    under ``keys[g]`` exactly.  Returns (g, m, batch).
+    ``xb`` (g, n, batch), or (g, m, batch) with ``transpose=True``.  Member
+    ``g`` reproduces :func:`streamed_block_mvm` under ``keys[g]`` exactly.
+    Returns (g, m, batch), or (g, n, batch) transposed.
     """
     def one(ops):
         g, at, x, k = ops
         return streamed_block_mvm(
             _switched_producer(block_fns, g), at, x, k, cfg, m=m, n=n,
-            use_kernel=use_kernel, tier2=tier2)
+            use_kernel=use_kernel, tier2=tier2, transpose=transpose)
 
     return jax.lax.map(one, (jnp.arange(len(block_fns)), at_blocks, xb, keys))
-
-
-def grouped_streamed_block_rmvm(
-    block_fns: Tuple[Callable, ...],
-    at_blocks: jnp.ndarray,
-    yb: jnp.ndarray,
-    keys: jax.Array,
-    cfg: CrossbarConfig,
-    *,
-    m: int,
-    n: int,
-    use_kernel: bool = False,
-    tier2: bool = True,
-) -> jnp.ndarray:
-    """Grouped streamed TRANSPOSED execute: the :func:`streamed_block_rmvm`
-    mirror of :func:`grouped_streamed_block_mvm` (``yb`` (g, m, batch) ->
-    (g, n, batch), same per-block k_x halves per member as forward)."""
-    def one(ops):
-        g, at, y, k = ops
-        return streamed_block_rmvm(
-            _switched_producer(block_fns, g), at, y, k, cfg, m=m, n=n,
-            use_kernel=use_kernel, tier2=tier2)
-
-    return jax.lax.map(one, (jnp.arange(len(block_fns)), at_blocks, yb, keys))
 
 
 # --------------------------------------------------------------------------- #
@@ -820,17 +736,22 @@ def streamed_block_mvm(
     tier2: bool = True,
     block_offset=(0, 0),
     grid: Optional[Tuple[int, int]] = None,
+    transpose: bool = False,
 ) -> jnp.ndarray:
     """Scan-fused execute stage over a streamed block producer.
 
-    One ``lax.scan`` over row blocks (inner scan over column blocks with
-    in-place fp32 row accumulation) replaces the per-block host loop: the
+    One ``lax.scan`` over output blocks (inner scan over contraction blocks
+    with in-place fp32 accumulation) replaces the per-block host loop: the
     input-DAC encode, the per-block ``dA = block_fn(i, j) - at_blocks[i, j]``
-    re-derivation, the tier-1 EC product (``use_kernel=True`` fuses it into
-    the Pallas :func:`repro.kernels.rram_ec_matmul` tile step) and the partial
+    re-derivation, the tier-1 EC product (:func:`tier1_tile`;
+    ``use_kernel=True`` fuses the Pallas tile step) and the partial
     reduction all live inside one traced program -- one device dispatch per
     MVM.  Key/draw schedule matches :func:`programmed_block_mvm` exactly (the
     k_x half of the per-block key).  ``xb`` is (n, batch); returns (m, batch).
+    ``transpose=True`` runs ``A.T @ y``: the outer scan walks column blocks,
+    the inner one row blocks (the contraction axis of A^T), ``xb`` is
+    (m, batch) and the result (n, batch); block (i, j) keeps its global key
+    whichever direction the grid is traversed.
 
     ``at_blocks`` is normally the resident programmed image from
     :func:`streamed_program_blocks` (the engine's execute-many path).
@@ -843,8 +764,9 @@ def streamed_block_mvm(
     ``grid`` / ``block_offset`` select a local window of a global block grid
     exactly as in :func:`streamed_program_blocks` (global producer indices,
     global key schedule); ``m``/``n``/``xb`` are then the LOCAL row/column
-    footprint of that window -- the shard_map per-device view.  Column-partial
-    psums and tier-2 denoise stay with the caller (``tier2=False``).
+    footprint of that window -- the shard_map per-device view.  Partial
+    psums over the contraction and tier-2 denoise stay with the caller
+    (``tier2=False``).
     """
     i0, j0 = block_offset
     oneshot = at_blocks is None
@@ -854,150 +776,54 @@ def streamed_block_mvm(
     else:
         mb, nb, cap_m, cap_n = at_blocks.shape
     batch = xb.shape[1]
-    if cfg.ec and cfg.ec_mode not in ("fused", "faithful"):
-        raise ValueError(f"unknown first-order EC mode {cfg.ec_mode!r}")
-    x_pad = jnp.pad(xb, ((0, nb * cap_n - n), (0, 0)))
-    x_chunks = x_pad.reshape(nb, cap_n, batch)
+    (n_in, cap_in, len_in), (n_out, cap_out, len_out) = _contraction(
+        transpose, mb, nb, cap_m, cap_n, m, n)
+    c0, o0 = (i0, j0) if transpose else (j0, i0)
+    x_pad = jnp.pad(xb, ((0, n_in * cap_in - len_in), (0, 0)))
+    x_chunks = x_pad.reshape(n_in, cap_in, batch)
     keys = local_block_keys(key, mb, nb, i0, j0, grid)
+    if transpose:
+        keys = jnp.swapaxes(keys, 0, 1)
+        at_blocks = None if oneshot else jnp.swapaxes(at_blocks, 0, 1)
 
-    def row_step(_, row_xs):
+    # The scan bodies keep their forward names (row_step over output blocks,
+    # col_step over the contraction) in both directions: they name the
+    # program's computations and op metadata.
+    def row_step(_, out_xs):
         if oneshot:
-            row_keys, i = row_xs
+            out_keys, o = out_xs
         else:
-            at_row, row_keys, i = row_xs
+            at_out, out_keys, o = out_xs
 
-        def col_step(acc, col_xs):
+        def col_step(acc, in_xs):
             if oneshot:
-                k, j, x_blk = col_xs
+                k, c, x_blk = in_xs
+            else:
+                at_blk, k, c, x_blk = in_xs
+            i, j = (c, o) if transpose else (o, c)
+            if oneshot:
                 a_blk = _produce(block_fn, i, j)
                 k_a, k_x = jax.random.split(k)
                 at_blk = encode_tiled(a_blk, k_a, cfg)
             else:
-                at_blk, k, j, x_blk = col_xs
                 _k_a, k_x = jax.random.split(k)
                 a_blk = _produce(block_fn, i, j) if cfg.ec else None
             x_t = _encode_vec(x_blk, k_x, cfg) if cfg.encode_inputs else x_blk
             with jax.named_scope("meliso.tier1"):
-                if not cfg.ec:
-                    return acc + matmul(at_blk, x_t), None
-                if use_kernel:
-                    from repro.kernels import ops as kops
-                    return acc + kops.rram_ec_tile_mvm(
-                        x_blk, x_t, at_blk, a_blk - at_blk), None
-                if cfg.ec_mode == "faithful":
-                    return acc + (matmul(at_blk, x_blk) + matmul(a_blk, x_t)
-                                  - matmul(at_blk, x_t)), None
-                return acc + (matmul(at_blk, x_blk)
-                              + matmul(a_blk - at_blk, x_t)), None
+                return acc + tier1_tile(at_blk, None, x_blk, x_t, cfg,
+                                        a_blk=a_blk, transpose=transpose,
+                                        use_kernel=use_kernel), None
 
-        acc0 = jnp.zeros((cap_m, batch), jnp.float32)
-        col_xs = (row_keys, j0 + jnp.arange(nb), x_chunks) if oneshot else \
-            (at_row, row_keys, j0 + jnp.arange(nb), x_chunks)
-        acc, _ = jax.lax.scan(col_step, acc0, col_xs)
+        acc0 = jnp.zeros((cap_out, batch), jnp.float32)
+        in_xs = (out_keys, c0 + jnp.arange(n_in), x_chunks) if oneshot else \
+            (at_out, out_keys, c0 + jnp.arange(n_in), x_chunks)
+        acc, _ = jax.lax.scan(col_step, acc0, in_xs)
         return None, acc
 
-    row_xs = (keys, i0 + jnp.arange(mb)) if oneshot else \
-        (at_blocks, keys, i0 + jnp.arange(mb))
-    _, rows = jax.lax.scan(row_step, None, row_xs)
-    p = rows.reshape(mb * cap_m, batch)[:m]
-    if cfg.ec and tier2:
-        p = denoise_least_square(p, lam=cfg.lam, h=cfg.h,
-                                 method=cfg.denoise_method)
-    return p
-
-
-def streamed_block_rmvm(
-    block_fn: Callable[[jax.Array, jax.Array], jnp.ndarray],
-    at_blocks: Optional[jnp.ndarray],
-    yb: jnp.ndarray,
-    key: jax.Array,
-    cfg: CrossbarConfig,
-    *,
-    m: int,
-    n: int,
-    use_kernel: bool = False,
-    tier2: bool = True,
-    block_offset=(0, 0),
-    grid: Optional[Tuple[int, int]] = None,
-) -> jnp.ndarray:
-    """Scan-fused TRANSPOSED execute stage over a streamed block producer.
-
-    The mirror of :func:`streamed_block_mvm` for ``A.T @ y``: one ``lax.scan``
-    over COLUMN blocks (inner scan over row blocks -- the contraction axis of
-    A^T -- with in-place fp32 accumulation) fuses the input-DAC encode of the
-    row-chunked y, the per-block ``dA`` re-derivation, the transposed tier-1
-    EC product (``use_kernel=True`` fuses the Pallas
-    :func:`repro.kernels.ops.rram_ec_tile_rmvm` tile step) and the partial
-    reduction into one traced program -- ONE device dispatch per transposed
-    MVM.  Key/draw schedule matches :func:`programmed_block_rmvm` exactly
-    (block (i, j) consumes the same k_x half it would in a forward
-    execution).  ``yb`` is (m, batch); returns (n, batch).
-
-    ``at_blocks=None`` selects the one-shot variant (each block re-encoded
-    inside the scan with the k_a half -- draws identical to
-    program-then-execute, O(one block) memory); ``grid``/``block_offset``
-    select a local window of a global block grid exactly as in
-    :func:`streamed_block_mvm` (``m``/``n``/``yb`` are then the LOCAL
-    footprint; row-partial psums and tier-2 stay with the caller).
-    """
-    i0, j0 = block_offset
-    oneshot = at_blocks is None
-    if oneshot:
-        cap_m, cap_n = cfg.geom.capacity
-        mb, nb = -(-m // cap_m), -(-n // cap_n)
-    else:
-        mb, nb, cap_m, cap_n = at_blocks.shape
-    batch = yb.shape[1]
-    if cfg.ec and cfg.ec_mode not in ("fused", "faithful"):
-        raise ValueError(f"unknown first-order EC mode {cfg.ec_mode!r}")
-    y_pad = jnp.pad(yb, ((0, mb * cap_m - m), (0, 0)))
-    y_chunks = y_pad.reshape(mb, cap_m, batch)
-    # Column-major sweep over the SAME (mb, nb) key schedule: block (i, j)
-    # keeps its global key whichever direction the grid is traversed.
-    keys_t = jnp.swapaxes(local_block_keys(key, mb, nb, i0, j0, grid), 0, 1)
-    at_t = None if oneshot else jnp.swapaxes(at_blocks, 0, 1)
-
-    def col_step(_, col_xs):
-        if oneshot:
-            col_keys, j = col_xs
-        else:
-            at_col, col_keys, j = col_xs
-
-        def row_step(acc, row_xs):
-            if oneshot:
-                k, i, y_blk = row_xs
-                a_blk = _produce(block_fn, i, j)
-                k_a, k_x = jax.random.split(k)
-                at_blk = encode_tiled(a_blk, k_a, cfg)
-            else:
-                at_blk, k, i, y_blk = row_xs
-                _k_a, k_x = jax.random.split(k)
-                a_blk = _produce(block_fn, i, j) if cfg.ec else None
-            y_t = _encode_vec(y_blk, k_x, cfg) if cfg.encode_inputs else y_blk
-            with jax.named_scope("meliso.tier1"):
-                if not cfg.ec:
-                    return acc + matmul(at_blk.T, y_t), None
-                if use_kernel:
-                    from repro.kernels import ops as kops
-                    return acc + kops.rram_ec_tile_rmvm(
-                        y_blk, y_t, at_blk, a_blk - at_blk), None
-                if cfg.ec_mode == "faithful":
-                    return acc + (matmul(at_blk.T, y_blk)
-                                  + matmul(a_blk.T, y_t)
-                                  - matmul(at_blk.T, y_t)), None
-                return acc + (matmul(at_blk.T, y_blk)
-                              + matmul((a_blk - at_blk).T, y_t)), None
-
-        acc0 = jnp.zeros((cap_n, batch), jnp.float32)
-        row_xs = (col_keys, i0 + jnp.arange(mb), y_chunks) if oneshot else \
-            (at_col, col_keys, i0 + jnp.arange(mb), y_chunks)
-        acc, _ = jax.lax.scan(row_step, acc0, row_xs)
-        return None, acc
-
-    col_xs = (keys_t, j0 + jnp.arange(nb)) if oneshot else \
-        (at_t, keys_t, j0 + jnp.arange(nb))
-    _, cols = jax.lax.scan(col_step, None, col_xs)
-    p = cols.reshape(nb * cap_n, batch)[:n]
+    out_xs = (keys, o0 + jnp.arange(n_out)) if oneshot else \
+        (at_blocks, keys, o0 + jnp.arange(n_out))
+    _, outs = jax.lax.scan(row_step, None, out_xs)
+    p = outs.reshape(n_out * cap_out, batch)[:len_out]
     if cfg.ec and tier2:
         p = denoise_least_square(p, lam=cfg.lam, h=cfg.h,
                                  method=cfg.denoise_method)

@@ -52,9 +52,8 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .crossbar import (CrossbarConfig, input_write_cost, local_dense_mvm,
-                       local_dense_rmvm, local_program_dense,
-                       matrix_write_cost, streamed_block_mvm,
-                       streamed_block_rmvm, streamed_program_blocks,
+                       local_program_dense, matrix_write_cost,
+                       streamed_block_mvm, streamed_program_blocks,
                        write_cost)
 from .error_correction import denoise_least_square
 from .write_verify import WriteStats
@@ -65,13 +64,10 @@ __all__ = [
     "mesh_grid_shape",
     "make_distributed_program",
     "make_distributed_programmed_mvm",
-    "make_distributed_rmvm",
     "make_distributed_streamed_program",
     "make_distributed_streamed_mvm",
-    "make_distributed_streamed_rmvm",
     "make_distributed_group_program",
     "make_distributed_group_mvm",
-    "make_distributed_group_rmvm",
     "pallas_shard_map_supported",
 ]
 
@@ -111,6 +107,18 @@ def _psum_partials(p: jnp.ndarray, axes) -> jnp.ndarray:
     ``meliso.psum`` in traces."""
     with jax.named_scope("meliso.psum"):
         return jax.lax.psum(p, axis_name=axes)
+
+
+def _mesh_contraction(row_axes: Tuple[str, ...], col_axis: str,
+                      transpose: bool):
+    """The mesh axes an execute contracts over (its psum axes) and the
+    ``PartitionSpec``s of its input panel (over those axes) and its output
+    panel (over the others): ``col_axis`` forward, ``row_axes`` transposed."""
+    row_spec = row_axes if len(row_axes) > 1 else row_axes[0]
+    rows, cols = P(row_spec, None), P(col_axis, None)
+    if transpose:
+        return tuple(row_axes), rows, cols
+    return col_axis, cols, rows
 
 
 def _mean_stats(stats: WriteStats, axes: Tuple[str, ...]) -> WriteStats:
@@ -161,6 +169,7 @@ def make_distributed_programmed_mvm(
     *,
     stats_include_matrix: bool = False,
     use_kernel: bool = False,
+    transpose: bool = False,
 ):
     """Build the shard_map'd execute stage (unjitted, lowerable).
 
@@ -172,23 +181,36 @@ def make_distributed_programmed_mvm(
     :func:`pallas_shard_map_supported`), partials psum over ``col_axis``,
     tier-2 denoises on-node.  ``stats_include_matrix=True`` reproduces the
     legacy one-shot accounting (programming + input writes in one figure).
+
+    ``transpose=True`` builds the transposed execute against the same
+    operands: (a_tilde, da, y (m, batch), key) -> (z (n, batch)
+    COLUMN-sharded over ``col_axis``, WriteStats).  ``y`` enters sharded
+    over the ROW axes (the contraction axis of A^T), partials psum over
+    ``row_axes`` and tier-2 denoises each device's column segment -- ready
+    to feed the primal update of a distributed PDHG iteration without a
+    gather.
     """
     axes = tuple(row_axes) + (col_axis,)
+    psum_axes, in_spec, out_spec = _mesh_contraction(
+        row_axes, col_axis, transpose)
 
     def local_fn(at_blk, da_blk, x_blk, key):
         k = _device_key(key, axes)
         m_loc, n_loc = at_blk.shape
         batch = x_blk.shape[1]
         p = local_dense_mvm(at_blk, da_blk, x_blk, k, cfg,
-                            tier2=False, use_kernel=use_kernel)
-        p = _psum_partials(p, col_axis)
+                            tier2=False, use_kernel=use_kernel,
+                            transpose=transpose)
+        p = _psum_partials(p, psum_axes)
         if cfg.ec:
             p = denoise_least_square(
                 p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)
         if stats_include_matrix:
-            stats = write_cost(m_loc, n_loc, cfg, batch=batch)
+            stats = write_cost(m_loc, n_loc, cfg, batch=batch,
+                               transpose=transpose)
         else:
-            stats = input_write_cost(m_loc, n_loc, cfg, batch=batch)
+            stats = input_write_cost(m_loc, n_loc, cfg, batch=batch,
+                                     transpose=transpose)
         return p, _mean_stats(stats, axes)
 
     row_spec = row_axes if len(row_axes) > 1 else row_axes[0]
@@ -201,60 +223,8 @@ def make_distributed_programmed_mvm(
         local_fn,
         mesh=mesh,
         in_specs=(P(row_spec, col_axis), P(row_spec, col_axis),
-                  P(col_axis, None), P()),
-        out_specs=(P(row_spec, None), P()),
-        **kwargs,
-    )
-
-
-def make_distributed_rmvm(
-    cfg: CrossbarConfig,
-    mesh: Mesh,
-    row_axes: Tuple[str, ...] = ("data",),
-    col_axis: str = "model",
-    *,
-    use_kernel: bool = False,
-):
-    """Build the shard_map'd TRANSPOSED execute stage (unjitted, lowerable).
-
-    Returned fn: (a_tilde, da, y (m, batch), key) -> (z (n, batch)
-    COLUMN-sharded over ``col_axis``, WriteStats).  The mirror of
-    :func:`make_distributed_programmed_mvm` with the contraction flipped:
-    ``y`` enters sharded over the ROW axes (the contraction axis of A^T),
-    tier-1 runs transposed against the same resident operands via the shared
-    per-device stage (:func:`~repro.core.crossbar.local_dense_rmvm`;
-    ``use_kernel=True`` dispatches its tile products to the fused Pallas
-    transposed tile step), partials psum over ``row_axes``, and tier-2
-    denoises on-node on each device's COLUMN segment -- so the output is
-    produced already column-sharded, ready to feed the primal update of a
-    distributed PDHG iteration without a gather.
-    """
-    axes = tuple(row_axes) + (col_axis,)
-
-    def local_fn(at_blk, da_blk, y_blk, key):
-        k = _device_key(key, axes)
-        m_loc, n_loc = at_blk.shape
-        batch = y_blk.shape[1]
-        p = local_dense_rmvm(at_blk, da_blk, y_blk, k, cfg,
-                             tier2=False, use_kernel=use_kernel)
-        p = _psum_partials(p, tuple(row_axes))
-        if cfg.ec:
-            p = denoise_least_square(
-                p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)
-        stats = input_write_cost(m_loc, n_loc, cfg, batch=batch,
-                                 transpose=True)
-        return p, _mean_stats(stats, axes)
-
-    row_spec = row_axes if len(row_axes) > 1 else row_axes[0]
-    kwargs = {}
-    if use_kernel:
-        kwargs["check_vma"] = False
-    return shard_map(
-        local_fn,
-        mesh=mesh,
-        in_specs=(P(row_spec, col_axis), P(row_spec, col_axis),
-                  P(row_spec, None), P()),
-        out_specs=(P(col_axis, None), P()),
+                  in_spec, P()),
+        out_specs=(out_spec, P()),
         **kwargs,
     )
 
@@ -319,6 +289,7 @@ def make_distributed_group_mvm(
     col_axis: str = "model",
     *,
     use_kernel: bool = False,
+    transpose: bool = False,
 ):
     """Build the shard_map'd GROUP execute stage (unjitted, lowerable).
 
@@ -329,70 +300,30 @@ def make_distributed_group_mvm(
     over ``col_axis`` ONCE for the whole group (not once per member), and
     tier-2 denoises each member's on-node segment.  Member ``g`` under
     ``keys[g]`` is bit-identical to a solo distributed execute of that member
-    under the same key.
+    under the same key.  ``transpose=True`` runs every ``A_g.T @ y_g``:
+    ``y_g`` (g, m, batch) enters sharded over the ROW axes, the partials psum
+    once over ``row_axes`` and the (g, n, batch) output comes back
+    column-sharded over ``col_axis``.
     """
     axes = tuple(row_axes) + (col_axis,)
+    psum_axes, in_spec, out_spec = _mesh_contraction(
+        row_axes, col_axis, transpose)
 
     def local_fn(at_slab, da_slab, x_slab, keys):
         dev_keys = jax.vmap(lambda k: _device_key(k, axes))(keys)
         size, m_loc, n_loc = at_slab.shape
         batch = x_slab.shape[2]
         p = jax.vmap(lambda at, da, x, k: local_dense_mvm(
-            at, da, x, k, cfg, tier2=False, use_kernel=use_kernel))(
-            at_slab, da_slab, x_slab, dev_keys)
-        p = _psum_partials(p, col_axis)      # ONE psum for the group
-        if cfg.ec:
-            p = jax.vmap(lambda q: denoise_least_square(
-                q, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method))(p)
-        stats = _mean_stats(
-            _scale_stats(input_write_cost(m_loc, n_loc, cfg, batch=batch),
-                         size), axes)
-        return p, stats
-
-    row_spec = row_axes if len(row_axes) > 1 else row_axes[0]
-    kwargs = {"check_vma": False} if use_kernel else {}
-    return shard_map(
-        local_fn,
-        mesh=mesh,
-        in_specs=(P(None, row_spec, col_axis), P(None, row_spec, col_axis),
-                  P(None, col_axis, None), P()),
-        out_specs=(P(None, row_spec, None), P()),
-        **kwargs,
-    )
-
-
-def make_distributed_group_rmvm(
-    cfg: CrossbarConfig,
-    mesh: Mesh,
-    row_axes: Tuple[str, ...] = ("data",),
-    col_axis: str = "model",
-    *,
-    use_kernel: bool = False,
-):
-    """Build the shard_map'd GROUP transposed execute stage (unjitted).
-
-    The :func:`make_distributed_rmvm` mirror of
-    :func:`make_distributed_group_mvm`: ``y_g`` (g, m, batch) enters sharded
-    over the ROW axes, transposed tier-1 runs vmapped over the image axis, the
-    stacked partials psum ONCE over ``row_axes`` for the whole group, and the
-    (g, n, batch) output comes back column-sharded over ``col_axis``.
-    """
-    axes = tuple(row_axes) + (col_axis,)
-
-    def local_fn(at_slab, da_slab, y_slab, keys):
-        dev_keys = jax.vmap(lambda k: _device_key(k, axes))(keys)
-        size, m_loc, n_loc = at_slab.shape
-        batch = y_slab.shape[2]
-        p = jax.vmap(lambda at, da, y, k: local_dense_rmvm(
-            at, da, y, k, cfg, tier2=False, use_kernel=use_kernel))(
-            at_slab, da_slab, y_slab, dev_keys)
-        p = _psum_partials(p, tuple(row_axes))   # ONE psum per group
+            at, da, x, k, cfg, tier2=False, use_kernel=use_kernel,
+            transpose=transpose))(at_slab, da_slab, x_slab, dev_keys)
+        # ONE psum for the group
+        p = _psum_partials(p, psum_axes)
         if cfg.ec:
             p = jax.vmap(lambda q: denoise_least_square(
                 q, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method))(p)
         stats = _mean_stats(
             _scale_stats(input_write_cost(m_loc, n_loc, cfg, batch=batch,
-                                          transpose=True), size), axes)
+                                          transpose=transpose), size), axes)
         return p, stats
 
     row_spec = row_axes if len(row_axes) > 1 else row_axes[0]
@@ -401,8 +332,8 @@ def make_distributed_group_rmvm(
         local_fn,
         mesh=mesh,
         in_specs=(P(None, row_spec, col_axis), P(None, row_spec, col_axis),
-                  P(None, row_spec, None), P()),
-        out_specs=(P(None, col_axis, None), P()),
+                  P(None, *in_spec), P()),
+        out_specs=(P(None, *out_spec), P()),
         **kwargs,
     )
 
@@ -465,6 +396,7 @@ def make_distributed_streamed_mvm(
     nb: int,
     resident: bool = True,
     use_kernel: bool = False,
+    transpose: bool = False,
 ):
     """Build the shard_map'd producer-driven execute stage (unjitted).
 
@@ -472,18 +404,24 @@ def make_distributed_streamed_mvm(
     ``(x, key) -> y`` -- ``x`` is the global (n, batch) panel (sharded or
     resharded over ``col_axis`` on entry), ``y`` the global (m, batch) output
     which STAYS row-sharded over ``row_axes`` (no gather), so solver panels
-    remain distributed across a whole ``lax.while_loop``.
+    remain distributed across a whole ``lax.while_loop``.  ``transpose=True``
+    runs ``A.T @ y``: the (m, batch) panel enters sharded over the ROW axes
+    (the contraction of A^T) and the (n, batch) output comes back
+    COLUMN-sharded over ``col_axis``.
 
     Each device runs ONE scan-fused
     :func:`~repro.core.crossbar.streamed_block_mvm` over its local window of
-    the global block grid (global producer indices, global key schedule):
-    input-DAC encode, per-block dA re-derivation, tier-1 EC (``use_kernel``
-    fuses the Pallas tile step), fp32 row accumulation.  Tier-1 partials psum
-    over ``col_axis``; tier-2 denoise runs on-node on the local output
-    segment.  ``resident=False`` selects the one-shot scan variant: each
-    block is re-encoded inside the scan body (draws identical to
-    program-then-execute) and immediately consumed, so NO device ever holds
-    more than O(one capacity block) of A -- the paper's >= 65,536^2 regime.
+    the global block grid (global producer indices, global key schedule --
+    the same per-block k_x halves in either direction, so a 1x1 mesh is
+    draw-identical to the single-device streamed sweep): input-DAC encode,
+    per-block dA re-derivation, tier-1 EC (``use_kernel`` fuses the Pallas
+    tile step), fp32 accumulation.  Tier-1 partials psum over the
+    contraction (``col_axis`` forward, ``row_axes`` transposed); tier-2
+    denoise runs on-node on the local output segment.  ``resident=False``
+    selects the one-shot scan variant: each block is re-encoded inside the
+    scan body (draws identical to program-then-execute) and immediately
+    consumed, so NO device ever holds more than O(one capacity block) of A
+    -- the paper's >= 65,536^2 regime.
     """
     r_count, c_count = mesh_grid_shape(mesh, row_axes, col_axis)
     mb_loc, nb_loc = mb // r_count, nb // c_count
@@ -492,6 +430,8 @@ def make_distributed_streamed_mvm(
     # where the single device owns the (possibly padded) global edge.
     m_loc = m if r_count == 1 else mb_loc * cap_m
     n_loc = n if c_count == 1 else nb_loc * cap_n
+    psum_axes, in_spec, out_spec = _mesh_contraction(
+        row_axes, col_axis, transpose)
 
     def local_fn(*args):
         if resident:
@@ -503,8 +443,8 @@ def make_distributed_streamed_mvm(
         p = streamed_block_mvm(
             block_fn, at_loc, x_blk, key, cfg, m=m_loc, n=n_loc,
             use_kernel=use_kernel, tier2=False,
-            block_offset=(i0, j0), grid=(mb, nb))
-        p = _psum_partials(p, col_axis)
+            block_offset=(i0, j0), grid=(mb, nb), transpose=transpose)
+        p = _psum_partials(p, psum_axes)
         if cfg.ec:
             p = denoise_least_square(
                 p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)
@@ -515,75 +455,8 @@ def make_distributed_streamed_mvm(
     return shard_map(
         local_fn,
         mesh=mesh,
-        in_specs=at_spec + (P(col_axis, None), P()),
-        out_specs=P(row_spec, None),
-        check_vma=False,   # axis_index-derived block windows defeat the
-                           # static replication checker; psum is still exact
-    )
-
-
-def make_distributed_streamed_rmvm(
-    block_fn: Callable[[jax.Array, jax.Array], jnp.ndarray],
-    cfg: CrossbarConfig,
-    mesh: Mesh,
-    row_axes: Tuple[str, ...] = ("data",),
-    col_axis: str = "model",
-    *,
-    m: int,
-    n: int,
-    mb: int,
-    nb: int,
-    resident: bool = True,
-    use_kernel: bool = False,
-):
-    """Build the shard_map'd producer-driven TRANSPOSED execute stage.
-
-    Returned fn: ``(at_blocks, y, key) -> z`` when ``resident``, else
-    ``(y, key) -> z`` -- ``y`` the global (m, batch) panel sharded over the
-    ROW axes (the contraction of A^T), ``z`` the global (n, batch) output
-    which comes back COLUMN-sharded over ``col_axis`` (no gather).
-
-    Each device runs ONE scan-fused
-    :func:`~repro.core.crossbar.streamed_block_rmvm` over its window of the
-    global block grid (global producer indices, global key schedule -- the
-    SAME per-block k_x halves as forward execution, so a 1x1 mesh is
-    draw-identical to the single-device streamed transposed sweep).
-    Transposed tier-1 partials psum over ``row_axes``; tier-2 denoise runs
-    on-node on the local column segment.  ``resident=False`` re-encodes each
-    block inside the scan (draws identical to program-then-execute), so a
-    >= 65,536^2 LP's ``A.T @ y`` runs with no device ever holding more than
-    O(one capacity block) of A.
-    """
-    r_count, c_count = mesh_grid_shape(mesh, row_axes, col_axis)
-    mb_loc, nb_loc = mb // r_count, nb // c_count
-    cap_m, cap_n = cfg.geom.capacity
-    m_loc = m if r_count == 1 else mb_loc * cap_m
-    n_loc = n if c_count == 1 else nb_loc * cap_n
-
-    def local_fn(*args):
-        if resident:
-            at_loc, y_blk, key = args
-        else:
-            (y_blk, key), at_loc = args, None
-        i0 = _row_index(row_axes) * mb_loc
-        j0 = jax.lax.axis_index(col_axis) * nb_loc
-        p = streamed_block_rmvm(
-            block_fn, at_loc, y_blk, key, cfg, m=m_loc, n=n_loc,
-            use_kernel=use_kernel, tier2=False,
-            block_offset=(i0, j0), grid=(mb, nb))
-        p = _psum_partials(p, tuple(row_axes))
-        if cfg.ec:
-            p = denoise_least_square(
-                p, lam=cfg.lam, h=cfg.h, method=cfg.denoise_method)
-        return p
-
-    row_spec = row_axes if len(row_axes) > 1 else row_axes[0]
-    at_spec = (P(row_spec, col_axis, None, None),) if resident else ()
-    return shard_map(
-        local_fn,
-        mesh=mesh,
-        in_specs=at_spec + (P(row_spec, None), P()),
-        out_specs=P(col_axis, None),
+        in_specs=at_spec + (in_spec, P()),
+        out_specs=out_spec,
         check_vma=False,   # axis_index-derived block windows defeat the
                            # static replication checker; psum is still exact
     )

@@ -35,8 +35,6 @@ __all__ = [
     "build_l_matrix",
     "tridiag_coeffs",
     "denoise_least_square",
-    "corrected_matvecmul",
-    "corrected_matmul",
 ]
 
 
@@ -162,53 +160,3 @@ def denoise_least_square(
         if method == "neumann":
             return _neumann_apply(p, lam, h)
     raise ValueError(f"unknown denoise method {method!r}")
-
-
-# --------------------------------------------------------------------------- #
-# End-to-end corrected MVM (paper Algorithm 6)
-# --------------------------------------------------------------------------- #
-
-def corrected_matvecmul(
-    a: jnp.ndarray,
-    x: jnp.ndarray,
-    a_tilde: jnp.ndarray,
-    x_tilde: jnp.ndarray,
-    *,
-    lam: float = 1e-12,
-    h: float = -1.0,
-    ec_mode: str = "fused",
-    denoise_method: str = "neumann",
-) -> jnp.ndarray:
-    """correctedMatVecMul: tier-1 + tier-2 on pre-encoded operands."""
-    p = first_order_correct(a, a_tilde, x, x_tilde, mode=ec_mode)
-    return denoise_least_square(p, lam=lam, h=h, method=denoise_method)
-
-
-def corrected_matmul(
-    x: jnp.ndarray,
-    w: jnp.ndarray,
-    x_tilde: jnp.ndarray,
-    w_tilde: jnp.ndarray,
-    *,
-    lam: float = 1e-12,
-    h: float = -1.0,
-    ec_mode: str = "fused",
-    denoise_method: str = "neumann",
-) -> jnp.ndarray:
-    """Row-major orientation used by LM layers: y = x @ W, EC over both operands.
-
-    p = x̃W + xW̃ - x̃W̃  (= xW - Δx ΔW);  fused form: p = xW̃ + x̃(W - W̃).
-    Tier-2 denoising runs along the *output-feature* axis (the analog column
-    lines), i.e. the last axis -- we transpose through the (n,)-leading
-    convention of :func:`denoise_least_square`.
-    """
-    if ec_mode == "faithful":
-        p = x_tilde @ w + x @ w_tilde - x_tilde @ w_tilde
-    elif ec_mode == "fused":
-        p = x @ w_tilde + x_tilde @ (w - w_tilde)
-    else:
-        raise ValueError(f"unknown first-order EC mode {ec_mode!r}")
-    shape = p.shape
-    pt = jnp.moveaxis(p.reshape(-1, shape[-1]), -1, 0)  # (n_out, batch*)
-    yt = denoise_least_square(pt, lam=lam, h=h, method=denoise_method)
-    return jnp.moveaxis(yt, 0, -1).reshape(shape)

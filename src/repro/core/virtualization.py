@@ -26,9 +26,7 @@ __all__ = [
     "MCAGeometry",
     "zero_padding",
     "block_partition",
-    "generate_mat_chunks",
     "generate_vec_chunks",
-    "reassemble",
     "reassignment_count",
 ]
 
@@ -84,32 +82,12 @@ def block_partition(a: jnp.ndarray, geom: MCAGeometry) -> jnp.ndarray:
     return a.reshape(mb, cap_m, nb, cap_n).transpose(0, 2, 1, 3)
 
 
-def generate_mat_chunks(a: jnp.ndarray, geom: MCAGeometry) -> jnp.ndarray:
-    """generateMatChunksSet (Alg. 8): blocks -> per-MCA chunks.
-
-    Returns shape (mb, nb, R, C, r, c): block [i, j], MCA [p, q], cells [l, h].
-    """
-    blocks = block_partition(a, geom)  # (mb, nb, cap_m, cap_n)
-    mb, nb, cap_m, cap_n = blocks.shape
-    r_, c_ = geom.cell_rows, geom.cell_cols
-    out = blocks.reshape(mb, nb, geom.tile_rows, r_, geom.tile_cols, c_)
-    return out.transpose(0, 1, 2, 4, 3, 5)
-
-
 def generate_vec_chunks(x: jnp.ndarray, geom: MCAGeometry) -> jnp.ndarray:
     """generateVecChunksSet (Alg. 9): x -> (nb, C, c) chunks matching columns."""
     x = zero_padding(x, geom)
     cap_n = geom.capacity[1]
     nb = x.shape[0] // cap_n
     return x.reshape(nb, geom.tile_cols, geom.cell_cols)
-
-
-def reassemble(y_blocks: jnp.ndarray, m: int) -> jnp.ndarray:
-    """Inverse of the row-wise partition for the output vector.
-
-    ``y_blocks`` has shape (mb, cap_m) (column-block partials already summed);
-    returns the first ``m`` entries of the concatenation."""
-    return y_blocks.reshape(-1)[:m]
 
 
 def reassignment_count(m: int, n: int, geom: MCAGeometry) -> int:

@@ -31,9 +31,10 @@ Placement x pipeline matrix (which combinations fuse, which fall back)::
     execution     source      backend=reference          backend=pallas
     ------------  ----------  -------------------------  ------------------------
     local         dense a     vmapped block pipeline     fused rram_ec_matmul
-                                                         (one whole-image kernel)
+                              (programmed_block_mvm)     (one whole-image kernel)
     streamed      traceable   ONE lax.scan dispatch per  same scan, tile step =
                   block_fn    program / MVM              rram_ec_tile_mvm kernel
+                              (streamed_block_mvm)
     streamed      opaque      host loop, one jitted      host loop, kernel tile
                   block_fn    dispatch per block         step per block
     distributed   dense a     shard_map over the shared  shard_map'd kernel tile
@@ -44,7 +45,10 @@ Placement x pipeline matrix (which combinations fuse, which fall back)::
     distributed   opaque      rejected (cannot trace inside shard_map; use
                   block_fn    execution="streamed" for the host-loop fallback)
 
-Every cell of the matrix also executes TRANSPOSED: ``A.T @ y`` (=
+Every cell of the matrix also executes TRANSPOSED, through the same stage:
+each stage takes a static ``transpose`` argument, and every block's tier-1
+product goes through one tile step in either direction
+(:func:`repro.core.crossbar.tier1_tile`).  ``A.T @ y`` (=
 :meth:`AnalogEngine.rmvm`, via the zero-copy :class:`TransposedAnalogMatrix`
 view) runs the corrected ``A^T y`` against the SAME programmed image --
 tier-1 ``A_tilde^T y + dA^T y_tilde`` from the stored operands, row blocks
@@ -54,7 +58,7 @@ per-block k_x key halves as a forward call (a 1x1 mesh stays draw-identical
 to streamed in both directions), and ``resident=False`` handles re-encode
 inside the transposed scan exactly as they do forward (no A-sized array in
 either direction).  The pallas tile step reads the same fused kernel in the
-``y^T A`` direction (:func:`repro.kernels.ops.rram_ec_tile_rmvm`); see
+``y^T A`` direction (``rram_ec_tile_mvm(..., transpose=True)``); see
 DESIGN.md section 5.
 
 ``backend="pallas"`` under ``execution="distributed"`` is checked by
@@ -555,16 +559,11 @@ _assemble = crossbar.assemble_blocks
 _program_local = jax.jit(crossbar.program_blocks, static_argnames=("cfg",))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "m", "n"))
-def _exec_reference(at_blocks, da_blocks, xb, key, *, cfg, m, n):
+@functools.partial(jax.jit, static_argnames=("cfg", "m", "n", "transpose"))
+def _exec_reference(at_blocks, da_blocks, xb, key, *, cfg, m, n,
+                    transpose=False):
     return crossbar.programmed_block_mvm(
-        at_blocks, da_blocks, xb, key, cfg, m=m, n=n)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "m", "n"))
-def _exec_reference_t(at_blocks, da_blocks, yb, key, *, cfg, m, n):
-    return crossbar.programmed_block_rmvm(
-        at_blocks, da_blocks, yb, key, cfg, m=m, n=n)
+        at_blocks, da_blocks, xb, key, cfg, m=m, n=n, transpose=transpose)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "m", "n", "transpose"))
@@ -578,9 +577,8 @@ def _exec_reference_aged(at_blocks, da_blocks, xb, key, age, *, cfg, m, n,
     """
     from repro.reliability.aging import aged_blocks
     at_aged = aged_blocks(at_blocks, age, cfg.device)
-    run = crossbar.programmed_block_rmvm if transpose \
-        else crossbar.programmed_block_mvm
-    return run(at_aged, da_blocks, xb, key, cfg, m=m, n=n)
+    return crossbar.programmed_block_mvm(at_aged, da_blocks, xb, key, cfg,
+                                         m=m, n=n, transpose=transpose)
 
 
 def _pallas_corrected(at, da, xb, key, cfg, m, n, transpose):
@@ -630,19 +628,13 @@ def _pallas_corrected(at, da, xb, key, cfg, m, n, transpose):
     return p
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "m", "n"))
-def _exec_pallas(at, da, xb, key, *, cfg, m, n):
-    """Tier-1 via the fused Pallas EC kernel + tier-2 via the solver kernels
-    (see :func:`_pallas_corrected`); ``at``/``da`` are the handle's stored
+@functools.partial(jax.jit, static_argnames=("cfg", "m", "n", "transpose"))
+def _exec_pallas(at, da, xb, key, *, cfg, m, n, transpose=False):
+    """Tier-1 via the fused Pallas EC kernel (read backwards when
+    ``transpose``) + tier-2 via the solver kernels (see
+    :func:`_pallas_corrected`); ``at``/``da`` are the handle's stored
     capacity-block stacks."""
-    return _pallas_corrected(at, da, xb, key, cfg, m, n, transpose=False)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "m", "n"))
-def _exec_pallas_t(at, da, yb, key, *, cfg, m, n):
-    """Transposed tier-1 via the same fused Pallas EC kernel read backwards
-    over the same stored block stacks."""
-    return _pallas_corrected(at, da, yb, key, cfg, m, n, transpose=True)
+    return _pallas_corrected(at, da, xb, key, cfg, m, n, transpose=transpose)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "m", "n", "transpose"))
@@ -650,9 +642,8 @@ def _exec_group_reference(at_g, da_g, xb_g, keys, *, cfg, m, n, transpose):
     """Grouped execute: every member's corrected MVM in ONE dispatch (the
     vmapped :func:`repro.core.crossbar.grouped_block_mvm` stage; member g
     consumes ``keys[g]`` exactly as its solo execute would)."""
-    run = crossbar.grouped_block_rmvm if transpose \
-        else crossbar.grouped_block_mvm
-    return run(at_g, da_g, xb_g, keys, cfg, m=m, n=n)
+    return crossbar.grouped_block_mvm(at_g, da_g, xb_g, keys, cfg, m=m, n=n,
+                                      transpose=transpose)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "m", "n", "transpose"))
@@ -677,9 +668,8 @@ def _exec_group_reference_aged(at_g, da_g, xb_g, keys, ages, *, cfg, m, n,
     from repro.reliability.aging import aged_blocks
     at_aged = jax.vmap(lambda at, age: aged_blocks(at, age, cfg.device))(
         at_g, ages)
-    run = crossbar.grouped_block_rmvm if transpose \
-        else crossbar.grouped_block_mvm
-    return run(at_aged, da_g, xb_g, keys, cfg, m=m, n=n)
+    return crossbar.grouped_block_mvm(at_aged, da_g, xb_g, keys, cfg, m=m,
+                                      n=n, transpose=transpose)
 
 
 @functools.partial(jax.jit,
@@ -766,12 +756,10 @@ class AnalogEngine:
             from repro.core import distributed as D
             self._dist_program = jax.jit(D.make_distributed_program(
                 cfg, mesh, self.row_axes, col_axis))
-            self._dist_mvm = jax.jit(D.make_distributed_programmed_mvm(
-                cfg, mesh, self.row_axes, col_axis))
-            # dense execute pipelines keyed by (use_kernel, transpose)
-            # (pallas / transposed variants built lazily, the former behind
-            # the shard_map capability probe).
-            self._dist_mvm_cache = {(False, False): self._dist_mvm}
+            # dense execute pipelines keyed by (use_kernel, transpose), built
+            # on first use (pallas ones behind the shard_map capability
+            # probe).
+            self._dist_mvm_cache = {}
 
     def _dist_use_kernel(self) -> bool:
         """Whether distributed execution may fuse the Pallas tile step."""
@@ -787,11 +775,9 @@ class AnalogEngine:
         fn = self._dist_mvm_cache.get((use_kernel, transpose))
         if fn is None:
             from repro.core import distributed as D
-            make = D.make_distributed_rmvm if transpose else \
-                D.make_distributed_programmed_mvm
-            fn = jax.jit(make(
+            fn = jax.jit(D.make_distributed_programmed_mvm(
                 self.cfg, self.mesh, self.row_axes, self.col_axis,
-                use_kernel=use_kernel))
+                use_kernel=use_kernel, transpose=transpose))
             self._dist_mvm_cache[(use_kernel, transpose)] = fn
         return fn
 
@@ -1366,11 +1352,10 @@ class AnalogEngine:
             cache_key = (use_kernel, transpose, batch)
             fn = cache.get(cache_key)
             if fn is None:
-                stage = crossbar.grouped_streamed_block_rmvm if transpose \
-                    else crossbar.grouped_streamed_block_mvm
                 fn = jax.jit(functools.partial(
-                    stage, G.block_fns,
-                    cfg=self.cfg, m=m, n=n, use_kernel=use_kernel))
+                    crossbar.grouped_streamed_block_mvm, G.block_fns,
+                    cfg=self.cfg, m=m, n=n, use_kernel=use_kernel,
+                    transpose=transpose))
                 cache.put(cache_key, fn)
             p = fn(G.at_blocks, xb, keys)
         elif self.backend == "pallas":
@@ -1391,11 +1376,9 @@ class AnalogEngine:
         fn = self._dist_mvm_cache.get(("group", use_kernel, transpose))
         if fn is None:
             from repro.core import distributed as D
-            make = D.make_distributed_group_rmvm if transpose else \
-                D.make_distributed_group_mvm
-            fn = jax.jit(make(
+            fn = jax.jit(D.make_distributed_group_mvm(
                 self.cfg, self.mesh, self.row_axes, self.col_axis,
-                use_kernel=use_kernel))
+                use_kernel=use_kernel, transpose=transpose))
             self._dist_mvm_cache[("group", use_kernel, transpose)] = fn
         return fn
 
@@ -1556,14 +1539,11 @@ class AnalogEngine:
             elif A.da_blocks is None:
                 # Streamed handle: dA is not resident; re-derive per block.
                 p = self._exec_streamed(A, xb, key, transpose)
-            elif self.backend == "pallas":
-                run = _exec_pallas_t if transpose else _exec_pallas
-                p = run(A.at_blocks, A.da_blocks, xb, key,
-                        cfg=self.cfg, m=m, n=n)
             else:
-                run = _exec_reference_t if transpose else _exec_reference
+                run = _exec_pallas if self.backend == "pallas" \
+                    else _exec_reference
                 p = run(A.at_blocks, A.da_blocks, xb, key,
-                        cfg=self.cfg, m=m, n=n)
+                        cfg=self.cfg, m=m, n=n, transpose=transpose)
         if with_stats and stats is None:
             stats = crossbar.input_write_cost(m, n, self.cfg,
                                               batch=xb.shape[1],
@@ -1578,8 +1558,6 @@ class AnalogEngine:
         ones take the compatibility host loop (one jitted dispatch per
         block)."""
         cfg = self.cfg
-        if cfg.ec and cfg.ec_mode not in ("fused", "faithful"):
-            raise ValueError(f"unknown first-order EC mode {cfg.ec_mode!r}")
         m, n = A.shape
         use_kernel = self.backend == "pallas" and cfg.ec
         if A.block_traceable:
@@ -1593,11 +1571,10 @@ class AnalogEngine:
             cache_key = (use_kernel, transpose, xb.shape[1])
             fn = cache.get(cache_key)
             if fn is None:
-                stage = crossbar.streamed_block_rmvm if transpose \
-                    else crossbar.streamed_block_mvm
                 fn = jax.jit(functools.partial(
-                    stage, A.block_fn,
-                    cfg=cfg, m=m, n=n, use_kernel=use_kernel))
+                    crossbar.streamed_block_mvm, A.block_fn,
+                    cfg=cfg, m=m, n=n, use_kernel=use_kernel,
+                    transpose=transpose))
                 cache.put(cache_key, fn)
             return fn(A.at_blocks, xb, key)
         return self._exec_streamed_host(A, xb, key, use_kernel, transpose)
@@ -1619,12 +1596,10 @@ class AnalogEngine:
             from repro.core import distributed as D
             m, n = A.shape
             mb, nb = A._grid()
-            make = D.make_distributed_streamed_rmvm if transpose else \
-                D.make_distributed_streamed_mvm
-            fn = jax.jit(make(
+            fn = jax.jit(D.make_distributed_streamed_mvm(
                 A.block_fn, self.cfg, self.mesh, self.row_axes, self.col_axis,
                 m=m, n=n, mb=mb, nb=nb, resident=A.at_blocks is not None,
-                use_kernel=use_kernel))
+                use_kernel=use_kernel, transpose=transpose))
             cache.put(cache_key, fn)
         # Place the input as the shard_map takes it.  Its type then carries
         # the mesh on every call, so a solver's first input and its loop
@@ -1647,9 +1622,10 @@ class AnalogEngine:
         m, n = A.shape
         mb, nb, cap_m, cap_n = A.at_blocks.shape
         batch = xb.shape[1]
-        pad_to = mb * cap_m if transpose else nb * cap_n
-        x_pad = jnp.pad(xb, ((0, pad_to - xb.shape[0]), (0, 0)))
-        x_chunks = x_pad.reshape(mb if transpose else nb, -1, batch)
+        (n_in, cap_in, len_in), (n_out, cap_out, len_out) = \
+            crossbar._contraction(transpose, mb, nb, cap_m, cap_n, m, n)
+        x_pad = jnp.pad(xb, ((0, n_in * cap_in - len_in), (0, 0)))
+        x_chunks = x_pad.reshape(n_in, cap_in, batch)
         keys = crossbar.block_keys(key, mb, nb)
 
         step = self._streamed_step.get((use_kernel, transpose))
@@ -1658,41 +1634,24 @@ class AnalogEngine:
                 _, k_x = jax.random.split(k)
                 x_t = crossbar._encode_vec(x_blk, k_x, cfg) \
                     if cfg.encode_inputs else x_blk
-                from repro.kernels import ops as kops
                 with jax.named_scope("meliso.tier1"):
-                    if transpose:
-                        if not cfg.ec:
-                            return at_blk.T @ x_t
-                        if use_kernel:
-                            return kops.rram_ec_tile_rmvm(x_blk, x_t, at_blk,
-                                                          a_blk - at_blk)
-                        if cfg.ec_mode == "faithful":
-                            return (at_blk.T @ x_blk + a_blk.T @ x_t
-                                    - at_blk.T @ x_t)
-                        return at_blk.T @ x_blk + (a_blk - at_blk).T @ x_t
-                    if not cfg.ec:
-                        return at_blk @ x_t
-                    if use_kernel:
-                        return kops.rram_ec_tile_mvm(x_blk, x_t, at_blk,
-                                                     a_blk - at_blk)
-                    if cfg.ec_mode == "faithful":
-                        return at_blk @ x_blk + a_blk @ x_t - at_blk @ x_t
-                    return at_blk @ x_blk + (a_blk - at_blk) @ x_t
+                    return crossbar.tier1_tile(
+                        at_blk, None, x_blk, x_t, cfg, a_blk=a_blk,
+                        transpose=transpose, use_kernel=use_kernel)
 
             # Jitted once per engine (per direction/backend): execute-many
             # calls reuse the trace.
             step = jax.jit(step)
             self._streamed_step[(use_kernel, transpose)] = step
-        out_blocks, acc_cap = (nb, cap_n) if transpose else (mb, cap_m)
         rows = []
-        for o in range(out_blocks):
-            acc = jnp.zeros((acc_cap, batch), jnp.float32)
-            for c in range(mb if transpose else nb):
+        for o in range(n_out):
+            acc = jnp.zeros((cap_out, batch), jnp.float32)
+            for c in range(n_in):
                 i, j = (c, o) if transpose else (o, c)
                 acc = acc + step(A.at_blocks[i, j], A.block_fn(i, j),
                                  x_chunks[c], keys[i, j])
             rows.append(acc)
-        p = jnp.concatenate(rows, axis=0)[:n if transpose else m]
+        p = jnp.concatenate(rows, axis=0)[:len_out]
         if cfg.ec:
             p = denoise_least_square(p, lam=cfg.lam, h=cfg.h,
                                      method=cfg.denoise_method)

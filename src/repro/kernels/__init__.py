@@ -6,7 +6,6 @@ from .ops import (
     on_cpu,
     rram_ec_matmul,
     rram_ec_tile_mvm,
-    rram_ec_tile_rmvm,
     rram_encode_matmul,
     solver_cg_update,
     solver_richardson_update,
@@ -18,7 +17,6 @@ __all__ = [
     "on_cpu",
     "rram_ec_matmul",
     "rram_ec_tile_mvm",
-    "rram_ec_tile_rmvm",
     "rram_encode_matmul",
     "solver_cg_update",
     "solver_richardson_update",

@@ -33,9 +33,6 @@ __all__ = [
     "rram_encode_matmul",
     "rram_ec_matmul",
     "rram_ec_tile_mvm",
-    "rram_ec_tile_rmvm",
-    "rram_ec_group_mvm",
-    "rram_ec_group_rmvm",
     "denoise_thomas",
     "denoise_stencil",
     "solver_richardson_update",
@@ -205,85 +202,27 @@ def rram_ec_tile_mvm(
     at_blk: jnp.ndarray,
     da_blk: jnp.ndarray,
     *,
+    transpose: bool = False,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Tier-1 EC step for ONE capacity tile in the engine's (n, batch) layout.
+    """Tier-1 EC step for ONE capacity tile in the engine's (rows, batch)
+    layout.
 
     Computes ``at_blk @ x_blk + da_blk @ x_t`` as a single fused
     :func:`rram_ec_matmul` call with the tile on the left, so the streamed
     scan body and the host-loop fallback share one kernel-backed tile step
     and no operand is transposed.  ``x_blk``/``x_t``: (cap_n, batch);
     ``at_blk``/``da_blk``: (cap_m, cap_n).  Returns fp32 (cap_m, batch).
+
+    ``transpose=True`` computes ``at_blk.T @ x_blk + da_blk.T @ x_t`` in the
+    ``z^T = y^T At + y_t^T dA`` form -- the same kernel read in the
+    transposed direction; only the (cap_m, batch) input panels are
+    transposed.  Returns fp32 (cap_n, batch).
     """
-    return rram_ec_matmul(at_blk, da_blk, x_blk, x_t, interpret=interpret)
-
-
-def rram_ec_tile_rmvm(
-    y_blk: jnp.ndarray,
-    y_t: jnp.ndarray,
-    at_blk: jnp.ndarray,
-    da_blk: jnp.ndarray,
-    *,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """TRANSPOSED tier-1 EC step for ONE capacity tile ((m, batch) layout).
-
-    Computes ``at_blk.T @ y_blk + da_blk.T @ y_t`` as a single fused
-    :func:`rram_ec_matmul` call -- the ``z^T = y^T At + y_t^T dA`` form, i.e.
-    the same kernel read in the transposed direction, so the transposed
-    streamed scan body and the host-loop fallback share one kernel-backed
-    tile step; only the (cap_m, batch) input panels are transposed.
-    ``y_blk``/``y_t``: (cap_m, batch); ``at_blk``/``da_blk``:
-    (cap_m, cap_n).  Returns fp32 (cap_n, batch).
-    """
-    with jax.named_scope("meliso.tier1"):
-        return rram_ec_matmul(y_blk.T, y_t.T, at_blk, da_blk,
+    if transpose:
+        return rram_ec_matmul(x_blk.T, x_t.T, at_blk, da_blk,
                               transposed=True, interpret=interpret).T
-
-
-def rram_ec_group_mvm(
-    x_g: jnp.ndarray,
-    x_t_g: jnp.ndarray,
-    at_g: jnp.ndarray,
-    da_g: jnp.ndarray,
-    *,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Tier-1 EC step for a STACK of images under an extra leading image axis.
-
-    All operands carry a leading group axis ``g``: ``x_g``/``x_t_g`` are
-    (g, n, batch) input panels, ``at_g``/``da_g`` (g, m, n) dense operands.
-    Runs the fused :func:`rram_ec_matmul` kernel once per member inside a
-    single ``lax.map`` (a scan -- ONE traced program, the kernel grid never
-    sees the image axis), returning (g, m, batch).  Member ``g`` is
-    bit-identical to a solo :func:`rram_ec_tile_mvm` on its slice.
-    """
-    def one(ops):
-        x, x_t, at, da = ops
-        return rram_ec_tile_mvm(x, x_t, at, da, interpret=interpret)
-
-    with jax.named_scope("meliso.tier1"):
-        return jax.lax.map(one, (x_g, x_t_g, at_g, da_g))
-
-
-def rram_ec_group_rmvm(
-    y_g: jnp.ndarray,
-    y_t_g: jnp.ndarray,
-    at_g: jnp.ndarray,
-    da_g: jnp.ndarray,
-    *,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """TRANSPOSED grouped tier-1 EC step: the :func:`rram_ec_tile_rmvm`
-    mirror of :func:`rram_ec_group_mvm`.  ``y_g``/``y_t_g`` are (g, m, batch),
-    ``at_g``/``da_g`` (g, m, n); returns (g, n, batch) -- the same kernel read
-    backwards per member under one ``lax.map``."""
-    def one(ops):
-        y, y_t, at, da = ops
-        return rram_ec_tile_rmvm(y, y_t, at, da, interpret=interpret)
-
-    with jax.named_scope("meliso.tier1"):
-        return jax.lax.map(one, (y_g, y_t_g, at_g, da_g))
+    return rram_ec_matmul(at_blk, da_blk, x_blk, x_t, interpret=interpret)
 
 
 def solver_richardson_update(

@@ -80,10 +80,9 @@ def _kernel_ops(text):
 @pytest.mark.parametrize("batch", [1, 64])
 @pytest.mark.parametrize("direction", ["mvm", "rmvm"])
 def test_ec_tile_step(one_chip, batch, direction):
-    step = kops.rram_ec_tile_mvm if direction == "mvm" \
-        else kops.rram_ec_tile_rmvm
-    _compile(lambda x, xt, a, d: step(x, xt, a, d, interpret=False),
-             one_chip, (CAP, batch), (CAP, batch), (CAP, CAP), (CAP, CAP))
+    _compile(lambda x, xt, a, d: kops.rram_ec_tile_mvm(
+        x, xt, a, d, transpose=direction == "rmvm", interpret=False),
+        one_chip, (CAP, batch), (CAP, batch), (CAP, CAP), (CAP, CAP))
 
 
 @pytest.mark.parametrize("batch", [1, 8, 64, 128])
@@ -124,11 +123,19 @@ def test_ec_matmul_packed_resident_stack(one_chip):
     assert ops[0][1] == "meliso.tier1", ops
 
 
-def test_ec_group_step(one_chip):
-    _compile(lambda x, xt, a, d: kops.rram_ec_group_mvm(x, xt, a, d,
-                                                        interpret=False),
-             one_chip, (3, 512, 1), (3, 512, 1), (3, 512, 512),
-             (3, 512, 512))
+def test_ec_group_step(one_chip, monkeypatch):
+    """The grouped pallas execute (one ``lax.map`` of the resident kernel
+    body over the members) for three 512^2 members, one column each."""
+    from repro.core import CrossbarConfig, MCAGeometry, get_device
+    from repro.engine import _exec_group_pallas
+    monkeypatch.setattr(kops, "on_cpu", lambda: False)
+    cfg = CrossbarConfig(device=get_device("epiram"),
+                         geom=MCAGeometry(1, 1, 512, 512))
+    keys = jax.ShapeDtypeStruct((3, 2), jnp.uint32, sharding=one_chip)
+    _compile(lambda at, da, x, k: _exec_group_pallas(
+        at, da, x, k, cfg=cfg, m=512, n=512, transpose=False),
+        one_chip, (3, 1, 1, 512, 512), (3, 1, 1, 512, 512), (3, 512, 1),
+        keys)
 
 
 def test_encode_matmul_rng(one_chip):
